@@ -289,27 +289,32 @@ def _offline_tools(args, cfg) -> int:
         hdr = txdb.get_ledger_header(seq=args.ledger)
         if hdr is None:
             raise SystemExit(f"no stored ledger {args.ledger}")
-        # replay through the CONFIGURED hash/signature backends — this is
-        # the BASELINE #5 harness, so it must measure the device pipeline
-        # (batched re-verification is the catch-up trust model)
-        from .crypto.backend import make_hasher
-        from .node.verifyplane import VerifyPlane
+        # replay through the CONFIGURED hash/signature backends, built
+        # by the same wiring as Node (watchdogged hasher, routing=,
+        # deadlines, compile cache) — this is the BASELINE #5 harness,
+        # so it must measure the device pipeline (batched
+        # re-verification is the catch-up trust model)
+        from .node.node import make_crypto_planes
+        from .utils.xlacache import COMPILES
 
-        hasher = make_hasher(
-            cfg.hash_backend,
-            **({"mesh": cfg.hash_mesh} if cfg.hash_backend == "tpu" else {}),
-        )
-        plane = VerifyPlane(backend=cfg.signature_backend, window_ms=1.0,
-                            backend_opts=cfg.verify_backend_opts())
-        stats = replay_ledger(db, hdr["hash"], hash_batch=hasher,
-                              verify_many=plane.verify_many)
+        hasher, plane = make_crypto_planes(cfg)
+        try:
+            stats = replay_ledger(db, hdr["hash"], hash_batch=hasher,
+                                  verify_many=plane.verify_many)
+        finally:
+            plane.stop()
         # routing evidence: without this, latency-aware routing could
         # verify everything on the CPU while the harness claims a
         # device-pipeline measurement
         pj = plane.get_json()
         stats["device_share"] = pj.get("device_share", 0.0)
         stats["device_sigs"] = pj.get("device_sigs", 0)
-        plane.stop()
+        stats["verify"] = pj
+        hj = getattr(hasher, "get_json", None)
+        stats["hash"] = hj() if hj is not None else {"backend": hasher.name}
+        # compile requests and persistent-cache hits of THIS process: a
+        # catch-up after a node ran should load, not compile
+        stats["xla"] = COMPILES.snapshot()
         print(json.dumps(stats, indent=2))
         return 0 if stats["ok"] else 1
     return 0

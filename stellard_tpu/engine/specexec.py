@@ -372,7 +372,9 @@ def _worker_main(cmd, res) -> None:
     Replies can interleave with proactive sends (deltas, the next exec),
     so non-reply messages arriving while a read is in flight are buffered
     and handled after the current execution finishes."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # workers never need the device, and a chip belongs to ONE process:
+    # an inherited JAX_PLATFORMS=tpu must not let a worker reach for it
+    os.environ["JAX_PLATFORMS"] = "cpu"
     for conn in (cmd, res):
         # ring transport: drop this process's inherited copy of the
         # parent-side doorbell fd so parent death surfaces as EOF here

@@ -5,18 +5,24 @@ backends, OpenSSL hashing — SURVEY §2 [native-perf]); this module builds
 and binds their equivalents. The library is compiled on first use with
 `make` (toolchain is in the image) and cached; every consumer degrades
 gracefully to the pure-Python path when the toolchain or build is
-unavailable, mirroring the pluggable-backend seam.
+unavailable, mirroring the pluggable-backend seam. The degrade is for
+installs without a toolchain, so it is never silent: a failed build
+logs make's stderr once and keeps it in ``build_errors``.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional
 
+log = logging.getLogger("stellard.native")
+
 __all__ = [
+    "build_errors",
     "load_native",
     "load_stser",
     "native_available",
@@ -35,6 +41,37 @@ _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
+# library file name -> why its build failed (make's stderr, or the
+# OSError when there is no make at all). Empty when both built.
+build_errors: dict[str, str] = {}
+
+
+def _make(target_args: list[str], what: str) -> bool:
+    """Run make in native/; on failure keep and log its stderr."""
+    try:
+        subprocess.run(
+            ["make", "-s", *target_args],
+            cwd=_NATIVE_DIR,
+            check=True,
+            capture_output=True,
+            text=True,
+            timeout=300,
+        )
+        return True
+    except subprocess.CalledProcessError as exc:
+        err = (exc.stderr or exc.stdout or "").strip() or repr(exc)
+    except (OSError, subprocess.SubprocessError) as exc:
+        err = repr(exc)
+    build_errors[what] = err
+    log.warning(
+        "native build of %s FAILED — consumers fall back to %s:\n%s",
+        what,
+        "a stale prebuilt copy" if os.path.exists(
+            os.path.join(_NATIVE_DIR, what)) else "pure Python",
+        err[-4000:],
+    )
+    return False
+
 
 def load_native() -> Optional[ctypes.CDLL]:
     """Build (once) and dlopen the native library; None if unavailable."""
@@ -49,17 +86,10 @@ def load_native() -> Optional[ctypes.CDLL]:
         # from an older source tree must be refreshed, or newly added
         # symbols would be missing from the dlopened library
         if os.path.isdir(_NATIVE_DIR):
-            try:
-                subprocess.run(
-                    ["make", "-s"],
-                    cwd=_NATIVE_DIR,
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-            except (OSError, subprocess.SubprocessError):
-                if not os.path.exists(_LIB_PATH):
-                    return None
+            if not _make([], os.path.basename(_LIB_PATH)) and (
+                not os.path.exists(_LIB_PATH)
+            ):
+                return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError:
@@ -93,24 +123,16 @@ def load_stser():
         _stser_tried = True
         path = os.path.join(_NATIVE_DIR, "_stser.so")
         if os.path.isdir(_NATIVE_DIR):
-            try:
-                # build against the RUNNING interpreter's headers — the
-                # Makefile's `python3` may be a different installation,
-                # and a version-mismatched extension dlopens anyway
-                # (inline object-layout macros would then misread)
-                import sysconfig
+            # build against the RUNNING interpreter's headers — the
+            # Makefile's `python3` may be a different installation,
+            # and a version-mismatched extension dlopens anyway
+            # (inline object-layout macros would then misread)
+            import sysconfig
 
-                subprocess.run(
-                    ["make", "-s", "_stser.so",
-                     f"PY_INC={sysconfig.get_paths()['include']}"],
-                    cwd=_NATIVE_DIR,
-                    check=True,
-                    capture_output=True,
-                    timeout=120,
-                )
-            except (OSError, subprocess.SubprocessError):
-                if not os.path.exists(path):
-                    return None
+            _make(
+                ["_stser.so", f"PY_INC={sysconfig.get_paths()['include']}"],
+                "_stser.so",
+            )
         if not os.path.exists(path):
             return None
         try:

@@ -172,7 +172,20 @@ class NetworkOPs:
         )
 
         def when_done(f):
-            good = bool(f.result()) if not f.exception() else False
+            if f.exception() is not None:
+                # the verifier itself failed (the plane already retried
+                # a raising device arm on the CPU arm, so this is the
+                # host side raising): no verdict exists, so none is
+                # recorded — no SF_BAD, no bad_sig, a resubmittable
+                # local error to the client
+                tr.end(vtok, good=False, verifier_error=True)
+                self.stats["verify_error"] = (
+                    self.stats.get("verify_error", 0) + 1
+                )
+                if cb:
+                    cb(tx, TER.tefEXCEPTION, False)
+                return
+            good = bool(f.result())
             tr.end(vtok, good=good)
             tx.set_sig_verdict(good)
             self.router.set_flag(txid, SF_SIGGOOD if good else SF_BAD)

@@ -27,23 +27,6 @@ from ..ops.sha512_jax import sha512_blocks
 BATCH_AXIS = "batch"
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable shard_map: `jax.shard_map` (with `check_vma`)
-    landed well after the jax this image pins — older versions expose
-    `jax.experimental.shard_map.shard_map` with the same semantics under
-    the pre-rename `check_rep` keyword."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kwargs = {} if check_vma is None else {"check_vma": check_vma}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kwargs)
-    from jax.experimental.shard_map import shard_map as esm
-
-    kwargs = {} if check_vma is None else {"check_rep": check_vma}
-    return esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **kwargs)
-
-
 def make_mesh(devices=None) -> Mesh:
     devices = devices if devices is not None else jax.devices()
     return Mesh(np.asarray(devices), (BATCH_AXIS,))
@@ -76,7 +59,7 @@ def sharded_verify_kernel_pallas(mesh: Mesh):
 
     pspec = P(BATCH_AXIS)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             verify_kernel_pallas,
             mesh=mesh,
             in_specs=(pspec,) * 5,
@@ -178,7 +161,7 @@ def verify_and_count(mesh: Mesh):
 
     pspec = P(BATCH_AXIS)
     return jax.jit(
-        _shard_map(
+        jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(pspec, pspec, pspec, pspec, pspec),
