@@ -26,10 +26,12 @@ The parent process imports neither JAX nor ``stellard_tpu``: a chip
 belongs to one process at a time, so the three children run one after
 another, each under a wall-clock limit. Without an accelerator the first
 child exits non-zero, naming the platform it found, and no result is
-printed. On success the last line of stdout is one JSON object,
-``{"ok": true, "device": {"platform", "kind", "count"}, ...,
-"claim": null}``. Rates are not printed: this is a smoke, the benchmark
-owns every number.
+printed. Once the device is known, stdout carries two lines: a JSON
+summary of the run (sizes, kernels, set-up seconds, compile counts,
+``"claim": null``), then — the LAST line, with exactly these keys — the
+verdict ``{"ok": true|false, "device": {"platform", "kind", "count"}}``
+with the device as JAX reported it to the child that held it. Rates are
+not printed: this is a smoke, the benchmark owns every number.
 
     python chip_smoke.py [--seed 0] [--txs 32768] [--close-every 2048]
                          [--impl xla|pallas] [--mesh 0]
@@ -625,6 +627,70 @@ def check(device: dict, replay: dict, reference: dict, *,
 # entry points: the parent (no JAX) and its children
 
 
+def result_lines(device: dict, replay: dict, reference: dict,
+                 failures: list[str], *, impl: str, mesh: int) -> list[str]:
+    """What the parent writes to stdout once the device is known. A
+    passing run gets a JSON summary line first (set-up information; it
+    ends with ``"claim": null``). The LAST line is always the verdict,
+    with exactly the keys ``ok`` and ``device``, the device as JAX
+    reported it to the child that held the chip."""
+    lines: list[str] = []
+    if not failures:
+        vj = device["verify"]
+        hmesh = device["hash"]["mesh"]
+        stats = replay.get("stats") or {}
+        xla = stats.get("xla") or {}
+        first = device["compiles"]["through_first_close"] or {}
+        total = device["compiles"]["total"]
+        lines.append(json.dumps({
+            "summary": "chip_smoke",
+            "impl": impl,
+            "mesh": mesh,
+            "sizes": {"txs": device["txs"], "planted": PLANTED,
+                      "accounts": device["txs"] // 2,
+                      "ledgers": len(device["ledgers"]),
+                      "verify_lanes": (vj["mesh"] or {}).get("max_batch")},
+            "reduced": REDUCED,
+            "kernels": {"verify": vj["mesh"]["kernel"],
+                        "tree": hmesh["tree_kernel"],
+                        "flat": hmesh["kernel"]},
+            "device_work": {
+                "verify_batches": vj["device_batches"],
+                "verify_sigs": vj["device_sigs"],
+                "cpu_small_batches": vj["cpu_batches"],
+                "hash_device_nodes": device["hash"]["device_nodes"],
+                "fused_trees": hmesh["tree_pipeline_calls"]},
+            "setup_seconds": {
+                "device_node": {k: device[k] for k in (
+                    "setup_s", "prewarm_s", "workload_s", "flood_close_s",
+                    "rpc_s", "phase_s")},
+                "replay": {"process_s": replay["process_s"],
+                           "first_verdict_s": stats.get("verify_s")},
+                "reference": {k: reference[k] for k in (
+                    "workload_s", "flood_close_s", "phase_s")},
+            },
+            "compiles": {
+                "through_first_close": {k: first.get(k) for k in (
+                    "requests", "compiled", "seconds")},
+                "total": {k: total[k] for k in (
+                    "requests", "compiled", "seconds", "programs")},
+                "replay": {k: xla.get(k) for k in (
+                    "requests", "cache_hits", "compiled", "seconds",
+                    "programs")},
+            },
+            "rates": "not measured (a smoke; the benchmark owns every "
+                     "number)",
+            "claim": None,
+        }))
+    lines.append(json.dumps({
+        "ok": not failures,
+        "device": {"platform": str(device["platform"]),
+                   "kind": str(device["device_kind"]),
+                   "count": int(device["devices_visible"])},
+    }))
+    return lines
+
+
 def _child_main(args) -> int:
     res = run_node_phase(
         args.workdir, "tpu" if args.phase == "device" else "cpu",
@@ -732,6 +798,9 @@ def main(argv: list[str] | None = None) -> int:
                                  limit("reference"))
         if reference is None:
             say("FAILED: the CPU reference did not run")
+            print(result_lines(device, replay, {}, ["no reference"],
+                               impl=args.impl, mesh=args.mesh)[-1],
+                  flush=True)
             return 1
         say(f"  flood+close seconds {reference['flood_close_s']}")
     finally:
@@ -746,52 +815,10 @@ def main(argv: list[str] | None = None) -> int:
         for f in failures:
             say(f"GATE FAILED: {f}")
         say(f"FAILED: {len(failures)} gate(s)")
-        return 1
-
-    vj = device["verify"]
-    hmesh = device["hash"]["mesh"]
-    print(json.dumps({
-        "ok": True,
-        "device": {"platform": device["platform"],
-                   "kind": device["device_kind"],
-                   "count": device["devices_visible"]},
-        "impl": args.impl,
-        "mesh": args.mesh,
-        "sizes": {"txs": args.txs, "planted": PLANTED,
-                  "accounts": args.txs // 2,
-                  "ledgers": len(device["ledgers"]),
-                  "verify_lanes": (vj["mesh"] or {}).get("max_batch")},
-        "reduced": REDUCED,
-        "kernels": {"verify": vj["mesh"]["kernel"],
-                    "tree": hmesh["tree_kernel"],
-                    "flat": hmesh["kernel"]},
-        "device_work": {"verify_batches": vj["device_batches"],
-                        "verify_sigs": vj["device_sigs"],
-                        "cpu_small_batches": vj["cpu_batches"],
-                        "hash_device_nodes": device["hash"]["device_nodes"],
-                        "fused_trees": hmesh["tree_pipeline_calls"]},
-        "setup_seconds": {
-            "device_node": {k: device[k] for k in (
-                "setup_s", "prewarm_s", "workload_s", "flood_close_s",
-                "rpc_s", "phase_s")},
-            "replay": {"process_s": replay["process_s"],
-                       "first_verdict_s": stats.get("verify_s")},
-            "reference": {k: reference[k] for k in (
-                "workload_s", "flood_close_s", "phase_s")},
-        },
-        "compiles": {
-            "through_first_close": {k: first.get(k) for k in (
-                "requests", "compiled", "seconds")},
-            "total": {k: total[k] for k in (
-                "requests", "compiled", "seconds", "programs")},
-            "replay": {k: xla.get(k) for k in (
-                "requests", "cache_hits", "compiled", "seconds",
-                "programs")},
-        },
-        "rates": "not measured (a smoke; the benchmark owns every number)",
-        "claim": None,
-    }), flush=True)
-    return 0
+    for line in result_lines(device, replay, reference, failures,
+                             impl=args.impl, mesh=args.mesh):
+        print(line, flush=True)
+    return 1 if failures else 0
 
 
 if __name__ == "__main__":

@@ -63,6 +63,22 @@ def test_chip_smoke_phases_on_cpu(tmp_path, monkeypatch):
     )
     assert failures == []
 
+    # stdout: a summary line, then the verdict with exactly ok + device
+    import json
+
+    lines = chip_smoke.result_lines(
+        device, replay, reference, failures, impl="xla", mesh=0)
+    assert json.loads(lines[0])["claim"] is None
+    want_device = {"platform": "cpu", "kind": device["device_kind"],
+                   "count": device["devices_visible"]}
+    assert json.loads(lines[-1]) == {"ok": True, "device": want_device}
+    assert isinstance(want_device["kind"], str)
+    assert type(want_device["count"]) is int
+    lines = chip_smoke.result_lines(
+        device, replay, reference, ["a gate"], impl="xla", mesh=0)
+    assert [json.loads(ln) for ln in lines] == [
+        {"ok": False, "device": want_device}]
+
     # the gates bite: a plane that fell back, a forked ledger
     broken = dict(device, verify=dict(device["verify"],
                                       cpu_eligible_batches=1))
