@@ -53,6 +53,10 @@ class _Entry:
     enqueued_at: float = field(default_factory=time.perf_counter)
 
 
+# the `persist.<stage>` spans that get_json() reports as its stages
+_STAGES = ("queue_wait", "nodestore", "txdb", "clf", "total")
+
+
 class ClosePipeline:
     """Bounded, strictly-ordered persistence stage for closed ledgers."""
 
@@ -89,13 +93,9 @@ class ClosePipeline:
         self.depth_hwm = 0
         self.backpressure_waits = 0
         self.backpressure_ms = 0.0
-        self.stage_hist = {
-            "queue_wait": LatencyHist(),  # enqueue -> drain start
-            "nodestore": LatencyHist(),
-            "txdb": LatencyHist(),
-            "clf": LatencyHist(),
-            "total": LatencyHist(),
-        }
+        # the stage latencies (queue_wait, nodestore, txdb, clf, total)
+        # are the tracer's `persist.*` spans: ONE histogram an interval,
+        # in tracer.stage_hist, which get_json() reads back
         self._name = name
         # worker starts lazily on first submit: a Node constructed and
         # discarded without stop() must not leak a polling daemon thread
@@ -168,7 +168,14 @@ class ClosePipeline:
                 while (self._kind_depth(entry.kind) >= limit
                        and not self._stopping):
                     self._not_full.wait(timeout=1.0)
-                self.backpressure_ms += (time.perf_counter() - t0) * 1000.0
+                t1 = time.perf_counter()
+                self.backpressure_ms += (t1 - t0) * 1000.0
+                # the close that waited for the drain worker: how long
+                # persist held the close path (what paces a flood)
+                self.tracer.complete(
+                    "persist.backpressure", "persist", t0, t1,
+                    seq=entry.ledger.seq if entry.ledger is not None
+                    else None, kind=entry.kind)
                 if self._stopping:
                     # stop() fired while we were blocked: the drain worker
                     # may already have exited — appending now would strand
@@ -278,9 +285,6 @@ class ClosePipeline:
         t_start = time.perf_counter()
         seq = entry.ledger.seq
         tr = self.tracer
-        self.stage_hist["queue_wait"].record(
-            (t_start - entry.enqueued_at) * 1000.0
-        )
         tr.complete("persist.queue_wait", "persist", entry.enqueued_at,
                     t_start, seq=seq)
         results = entry.results
@@ -294,19 +298,15 @@ class ClosePipeline:
         t0 = time.perf_counter()
         self.save_stage(entry.ledger)
         t1 = time.perf_counter()
-        self.stage_hist["nodestore"].record((t1 - t0) * 1000.0)
         tr.complete("persist.nodestore", "persist", t0, t1, seq=seq)
         self.txdb_stage(entry.ledger, results)
         t2 = time.perf_counter()
-        self.stage_hist["txdb"].record((t2 - t1) * 1000.0)
         tr.complete("persist.txdb", "persist", t1, t2, seq=seq)
         if entry.kind == "close":
             self.clf_stage(entry.ledger)
             t3 = time.perf_counter()
-            self.stage_hist["clf"].record((t3 - t2) * 1000.0)
             tr.complete("persist.clf", "persist", t2, t3, seq=seq)
         t_end = time.perf_counter()
-        self.stage_hist["total"].record((t_end - t_start) * 1000.0)
         tr.complete("persist.total", "persist", t_start, t_end, seq=seq,
                     kind=entry.kind, txs=len(results or ()))
         # per-tx persist marks close out each SAMPLED transaction's
@@ -393,7 +393,11 @@ class ClosePipeline:
             "failed": self.failed,
             "backpressure_waits": self.backpressure_waits,
             "backpressure_ms": round(self.backpressure_ms, 3),
+            # from the tracer's `persist.*` stage histograms (absent
+            # stages with `[trace] enabled=0`: nothing records them)
             "stages": {
-                name: h.get_json() for name, h in self.stage_hist.items()
+                name: h.get_json()
+                for name, h in self.tracer.stages(
+                    "persist.", _STAGES).items()
             },
         }

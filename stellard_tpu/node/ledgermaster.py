@@ -21,8 +21,8 @@ from ..node.hashrouter import SF_SIGGOOD
 from ..protocol.sttx import SerializedTransaction
 from ..protocol.ter import TER
 from ..state.ledger import Ledger
-from .metrics import AtomicCounters, LatencyHist
-from .tracer import STAGE_BOUNDS, get_tracer
+from .metrics import AtomicCounters
+from .tracer import get_tracer
 
 __all__ = ["LedgerMaster", "CanonicalTXSet", "LEDGER_TOTAL_PASSES"]
 
@@ -151,21 +151,16 @@ class LedgerMaster:
             "seal_rejected": 0, "seal_residual_keys": 0,
             "bulk_merges": 0, "bulk_merged_keys": 0,
         }
-        self._drain_hist = LatencyHist(bounds=STAGE_BOUNDS, interpolate=True)
         self._drain_cv = threading.Condition()
         self._drain_pending = 0
         self._drain_kick = False
         self._drain_busy = False
         self._drainer: Optional[threading.Thread] = None
         self._drain_stop = False
-        # per-close stage latency histograms (ms): apply pass, seal
-        # overlap, total — the shared metrics.LatencyHist (fine-grained
-        # bounds: closes live in the 1-500 ms band)
-        self.close_stage_hist: dict[str, LatencyHist] = {
-            "apply": LatencyHist(bounds=STAGE_BOUNDS, interpolate=True),
-            "seal": LatencyHist(bounds=STAGE_BOUNDS, interpolate=True),
-            "total": LatencyHist(bounds=STAGE_BOUNDS, interpolate=True),
-        }
+        # the per-close stage latencies (apply pass, seal overlap,
+        # total) are the tracer's `close.*` spans: ONE histogram an
+        # interval, in tracer.stage_hist, which delta_replay_json()
+        # reads back
 
     # -- bootstrap --------------------------------------------------------
 
@@ -478,7 +473,6 @@ class LedgerMaster:
                 self.tree_stats["drained_nodes"] += n
                 self._drain_busy = False
                 self._drain_cv.notify_all()
-            self._drain_hist.record((t1 - t0) * 1000.0)
             self.tracer.complete("seal.incremental", "seal", t0, t1,
                                  nodes=n)
 
@@ -498,9 +492,13 @@ class LedgerMaster:
             out = dict(self.tree_stats)
         out["incremental_seal"] = self.incremental_seal
         out["drain_batch"] = self.seal_drain_batch
-        if self._drain_hist.count:
-            out["drain_p50_ms"] = self._drain_hist.quantile(0.5)
-            out["drain_p90_ms"] = self._drain_hist.quantile(0.9)
+        # drain latency from the tracer's `seal.incremental` stage
+        # histogram (absent with `[trace] enabled=0`)
+        hist = self.tracer.stages("seal.", ("incremental",)).get(
+            "incremental")
+        if hist is not None and hist.count:
+            out["drain_p50_ms"] = hist.quantile(0.5)
+            out["drain_p90_ms"] = hist.quantile(0.9)
         return out
 
     # -- close (standalone / consensus-accept share this tail) ------------
@@ -1024,9 +1022,6 @@ class LedgerMaster:
             "seal_ms": round((t_seal - t_apply) * 1000.0, 3),
             "total_ms": round((now - t0) * 1000.0, 3),
         }
-        self.close_stage_hist["apply"].record(stages["apply_ms"])
-        self.close_stage_hist["seal"].record(stages["seal_ms"])
-        self.close_stage_hist["total"].record(stages["total_ms"])
         self.last_close.update(stages)
         tr = self.tracer
         tr.complete("close.apply", "close", t0, t_apply, seq=seq)
@@ -1044,8 +1039,11 @@ class LedgerMaster:
                 **self.delta_stats.snapshot(),
                 "last_close": dict(self.last_close),
             }
-            if self.close_stage_hist["total"].count:
-                for stage, hist in self.close_stage_hist.items():
+            # close-stage percentiles from the tracer's `close.*` stage
+            # histograms (absent with `[trace] enabled=0`)
+            for stage, hist in self.tracer.stages(
+                    "close.", ("apply", "seal", "total")).items():
+                if hist.count:
                     out[f"{stage}_p50_ms"] = hist.quantile(0.5)
                     out[f"{stage}_p90_ms"] = hist.quantile(0.9)
         if self.spec_executor is not None:

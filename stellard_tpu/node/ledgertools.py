@@ -22,6 +22,7 @@ from ..protocol.stobject import STObject
 from ..protocol.ter import TER
 from ..state.ledger import Ledger
 from .ledgermaster import CanonicalTXSet, LedgerMaster
+from .tracer import GC_PROBE, get_tracer
 
 __all__ = [
     "dump_ledger",
@@ -147,6 +148,15 @@ def _reverify_memoized(txs: list, verify_many: Callable) -> None:
         tx.set_sig_verdict(bool(good))
 
 
+def _runtime_marks() -> tuple:
+    """What the interpreter's collector and the hot-node cache's victim
+    scans have cost so far: a replay span carries the difference over
+    its own length (``gc_pause_s``, ``evict_scan_s``)."""
+    from ..state.shamap import inner_node_cache
+
+    return GC_PROBE.pause_total_s(), inner_node_cache().evict_scan_s
+
+
 def replay_ledger(
     db: Database,
     ledger_hash: bytes,
@@ -154,6 +164,7 @@ def replay_ledger(
     verify_many: Optional[Callable] = None,
     _txs: Optional[list] = None,
     _target: Optional[Ledger] = None,
+    tracer=None,
 ) -> dict:
     """Re-close a stored ledger from its parent and verify the result
     hashes identically (reference: --ledger N --replay, Main.cpp:325-332).
@@ -167,35 +178,62 @@ def replay_ledger(
     signature in the ledger is re-verified in ONE batch up front and the
     verdicts memoized into the txs — the HashRouter SF_SIGGOOD seam — so
     the per-tx engine path skips its inline host verify. This is the
-    catch-up trust model: replayed history is re-verified, batched."""
+    catch-up trust model: replayed history is re-verified, batched.
+
+    One ``replay.ledger`` span a call (``tracer`` defaults to the
+    process tracer), with the loads it makes (``ledger.load``),
+    ``replay.verify``, ``replay.apply`` and ``replay.close`` (the close
+    and both tree hashes) under it."""
+    tr = tracer if tracer is not None else get_tracer()
+    probed = GC_PROBE.install(tr)
+    try:
+        with tr.span("replay.ledger", "replay") as span:
+            return _replay_ledger(db, ledger_hash, hash_batch, verify_many,
+                                  _txs, _target, tr, span)
+    finally:
+        if probed:
+            GC_PROBE.remove(tr)
+
+
+def _replay_ledger(db, ledger_hash, hash_batch, verify_many, _txs, _target,
+                   tr, span) -> dict:
     kw = {"hash_batch": hash_batch} if hash_batch else {}
     target = _target if _target is not None else Ledger.load(
-        db, ledger_hash, **kw
+        db, ledger_hash, tracer=tr, **kw
     )
-    parent = Ledger.load(db, target.parent_hash, **kw)
+    parent = Ledger.load(db, target.parent_hash, tracer=tr, **kw)
 
-    txs = _txs if _txs is not None else [
-        SerializedTransaction.from_bytes(blob)
-        for _txid, blob, _meta in target.tx_entries()
-    ]
+    if _txs is not None:
+        txs = _txs
+    else:
+        with tr.span("replay.parse", "replay"):
+            txs = [
+                SerializedTransaction.from_bytes(blob)
+                for _txid, blob, _meta in target.tx_entries()
+            ]
     t0 = time.perf_counter()
     if verify_many is not None:
-        _reverify_memoized(txs, verify_many)
+        with tr.span("replay.verify", "replay", sigs=len(txs)):
+            _reverify_memoized(txs, verify_many)
     verify_s = time.perf_counter() - t0
-    replay = parent.open_successor()
-    txset = CanonicalTXSet(parent.hash())
-    for tx in txs:
-        txset.insert(tx)
-    lm = LedgerMaster(**kw)
-    results = lm._apply_transactions(replay, txset)
-    replay.close(
-        target.close_time,
-        target.close_resolution,
-        correct_close_time=(target.close_flags & 1) == 0,
-    )
-    replay.close_flags = target.close_flags
-    replay_hash = replay.hash()
+    with tr.span("replay.apply", "replay", txs=len(txs)):
+        replay = parent.open_successor()
+        txset = CanonicalTXSet(parent.hash())
+        for tx in txs:
+            txset.insert(tx)
+        lm = LedgerMaster(tracer=tr, **kw)
+        results = lm._apply_transactions(replay, txset)
+    with tr.span("replay.close", "replay"):
+        replay.close(
+            target.close_time,
+            target.close_resolution,
+            correct_close_time=(target.close_flags & 1) == 0,
+        )
+        replay.close_flags = target.close_flags
+        replay_hash = replay.hash()
     elapsed = time.perf_counter() - t0
+    if span is not None:
+        span.attrs = {"seq": target.seq, "txs": len(txs)}
 
     ok = replay_hash == ledger_hash
     return {
@@ -221,6 +259,7 @@ def replay_range(
     ledger_hashes: list[bytes],
     hash_batch: Optional[Callable] = None,
     verify_many: Optional[Callable] = None,
+    tracer=None,
 ) -> dict:
     """Bulk catch-up over a chain of stored ledgers.
 
@@ -232,24 +271,55 @@ def replay_range(
     verdicts memoized (the SF_SIGGOOD seam) — the bigger the catch-up
     span, the further the batch rides up the device's throughput curve.
     Verdict semantics are identical to per-ledger replay: a bad historic
-    signature still fails its own ledger's hash check, no other's."""
+    signature still fails its own ledger's hash check, no other's.
+
+    One ``replay.span`` span a call (``tracer`` defaults to the process
+    tracer): ``ledger.load`` for every target, ``replay.parse``,
+    ``replay.verify`` (the plane's ``verify.batch`` nests under it), then
+    a ``replay.ledger`` per ledger. It ends with the ledgers and
+    transactions it covered and what the collector (``gc_pause_s``) and
+    the hot cache's victim scans (``evict_scan_s``) took of it."""
+    tr = tracer if tracer is not None else get_tracer()
+    probed = GC_PROBE.install(tr)
+    try:
+        with tr.span("replay.span", "replay") as span:
+            marks = _runtime_marks()
+            out = _replay_range(db, ledger_hashes, hash_batch, verify_many,
+                                tr)
+            if span is not None:
+                gc_s, scan_s = _runtime_marks()
+                span.attrs = {
+                    "ledgers": out["ledger_count"], "txs": out["tx_count"],
+                    "gc_pause_s": round(gc_s - marks[0], 6),
+                    "evict_scan_s": round(scan_s - marks[1], 6),
+                }
+            return out
+    finally:
+        if probed:
+            GC_PROBE.remove(tr)
+
+
+def _replay_range(db, ledger_hashes, hash_batch, verify_many, tr) -> dict:
     kw = {"hash_batch": hash_batch} if hash_batch else {}
     t0 = time.perf_counter()
-    targets = [Ledger.load(db, h, **kw) for h in ledger_hashes]
-    per_ledger: list[list[SerializedTransaction]] = [
-        [
-            SerializedTransaction.from_bytes(blob)
-            for _txid, blob, _meta in target.tx_entries()
+    targets = [Ledger.load(db, h, tracer=tr, **kw) for h in ledger_hashes]
+    with tr.span("replay.parse", "replay"):
+        per_ledger: list[list[SerializedTransaction]] = [
+            [
+                SerializedTransaction.from_bytes(blob)
+                for _txid, blob, _meta in target.tx_entries()
+            ]
+            for target in targets
         ]
-        for target in targets
-    ]
     if verify_many is not None:
-        _reverify_memoized(
-            [tx for txs in per_ledger for tx in txs], verify_many
-        )
+        with tr.span("replay.verify", "replay",
+                     sigs=sum(len(txs) for txs in per_ledger)):
+            _reverify_memoized(
+                [tx for txs in per_ledger for tx in txs], verify_many
+            )
     stats = [
         replay_ledger(db, h, hash_batch=hash_batch, _txs=txs,
-                      _target=target)
+                      _target=target, tracer=tr)
         for h, txs, target in zip(ledger_hashes, per_ledger, targets)
     ]
     elapsed = time.perf_counter() - t0

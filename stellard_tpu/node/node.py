@@ -239,6 +239,11 @@ class Node:
         from .tracer import Tracer
 
         self.tracer = Tracer.from_config(cfg)
+        # set-up on the timeline: constructor to serving (`node.boot`,
+        # ended by serve()); the device prewarm is `node.prewarm`
+        # (verifyplane.start_prewarm)
+        self._boot_span = self.tracer.begin(
+            "node.boot", "setup", start_up=cfg.start_up)
 
         # storage plane (reference: NodeStore Manager + main db :330)
         db_kwargs = {}
@@ -369,6 +374,7 @@ class Node:
         self.hasher, self.verify_plane = make_crypto_planes(
             cfg, tracer=self.tracer
         )
+        self._gc_probed = False
         self.verify_prewarm: Optional[threading.Thread] = None
         if cfg.signature_backend != "cpu":
             # compile + measure the device shapes in the background;
@@ -1057,6 +1063,12 @@ class Node:
     def setup(self) -> "Node":
         """reference: ApplicationImp::setup — START_UP switch
         (Application.cpp:733-762)."""
+        from .tracer import GC_PROBE
+
+        if not self._gc_probed:
+            # the collector's pauses, counted and (the long ones) on
+            # this node's timeline; nothing with `[trace] enabled=0`
+            self._gc_probed = GC_PROBE.install(self.tracer)
         if self.config.debug_logfile and self._debug_log_handler is None:
             # [debug_logfile]: full-severity mirror on disk regardless of
             # the console/partition levels (reference: setDebugLogFile,
@@ -1189,6 +1201,9 @@ class Node:
             lambda: {
                 "batches": self.verify_plane.batches,
                 "verified": self.verify_plane.verified,
+                "device_sigs": self.verify_plane.device_sigs,
+                **{f"host_{why}_sigs": n for why, n in
+                   self.verify_plane.host_sigs_by_why.items()},
             },
         )
         # routing-flip telemetry for the health watchdog: which side
@@ -1270,6 +1285,22 @@ class Node:
         # span-derived per-stage latency percentiles (trace.<stage>.p50_ms
         # et al.): the unified latency surface the tracing plane feeds
         self.collector.hook("trace", self.tracer.statsd_hook)
+        # the runtime, the door and the state cache seen from inside
+        # (`gc.gen2_pause_s`, `rpc.busy_s`, `rpc.lag_s`,
+        # `state_cache.evict_scan_s`, ... on /metrics)
+        from ..state.shamap import inner_node_cache
+        from .tracer import GC_PROBE
+
+        def _flat(get_json):
+            return lambda: {
+                k: v for k, v in get_json().items()
+                if isinstance(v, (int, float)) and not isinstance(v, bool)
+            }
+
+        self.collector.hook("gc", _flat(GC_PROBE.get_json))
+        self.collector.hook("state_cache", _flat(inner_node_cache().get_json))
+        if self.http_server is not None:
+            self.collector.hook("rpc", _flat(self.http_server.get_json))
         if self.spec_executor.active:
             self.collector.hook(
                 "spec",
@@ -1292,6 +1323,8 @@ class Node:
             },
         )
         self.collector.start()
+        self.tracer.end(self._boot_span)
+        self._boot_span = None
         return self
 
     def run(self) -> None:
@@ -1432,6 +1465,11 @@ class Node:
 
     def stop(self) -> None:
         self._running.clear()
+        if self._gc_probed:
+            from .tracer import GC_PROBE
+
+            GC_PROBE.remove(self.tracer)
+            self._gc_probed = False
         self.load_manager.stop()
         # the executor first: any open speculation window completes
         # serially before the chain machinery below winds down

@@ -47,20 +47,52 @@ complete fleet-wide. ``wire_context()`` exports the sender side;
 any span recorded for that trace with no local parent links under it
 (marked ``remote`` in the dump — a single-node validation must not
 demand the foreign parent resolve locally).
+
+One clock with the device trace: spans are stamped ``perf_counter``
+minus ``epoch``, and the dump exports that epoch (``otherData.
+epoch_ns``). Whoever starts a ``jax.profiler`` capture (the ``profile``
+RPC) calls ``anchor()``, which writes ONE instant host annotation into
+the XPlane whose name carries this tracer's ``node_tag`` and the
+``perf_counter`` reading of that moment; a reader then places every
+span of the ring, ``complete()``-style and cross-thread ones included,
+on the profiler's clock with ``place_on_trace_clock`` (tools/
+traceview.py --xplane draws both on one timeline). No annotation per
+span: a span costs the same whether or not a capture is live.
+
+The interpreter's collector is part of the runtime every span runs on:
+``GC_PROBE`` (one ``gc.callbacks`` hook per process, installed by the
+nodes and replay tools whose tracer is enabled) counts collections and
+their pauses per generation and records the long ones as ``gc.collect``
+spans in the rings of the tracers that installed it.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import os
+import re
 import threading
 import time
+import weakref
 import zlib
+from collections import deque
 from typing import Optional
 
 from .metrics import LatencyHist
 
-__all__ = ["Tracer", "SpanToken", "get_tracer"]
+__all__ = ["Tracer", "SpanToken", "get_tracer", "GC_PROBE",
+           "parse_anchor", "place_on_trace_clock"]
+
+# the clock anchor's name in a profiler trace: node tag and the
+# perf_counter reading (ns) taken as the annotation was written
+ANCHOR_PREFIX = "stellard.anchor"
+_ANCHOR = re.compile(
+    r"^stellard\.anchor tag=([0-9a-f]{8}) pc_ns=(\d+)$")
+
+# a collection at least this long is a span (a full collection always
+# is: there are a handful a minute and each holds every thread)
+GC_SPAN_MIN_S = 0.010
 
 # bound on the per-trace foreign-parent / last-span maps the propagation
 # plane keeps (FIFO eviction; a trace is a txid or "ledger-<seq>")
@@ -180,6 +212,10 @@ class Tracer:
         # optional flight-recorder feed (node/health.py FlightRecorder):
         # every recorded span/instant also lands in its black box
         self.flight = None
+        # spans handed over by a caller that must not take _lock (the
+        # collector's hook, see complete_unlocked); they move into the
+        # ring under the next lock hold
+        self._parked: deque = deque()
 
     @classmethod
     def from_config(cls, cfg) -> "Tracer":
@@ -190,6 +226,34 @@ class Tracer:
             sample=cfg.trace_sample,
             propagate=getattr(cfg, "trace_propagate", False),
         )
+
+    # -- one clock with the device trace ---------------------------------
+
+    @property
+    def epoch(self) -> float:
+        """The ``perf_counter`` reading every ``ts`` of this ring is
+        relative to (seconds)."""
+        return self._epoch
+
+    def anchor(self) -> Optional[int]:
+        """Write the clock anchor into a LIVE ``jax.profiler`` capture:
+        an instant host annotation named ``stellard.anchor tag=<node
+        tag> pc_ns=<perf_counter now>``, and the same reading as a
+        ``trace.anchor`` instant in the ring. Called by the door that
+        started the capture (the ``profile`` RPC), once at its start
+        and once before its stop (two anchors bound the drift between
+        the two clocks). -> the reading in ns, or None when the tracer
+        is disabled (nothing is written then)."""
+        if not self.enabled:
+            return None
+        from jax.profiler import TraceAnnotation
+
+        pc_ns = int(time.perf_counter() * 1e9)
+        with TraceAnnotation(
+                f"{ANCHOR_PREFIX} tag={self.node_tag:08x} pc_ns={pc_ns}"):
+            pass
+        self.instant("trace.anchor", "trace", pc_ns=pc_ns)
+        return pc_ns
 
     # -- sampling ----------------------------------------------------------
 
@@ -231,6 +295,8 @@ class Tracer:
 
     def _push(self, rec: tuple) -> None:
         with self._lock:
+            if self._parked:
+                self._unpark_locked()
             self._ring[self._n % self.capacity] = rec
             self._n += 1
 
@@ -310,25 +376,52 @@ class Tracer:
 
     def _record_complete(self, token: SpanToken, t1: float, ms: float) -> None:
         with self._lock:
-            hist = self.stage_hist.get(token.name)
-            if hist is None:
-                hist = self.stage_hist[token.name] = LatencyHist(
-                    bounds=STAGE_BOUNDS, interpolate=True
-                )
-            hist.record(ms)
-            self._ring[self._n % self.capacity] = (
-                "X", token.name, token.cat, token.trace, token.span_id,
-                token.parent,
-                int((token.t0 - self._epoch) * 1e6),
-                max(0, int((t1 - token.t0) * 1e6)),
-                token.tid, token.attrs,
-            )
-            self._n += 1
-            if self.propagate and token.trace is not None:
-                self._note_last_locked(token.trace, token.span_id)
+            if self._parked:
+                self._unpark_locked()
+            self._write_locked(token, t1, ms)
         fl = self.flight
         if fl is not None:
             fl.note_span("X", token.name, token.cat, token.trace, ms)
+
+    def _write_locked(self, token: SpanToken, t1: float, ms: float) -> None:
+        hist = self.stage_hist.get(token.name)
+        if hist is None:
+            hist = self.stage_hist[token.name] = LatencyHist(
+                bounds=STAGE_BOUNDS, interpolate=True
+            )
+        hist.record(ms)
+        self._ring[self._n % self.capacity] = (
+            "X", token.name, token.cat, token.trace, token.span_id,
+            token.parent,
+            int((token.t0 - self._epoch) * 1e6),
+            max(0, int((t1 - token.t0) * 1e6)),
+            token.tid, token.attrs,
+        )
+        self._n += 1
+        if self.propagate and token.trace is not None:
+            self._note_last_locked(token.trace, token.span_id)
+
+    def complete_unlocked(self, name: str, cat: str, t0: float, t1: float,
+                          **attrs) -> None:
+        """``complete()`` for a caller that may be running INSIDE this
+        tracer's lock: the collector's hook fires at any allocation, one
+        made under ``_lock`` included, and the lock does not nest. The
+        span is parked (a deque append takes no lock) and moves into the
+        ring under the next lock hold, a record or a dump."""
+        if not self.enabled:
+            return
+        parent_id, attrs = self._resolve_parent(None, None, attrs)
+        token = SpanToken(name, cat, None, self._next_id(), parent_id, t0,
+                          threading.get_ident(), attrs or None)
+        self._parked.append((token, t1))
+        fl = self.flight
+        if fl is not None:
+            fl.note_span("X", name, cat, None, (t1 - t0) * 1000.0)
+
+    def _unpark_locked(self) -> None:
+        while self._parked:
+            token, t1 = self._parked.popleft()
+            self._write_locked(token, t1, (t1 - token.t0) * 1000.0)
 
     def instant(self, name: str, cat: str, txid=None, seq=None, parent=None,
                 **attrs) -> None:
@@ -430,6 +523,8 @@ class Tracer:
         one window, never between two (stage histograms survive a
         window reset; `reset()` clears those too)."""
         with self._lock:
+            if self._parked:
+                self._unpark_locked()
             recorded = self._n
             snap = list(self._snapshot_locked())
             if reset:
@@ -465,6 +560,10 @@ class Tracer:
                 "recorded": recorded,
                 "dropped": max(0, recorded - self.capacity),
                 "sample": self.sample,
+                # the clock: ts is perf_counter minus this epoch, and
+                # anchors in a profiler trace carry this node tag
+                "epoch_ns": int(self._epoch * 1e9),
+                "node_tag": f"{self.node_tag:08x}",
             },
         }
 
@@ -518,6 +617,16 @@ class Tracer:
             "stages": stages,
         }
 
+    def stages(self, prefix: str, names) -> dict:
+        """The stage histograms ``<prefix><name>`` for ``names``, keyed
+        by the bare name: how the close pipeline and the ledger master
+        fill their ``get_counts`` blocks, so that an interval is
+        recorded into ONE histogram. A stage nothing has recorded yet
+        (every one, with the tracer disabled) is left out."""
+        with self._lock:
+            return {n: self.stage_hist[prefix + n] for n in names
+                    if prefix + n in self.stage_hist}
+
     def status_json(self, timeline: bool = True) -> dict:
         """One-call status block for the RPC surfaces: get_json plus —
         for ADMIN surfaces — the recent consensus/close timeline (it
@@ -551,6 +660,133 @@ class Tracer:
             self.stage_hist = {}
             self._foreign = {}
             self._last = {}
+            self._parked.clear()
+
+
+def parse_anchor(event_name: str) -> Optional[tuple[str, int]]:
+    """``stellard.anchor tag=<8 hex> pc_ns=<n>`` -> (tag, pc_ns), or
+    None for any other event of a profiler trace."""
+    m = _ANCHOR.match(event_name)
+    return (m.group(1), int(m.group(2))) if m else None
+
+
+def place_on_trace_clock(anchors: list, epoch_ns: int):
+    """-> f(ts_us) -> ns on the profiler's clock, for a ring whose
+    ``otherData.epoch_ns`` is ``epoch_ns``. ``anchors`` are (pc_ns,
+    trace_ns) pairs of ONE node tag: the ``perf_counter`` reading an
+    anchor carries and where the profiler put the annotation. One anchor
+    gives a constant offset; two or more also take out the drift
+    between the two clocks (a line through the first and the last)."""
+    if not anchors:
+        raise ValueError("no clock anchor")
+    anchors = sorted(anchors)
+    (pc0, tr0), (pc1, tr1) = anchors[0], anchors[-1]
+    rate = (tr1 - tr0) / (pc1 - pc0) if pc1 > pc0 else 1.0
+
+    def place(ts_us: float) -> float:
+        return tr0 + (epoch_ns + ts_us * 1000.0 - pc0) * rate
+
+    return place
+
+
+class _GcProbe:
+    """The interpreter's collector, seen from inside: ONE
+    ``gc.callbacks`` hook per process. ``install(tracer)`` is counted
+    (a node's ``setup``, ``replay_range``/``replay_ledger`` on entry)
+    and does nothing for a disabled tracer; ``remove(tracer)`` takes one
+    installation back and the last one unhooks. Per generation:
+    ``collections``, ``pause_s``, ``collected``. A full collection, and
+    any collection of ``GC_SPAN_MIN_S`` or more, is also a
+    ``gc.collect`` span in every installed tracer's ring (``len(gc.
+    get_objects())`` is not taken: it is itself a scan).
+
+    The hook runs for every young collection too (two calls, a clock
+    read and three adds each; its measured share of the interpreter's
+    time is in PERF.md)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # id(tracer) -> [weak reference, installations]: a tracer that
+        # installed twice (replay_range and the replay_ledger inside it)
+        # still gets each collection once. Weak: a node dropped without
+        # stop() must not pin its ring
+        self._tracers: dict[int, list] = {}
+        self.installs = 0
+        self.collections = [0, 0, 0]
+        self.pause_s = [0.0, 0.0, 0.0]
+        self.collected = [0, 0, 0]
+        self._t0 = 0.0
+
+    def _prune_locked(self) -> None:
+        for key in [k for k, (r, _n) in self._tracers.items()
+                    if r() is None]:
+            del self._tracers[key]
+
+    def install(self, tracer: "Tracer") -> bool:
+        if not tracer.enabled:
+            return False
+        with self._lock:
+            self._prune_locked()
+            slot = self._tracers.setdefault(
+                id(tracer), [weakref.ref(tracer), 0])
+            slot[1] += 1
+            self.installs += 1
+            if self._on_gc not in gc.callbacks:
+                gc.callbacks.append(self._on_gc)
+        return True
+
+    def remove(self, tracer: "Tracer") -> None:
+        with self._lock:
+            slot = self._tracers.get(id(tracer))
+            if slot is not None:
+                slot[1] -= 1
+                if slot[1] <= 0:
+                    del self._tracers[id(tracer)]
+            self._prune_locked()
+            if not self._tracers and self._on_gc in gc.callbacks:
+                gc.callbacks.remove(self._on_gc)
+
+    @property
+    def installed(self) -> int:
+        return sum(n for _r, n in self._tracers.values())
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never nest and hold the interpreter lock, so one
+        # slot for the start reading is enough
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        t1 = time.perf_counter()
+        gen = info["generation"]
+        dt = t1 - self._t0
+        self.collections[gen] += 1
+        self.pause_s[gen] += dt
+        self.collected[gen] += info["collected"]
+        if gen == 2 or dt >= GC_SPAN_MIN_S:
+            for ref, _n in list(self._tracers.values()):
+                tr = ref()
+                if tr is not None:
+                    # never complete(): this thread may hold tr's lock
+                    tr.complete_unlocked(
+                        "gc.collect", "runtime", self._t0, t1,
+                        generation=gen, collected=info["collected"])
+
+    def pause_total_s(self) -> float:
+        return sum(self.pause_s)
+
+    def get_json(self) -> dict:
+        """Flat, for ``get_counts.runtime.gc`` and the ``gc`` collector
+        hook (``gc.gen2_pause_s`` on ``/metrics``)."""
+        out: dict = {"installed": self.installed,
+                     "installs": self.installs}
+        for g in range(3):
+            out[f"gen{g}_collections"] = self.collections[g]
+            out[f"gen{g}_pause_s"] = round(self.pause_s[g], 6)
+            out[f"gen{g}_collected"] = self.collected[g]
+        return out
+
+
+GC_PROBE = _GcProbe()
 
 
 # module-level default: subsystems constructed outside a Node (unit

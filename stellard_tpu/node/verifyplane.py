@@ -149,7 +149,18 @@ class _LatencyModel:
     def route(self, n: int, count: bool = True,
               arms: Optional[Sequence[str]] = None) -> str:
         """Pick the side for this batch: ``"cpu"`` or a device arm
-        name. Unmeasured arms are explored optimistically (in declared
+        name (``decide`` without its evidence)."""
+        return self.decide(n, count=count, arms=arms)[0]
+
+    def decide(self, n: int, count: bool = True,
+               arms: Optional[Sequence[str]] = None) -> tuple:
+        """-> (side, why, expected device ms, expected cpu ms): the side
+        for this batch (``"cpu"`` or a device arm name) and the evidence
+        behind it, which rides the ``verify.batch`` span. ``why`` is one
+        word: ``small`` (under min_device_batch, or no device arm),
+        ``explore`` (an unmeasured arm, or the periodic retry of a
+        losing one), ``priced`` (both sides measured, the cheaper one
+        taken). Unmeasured arms are explored optimistically (in declared
         order) once a batch reaches min_device_batch, after which real
         measurements drive every later decision. `count=False` asks the
         same question without advancing the re-exploration counters
@@ -158,21 +169,23 @@ class _LatencyModel:
         avail = [a for a in (arms if arms is not None else self.device_arms)
                  if a in self._bucket_ms]
         if n < self.min_device_batch or not avail:
-            return "cpu"
+            return "cpu", "small", None, None
         costs: dict[str, float] = {}
         for a in avail:
             d = self.expected_device_ms(n, a)
             if d is None:
-                return a  # explore: one measurement teaches the model
+                # explore: one measurement teaches the model
+                return a, "explore", None, None
             costs[a] = d
+        best_arm = min(costs, key=lambda a: costs[a])
+        best = costs[best_arm]
         cpu = self.expected_cpu_ms(n)
         if cpu is None:
-            return "cpu"  # CPU unmeasured: measure it too
-        best_arm = min(costs, key=lambda a: costs[a])
-        if costs[best_arm] < cpu:
-            return best_arm
+            return "cpu", "explore", best, None  # CPU unmeasured: measure it too
+        if best < cpu:
+            return best_arm, "priced", best, cpu
         if not count:
-            return "cpu"
+            return "cpu", "priced", best, cpu
         # periodic re-exploration so a stale loss can be unlearned — but
         # only within striking distance: a ~300 ms kernel invocation must
         # never be retried on a 64-sig batch it cannot possibly win
@@ -183,8 +196,8 @@ class _LatencyModel:
                 self._since[a] += 1
                 if self._since[a] >= self.REEXPLORE_EVERY:
                     self._since[a] = 0
-                    return a
-        return "cpu"
+                    return a, "explore", costs[a], cpu
+        return "cpu", "priced", best, cpu
 
     def use_device(self, n: int, count: bool = True) -> bool:
         return self.route(n, count=count) != "cpu"
@@ -198,6 +211,10 @@ class _LatencyModel:
                 ),
                 "arms": {a: dict(b) for a, b in self._bucket_ms.items()},
             }
+
+
+# why a batch ran on the host arm (the `why` of its `verify.batch` span)
+_HOST_REASONS = ("small", "priced", "explore", "cold", "wedged", "nodevice")
 
 
 class VerifyPlane:
@@ -309,6 +326,10 @@ class VerifyPlane:
         # the CPU arm (prewarm pending, cost routing, wedge, failure):
         # under routing=device a healthy warm plane keeps this at zero
         self.cpu_eligible_batches = 0
+        # signatures the host arm verified, by the reason the batch
+        # stayed there (the `why` of its `verify.batch` span): which of
+        # batch size and price keeps the chip idle
+        self.host_sigs_by_why: dict[str, int] = {}
         # per-arm routing counters (provenance: which kernel width the
         # device traffic actually ran on)
         self._arm_batches: dict[str, int] = {
@@ -453,6 +474,23 @@ class VerifyPlane:
             sizes = sorted(set(ladder))
         if self._device_capable:
             self._prewarm_pending = True
+        # set-up on the timeline: `node.prewarm` is this thread's life,
+        # with one `prewarm.program` child for every program the
+        # process built or loaded from the compile cache meanwhile (name,
+        # cache_hit; what utils.xlacache.COMPILES times)
+        from ..utils.xlacache import COMPILES
+
+        tr = self.tracer
+        span = tr.begin("node.prewarm", "setup", sizes=list(sizes),
+                        backend=self.backend_name)
+
+        def on_program(name: str, hit: bool, secs: float) -> None:
+            t1 = time.perf_counter()
+            tr.complete("prewarm.program", "setup", t1 - secs, t1,
+                        parent=span, program=name, cache_hit=bool(hit))
+
+        if span is not None and self._device_capable:
+            COMPILES.observers.append(on_program)
 
         def run() -> None:
             try:
@@ -500,6 +538,10 @@ class VerifyPlane:
                 log.exception("verify prewarm FAILED; device unwarmed")
             finally:
                 self._prewarm_pending = False
+                if on_program in COMPILES.observers:
+                    COMPILES.observers.remove(on_program)
+                tr.end(span, error=self.prewarm_error,
+                       wedged=self.device_wedged)
 
         t = threading.Thread(target=run, name="verify-prewarm", daemon=True)
         t.start()
@@ -509,13 +551,31 @@ class VerifyPlane:
         if not reqs:
             return np.zeros(0, bool)
         n = len(reqs)
-        arm = "cpu"
+        # the router's evidence, on the batch's span: `why` is one word
+        # (small | priced | explore from the cost model; forced under
+        # routing=device; cold while the prewarm runs; wedged once the
+        # device plane is retired; nodevice on a cpu-only backend)
+        arm, exp_dev, exp_cpu = "cpu", None, None
         if self._device_capable and not self._prewarm_pending:
             if self._route_by_cost:
-                arm = self.model.route(n, arms=self._device_arms())
+                arm, why, exp_dev, exp_cpu = self.model.decide(
+                    n, arms=self._device_arms())
             elif n >= self.min_device_batch:
                 # forced-device mode: the widest available arm
-                arm = self._device_arms()[-1]
+                arm, why = self._device_arms()[-1], "forced"
+            else:
+                why = "small"
+        elif self._prewarm_pending:
+            why = "cold"
+        elif self.device_wedged or self.device_failed:
+            why = "wedged"
+        else:
+            why = "nodevice"
+        evidence = {"why": why}
+        if exp_dev is not None:
+            evidence["exp_device_ms"] = round(exp_dev, 3)
+        if exp_cpu is not None:
+            evidence["exp_cpu_ms"] = round(exp_cpu, 3)
         wedged_now = False
         if arm != "cpu":
             ver = self._verifier_of(arm)
@@ -542,7 +602,7 @@ class VerifyPlane:
                 # the name), kernel wall time as the span duration
                 self.tracer.complete(
                     "verify.batch", "verify", t0, t1,
-                    n=n, routed=arm if arm != "device" else "device",
+                    n=n, routed=arm, **evidence,
                 )
                 return out
             except DeviceWedged as exc:
@@ -552,6 +612,7 @@ class VerifyPlane:
                 self._device_capable = False
                 self.device_wedged = True
                 wedged_now = True
+                evidence["why"] = "wedged"
                 log.error("verify plane: %s — falling back to CPU", exc)
             except Exception as exc:  # noqa: BLE001 — a device failure is not a verdict
                 # the arm raised instead of answering: no signature in
@@ -562,6 +623,7 @@ class VerifyPlane:
                 self.device_failed = True
                 self.device_error = f"{type(exc).__name__}: {exc}"[:2000]
                 wedged_now = True
+                evidence["why"] = "wedged"
                 log.exception(
                     "verify plane: device arm %s RAISED on a %d-signature "
                     "batch — device plane disabled, falling back to CPU",
@@ -581,11 +643,14 @@ class VerifyPlane:
             self.model.observe_cpu(n, ms)
         self.cpu_batches += 1
         self.cpu_sigs += n
+        why = evidence["why"]
+        self.host_sigs_by_why[why] = self.host_sigs_by_why.get(why, 0) + n
         self._record("cpu", ms)
         self.batches += 1
         self.verified += n
         self.tracer.complete(
             "verify.batch", "verify", t0, t1, n=n, routed="cpu",
+            **evidence,
             **({"wedged_fallback": True} if wedged_now else {}),
         )
         return out
@@ -644,6 +709,10 @@ class VerifyPlane:
             "device_sigs": self.device_sigs,
             "cpu_sigs": self.cpu_sigs,
             "cpu_eligible_batches": self.cpu_eligible_batches,
+            # why the host arm's signatures stayed there: too small a
+            # batch for the chip, priced out by the cost model, ...
+            **{f"host_{why}_sigs": self.host_sigs_by_why.get(why, 0)
+               for why in _HOST_REASONS},
             "device_wedged": self.device_wedged,
             "device_failed": self.device_failed,
             "device_error": self.device_error,

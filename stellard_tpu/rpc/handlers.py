@@ -644,6 +644,15 @@ def do_get_counts(ctx: Context) -> dict:
     tracer = getattr(node, "tracer", None)
     if tracer is not None:
         out["trace"] = tracer.status_json()  # ADMIN method: timeline ok
+    # the runtime and the door seen from inside: the collector's
+    # collections and pauses per generation (node/tracer.py GC_PROBE),
+    # the HTTP door's requests, busy seconds and event-loop lag
+    from ..node.tracer import GC_PROBE
+
+    out["runtime"] = {"gc": GC_PROBE.get_json()}
+    door = getattr(node, "http_server", None)
+    if door is not None:
+        out["rpc_door"] = door.get_json()
     # resource-pricing plane (`resource.*`): per-endpoint charge
     # balances + warn/drop/refuse/throttle evidence for the peer
     # overlay and the RPC doors (doc/overlay.md charging schedule)
@@ -2117,6 +2126,10 @@ def do_profile(ctx: Context) -> dict:
     what the device actually executes — TensorBoard XPlane format.
 
     params: {"action": "start"|"stop"|"status", "dir": optional path}
+
+    `start` and `stop` write the tracer's clock anchor into the capture
+    (node/tracer.py `anchor()`), so a `trace_dump` taken beside it lands
+    on the same timeline: `tools/traceview.py <dump> --xplane <file>`.
     """
     import jax
 
@@ -2136,12 +2149,16 @@ def do_profile(ctx: Context) -> dict:
         except Exception as exc:  # noqa: BLE001 — surface, don't crash the door
             raise RPCError("internal", f"profiler start failed: {exc}") from exc
         node._trace_dir = trace_dir
-        return {"status": "tracing", "dir": trace_dir}
+        # the clock anchor: places every span of `trace_dump` on this
+        # capture's clock (tools/traceview.py --xplane)
+        return {"status": "tracing", "dir": trace_dir,
+                "anchor_pc_ns": node.tracer.anchor()}
     if action == "stop":
         trace_dir = getattr(node, "_trace_dir", None)
         if not trace_dir:
             raise RPCError("internal", "no trace running")
         try:
+            node.tracer.anchor()  # a second anchor bounds the drift
             jax.profiler.stop_trace()
         finally:
             node._trace_dir = None

@@ -14,21 +14,32 @@ from __future__ import annotations
 import asyncio
 import json
 import threading
+import time
 from typing import Optional
 
 from .errors import RPCError
-from .handlers import Context, Role, dispatch
+from .handlers import HANDLERS, Context, Role, dispatch
 
 __all__ = ["HttpRpcServer", "process_http_request"]
 
 _MAX_BODY = 10 * 1024 * 1024
 
+# the lag probe on the door's own event loop: a tick every LAG_TICK_S
+# measures how late it ran; a tick LAG_SPAN_MIN_S or more late is an
+# `rpc.loop_lag` span (the time work waited for the door, measured
+# where the work waits)
+LAG_TICK_S = 0.050
+LAG_SPAN_MIN_S = 0.025
+
 
 def process_http_request(node, body: bytes, role: Role = Role.ADMIN,
-                         client_ip: str = "") -> dict:
+                         client_ip: str = "",
+                         seen: Optional[dict] = None) -> dict:
     """Decode one JSON-RPC request body → response object. Non-admin
     requests charge the client's resource balance (FEE_*_RPC schedule);
-    a client past the drop line gets rpcSLOW_DOWN until it decays."""
+    a client past the drop line gets rpcSLOW_DOWN until it decays.
+    With ``seen`` (the door's counters), the method name is left in
+    ``seen["method"]``."""
     from .handlers import charge_rpc_client
 
     try:
@@ -46,6 +57,8 @@ def process_http_request(node, body: bytes, role: Role = Role.ADMIN,
         refused = charge_rpc_client(node, client_ip, None, role)
         err = refused or RPCError("unknownCmd").to_json()
         return {"result": err | {"status": "error"}}
+    if seen is not None:
+        seen["method"] = method
     refused = charge_rpc_client(node, client_ip, method, role)
     if refused is not None:
         result = refused | {"status": "error"}
@@ -87,6 +100,19 @@ class HttpRpcServer:
         self._thread: Optional[threading.Thread] = None
         self._started = threading.Event()
         self._server = None
+        # the door seen from inside (get_json(), the `rpc` collector
+        # hook): every request counts; one in `1/sample` is an
+        # `rpc.request` span. Only the loop thread writes these.
+        self.tracer = getattr(node, "tracer", None)
+        sample = self.tracer.sample if self.tracer is not None else 0.0
+        self._span_every = max(1, round(1.0 / sample)) if sample > 0 else 0
+        self.requests = 0
+        self.errors = 0
+        self.busy_s = 0.0
+        self.by_method: dict[str, int] = {}
+        self.lag_ticks = 0
+        self.lag_late_ticks = 0
+        self.lag_s = 0.0
 
     # -- protocol ---------------------------------------------------------
 
@@ -108,6 +134,9 @@ class HttpRpcServer:
                     await writer.drain()
                     return
                 body = await reader.readexactly(length) if length else b""
+                t_read = time.perf_counter()
+                seen = {"method": "GET"}
+                failed = False
                 status_line = b"HTTP/1.1 200 OK\r\n"
                 ctype = b"Content-Type: application/json\r\n"
                 if request_line.startswith("GET"):
@@ -132,6 +161,7 @@ class HttpRpcServer:
                             status_line = (
                                 b"HTTP/1.1 429 Too Many Requests\r\n"
                             )
+                            failed = True
                             payload = b"slow down\n"
                             ctype = b"Content-Type: text/plain\r\n"
                         else:
@@ -144,13 +174,14 @@ class HttpRpcServer:
                         payload = b'{"status": "ok"}'
                 else:
                     peer = writer.get_extra_info("peername")
-                    payload = json.dumps(
-                        process_http_request(
-                            self.node, body,
-                            _role_for_peer(self.node, writer),
-                            client_ip=peer[0] if peer else "",
-                        )
-                    ).encode()
+                    reply = process_http_request(
+                        self.node, body,
+                        _role_for_peer(self.node, writer),
+                        client_ip=peer[0] if peer else "",
+                        seen=seen,
+                    )
+                    failed = reply["result"].get("status") == "error"
+                    payload = json.dumps(reply).encode()
                 writer.write(
                     status_line + ctype
                     + f"Content-Length: {len(payload)}\r\n".encode()
@@ -158,6 +189,8 @@ class HttpRpcServer:
                     + payload
                 )
                 await writer.drain()
+                self._note_request(seen["method"], failed, t_read,
+                                   len(body), len(payload))
                 if headers.get("connection", "").lower() == "close":
                     break
         except (asyncio.IncompleteReadError, ConnectionError,
@@ -165,6 +198,54 @@ class HttpRpcServer:
             pass
         finally:
             writer.close()
+
+    def _note_request(self, method, failed: bool, t_read: float,
+                      bytes_in: int, bytes_out: int) -> None:
+        """One answered request: body read to response written."""
+        t_done = time.perf_counter()
+        self.requests += 1
+        self.busy_s += t_done - t_read
+        if failed:
+            self.errors += 1
+        # names come from outside: only the handler table's (and GET)
+        # get a counter of their own
+        if method != "GET" and method not in HANDLERS:
+            method = "?"
+        self.by_method[method] = self.by_method.get(method, 0) + 1
+        if self._span_every and self.requests % self._span_every == 0:
+            self.tracer.complete(
+                "rpc.request", "rpc", t_read, t_done, method=method,
+                status="error" if failed else "ok",
+                bytes_in=bytes_in, bytes_out=bytes_out)
+
+    def _lag_tick(self, due: float) -> None:
+        """Runs on the door's loop every LAG_TICK_S: how late is the
+        time the loop could not run (a handler, a collection, a close
+        holding the interpreter)."""
+        now = time.perf_counter()
+        late = now - due
+        self.lag_ticks += 1
+        if late >= LAG_SPAN_MIN_S:
+            self.lag_late_ticks += 1
+            self.lag_s += late
+            self.tracer.complete("rpc.loop_lag", "rpc", due, now)
+        nxt = max(now, due) + LAG_TICK_S
+        self._loop.call_later(max(0.0, nxt - time.perf_counter()),
+                              self._lag_tick, nxt)
+
+    def get_json(self) -> dict:
+        """The door's counters (``get_counts.rpc_door``, the ``rpc``
+        collector hook: ``rpc.requests``, ``rpc.busy_s``, ``rpc.errors``,
+        ``rpc.lag_s`` on ``/metrics``)."""
+        return {
+            "requests": self.requests,
+            "errors": self.errors,
+            "busy_s": round(self.busy_s, 6),
+            "lag_s": round(self.lag_s, 6),
+            "lag_ticks": self.lag_ticks,
+            "lag_late_ticks": self.lag_late_ticks,
+            "by_method": dict(self.by_method),
+        }
 
     def _metrics_payload(self) -> bytes:
         """One /metrics scrape: every collector instrument plus the
@@ -200,6 +281,8 @@ class HttpRpcServer:
                 ssl=self._ssl,
             )
             self.port = self._server.sockets[0].getsockname()[1]
+            if self.tracer is not None and self.tracer.enabled:
+                self._lag_tick(time.perf_counter())
             self._started.set()
 
         self._loop.run_until_complete(boot())

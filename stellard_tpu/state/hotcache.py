@@ -93,6 +93,12 @@ class HotNodeCache:
         self.evictions = 0
         self.evicted_bytes = 0
         self.epoch_first_evictions = 0  # victims taken for being old-epoch
+        # what eviction costs when it has to LOOK for victims: passes 0
+        # and 1 build a list of the entries that qualify by walking the
+        # whole table (pass 2 pops the LRU head and walks nothing)
+        self.evict_scans = 0      # such walks
+        self.evict_scanned = 0    # entries walked by them
+        self.evict_scan_s = 0.0   # seconds inside them
 
     # -- configuration / epochs -------------------------------------------
 
@@ -210,7 +216,12 @@ class HotNodeCache:
         # whole subtree — TaggedCache-parity semantics for the eager
         # from_store role)
         if self._eager_count > EAGER_ENTRY_CAP:
-            for key in [k for k, e in self._data.items() if e[3]]:
+            t0 = time.perf_counter()
+            victims = [k for k, e in self._data.items() if e[3]]
+            self.evict_scan_s += time.perf_counter() - t0
+            self.evict_scans += 1
+            self.evict_scanned += len(self._data)
+            for key in victims:
                 if self._eager_count <= EAGER_ENTRY_CAP:
                     break
                 _n, cost, _e, _eager = self._data.pop(key)
@@ -224,9 +235,12 @@ class HotNodeCache:
         # current-epoch working set survives a cold history scan)
         cur = self.epoch
         if any(e[2] < cur for e in self._data.values()):
-            for key in [
-                k for k, e in self._data.items() if e[2] < cur
-            ]:
+            t0 = time.perf_counter()
+            victims = [k for k, e in self._data.items() if e[2] < cur]
+            self.evict_scan_s += time.perf_counter() - t0
+            self.evict_scans += 1
+            self.evict_scanned += len(self._data)
+            for key in victims:
                 if self.resident_bytes <= self.limit_bytes:
                     return
                 _node, cost, _e, eager = self._data.pop(key)
@@ -277,4 +291,7 @@ class HotNodeCache:
                 "evictions": self.evictions,
                 "evicted_bytes": self.evicted_bytes,
                 "epoch_first_evictions": self.epoch_first_evictions,
+                "evict_scans": self.evict_scans,
+                "evict_scanned": self.evict_scanned,
+                "evict_scan_s": round(self.evict_scan_s, 6),
             }

@@ -433,7 +433,8 @@ class Ledger:
     @classmethod
     def load(cls, db: Database, ledger_hash: bytes,
              hash_batch: Optional[Callable] = None,
-             lazy: bool = False, cold: bool = False) -> "Ledger":
+             lazy: bool = False, cold: bool = False,
+             tracer=None) -> "Ledger":
         """Rebuild a ledger (header + both trees) from the NodeStore —
         the checkpoint/resume path (reference: Application loadOldLedger,
         Ledger::Ledger(blob) Ledger.cpp:120-175).
@@ -444,7 +445,41 @@ class Ledger:
         on first touch. Opening a million-account ledger is O(1); the
         eager path's whole-tree hash re-verification is traded for
         per-node content verification at fault time (the same check,
-        paid lazily)."""
+        paid lazily).
+
+        The whole call is a ``ledger.load`` span (boot with
+        ``start_up=load``, a follower's fetch and catch-up all pass
+        through here): seq, lazy, the nodes it fetched and what the
+        hot-node cache did meanwhile. ``tracer`` defaults to the hot
+        cache's (the node's) and then to the process tracer."""
+        from ..node.tracer import get_tracer
+        from .shamap import inner_node_cache
+
+        cache = inner_node_cache()
+        tr = tracer or cache.tracer or get_tracer()
+        with tr.span("ledger.load", "state", lazy=bool(lazy)) as span:
+            if span is None:  # tracer disabled
+                return cls._load(db, ledger_hash, hash_batch, lazy, cold,
+                                 None)
+            before = (cache.hits, cache.misses, cache.evict_scans,
+                      cache.evict_scan_s)
+            seen: dict = {}
+            try:
+                return cls._load(db, ledger_hash, hash_batch, lazy, cold,
+                                 seen)
+            finally:
+                span.attrs = {
+                    **span.attrs, **seen,
+                    "cache_hits": cache.hits - before[0],
+                    "cache_misses": cache.misses - before[1],
+                    "evict_scans": cache.evict_scans - before[2],
+                    "evict_scan_s": round(
+                        cache.evict_scan_s - before[3], 6),
+                }
+
+    @classmethod
+    def _load(cls, db: Database, ledger_hash: bytes, hash_batch, lazy: bool,
+              cold: bool, seen: Optional[dict]) -> "Ledger":
         obj = db.fetch(ledger_hash)
         if obj is None:
             raise KeyError(f"missing ledger {ledger_hash.hex()}")
@@ -452,6 +487,8 @@ class Ledger:
         if int.from_bytes(body[:4], "big") == HP_LEDGER_MASTER:
             body = body[4:]
         f = parse_header(body)
+        if seen is not None:
+            seen["seq"] = f["seq"]
 
         fetched: set[bytes] = set()
 
@@ -497,4 +534,6 @@ class Ledger:
             # stay rewritable); the lazy path never claims this — each
             # node verifies at fault time instead
             db.flushed.update(fetched)
+            if seen is not None:
+                seen["nodes_fetched"] = len(fetched)
         return led

@@ -78,6 +78,10 @@ class CompileMeter:
         # name -> [requests, cache_hits, seconds]
         self._programs: dict[str, list] = {}
         self._installed = False
+        # called as fn(name, cache_hit, seconds) when a program has been
+        # built or loaded, on the thread that asked for it (the verify
+        # prewarm records its `prewarm.program` spans through this)
+        self.observers: list = []
 
     def _on_event(self, event: str, **_kw) -> None:
         if event == _CACHE_HIT_EVENT:
@@ -94,6 +98,8 @@ class CompileMeter:
             slot[0] += 1
             slot[1] += 1 if hit else 0
             slot[2] += float(secs)
+        for fn in list(self.observers):
+            fn(name, hit, float(secs))
 
     def install(self) -> None:
         """Register the listeners (once; jax keeps them for the life of

@@ -25,18 +25,33 @@ three things an operator (and the tier-1 gate) needs around that RPC:
                 python tools/traceview.py --merge \\
                     http://127.0.0.1:5005 http://127.0.0.1:5006 \\
                     -o merged.json
+  xplane    put a saved dump and the device trace of a `profile`
+            capture on ONE timeline. `profile` start/stop write the
+            tracer's clock anchor into the XPlane (node/tracer.py
+            `anchor()`), the dump carries the tracer's epoch, so every
+            span lands on the profiler's clock. The output holds the
+            program's spans and the device's `XLA Modules` events (one
+            per executed program, not its hundred thousand operations),
+            and the idle seconds of the device by the innermost span
+            the host was in are printed:
+                python tools/traceview.py --validate trace.json \\
+                    --xplane /tmp/trace/plugins/profile/*/*.xplane.pb \\
+                    -o timeline.json
 
 The schema validator is hand-rolled (no jsonschema dependency) against
 the trace-event format's documented requirements; `validate_chrome_trace`,
 `validate_span_trees`, `merge_dumps` and `validate_merged_trace` are
-importable by tests.
+importable by tests; so are the clock join (`xplane_anchors`,
+`place_spans`, `join_xplane`) and `idle_by_span`.
 """
 
 from __future__ import annotations
 
 import argparse
+import heapq
 import json
 import os
+import re
 import sys
 import urllib.request
 
@@ -228,6 +243,223 @@ def validate_merged_trace(obj, min_processes: int = 3) -> list[str]:
     return problems
 
 
+# -- one timeline with the device trace (--xplane) --------------------------
+
+_DEVICE_PLANE = re.compile(r"^/device:[A-Za-z]+:\d+$")
+_MODULES_LINE = "XLA Modules"
+_OPS_LINE = "XLA Ops"
+NO_SPAN = "span:none"
+
+
+def xplane_anchors(profile) -> dict[str, list[tuple[int, int]]]:
+    """The clock anchors of a profiler trace, by node tag: (pc_ns,
+    trace_ns) pairs, the `perf_counter` reading each anchor's name
+    carries and where the profiler put it on its own clock."""
+    from stellard_tpu.node.tracer import parse_anchor
+
+    out: dict[str, list[tuple[int, int]]] = {}
+    for plane in profile.planes:
+        if _DEVICE_PLANE.match(plane.name):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                hit = parse_anchor(ev.name)
+                if hit is not None:
+                    out.setdefault(hit[0], []).append(
+                        (hit[1], int(ev.start_ns)))
+    return out
+
+
+def place_spans(dump: dict, profile) -> tuple[list[dict], dict]:
+    """-> (the dump's events with `ts` moved to the profiler's clock, in
+    microseconds; what the join rested on). Every event is placed the
+    same way, a span recorded after the fact (`complete()`) or ended on
+    another thread included: its `ts` is `perf_counter` minus the
+    tracer's epoch, the anchor gives `perf_counter` on the trace's
+    clock. Raises ValueError when the dump carries no epoch or the
+    trace no anchor of this dump's node tag."""
+    from stellard_tpu.node.tracer import place_on_trace_clock
+
+    other = dump.get("otherData") or {}
+    if "epoch_ns" not in other or "node_tag" not in other:
+        raise ValueError("the dump carries no epoch_ns/node_tag "
+                         "(written by a tracer that predates the anchor)")
+    anchors = xplane_anchors(profile).get(other["node_tag"])
+    if not anchors:
+        raise ValueError(
+            f"the trace holds no clock anchor of node tag "
+            f"{other['node_tag']} (was the capture started through the "
+            f"`profile` RPC of this node?)")
+    place = place_on_trace_clock(anchors, int(other["epoch_ns"]))
+    placed = []
+    for ev in dump.get("traceEvents", []):
+        ev = dict(ev)
+        ev["ts"] = place(ev["ts"]) / 1000.0
+        placed.append(ev)
+    first, last = min(anchors), max(anchors)
+    info = {
+        "anchors": len(anchors),
+        "offset_ns": first[1] - first[0],
+        # how far the two clocks moved apart between the first and the
+        # last anchor (0 with one anchor)
+        "drift_ns": (last[1] - last[0]) - (first[1] - first[0]),
+        "anchored_s": (last[0] - first[0]) / 1e9,
+    }
+    return placed, info
+
+
+def device_events(profile) -> dict[str, dict[str, list]]:
+    """-> {device plane: {line: [(name, start_ns, end_ns)]}} for the
+    `XLA Modules` and `XLA Ops` lines."""
+    out: dict[str, dict[str, list]] = {}
+    for plane in profile.planes:
+        if not _DEVICE_PLANE.match(plane.name):
+            continue
+        lines = out.setdefault(plane.name, {})
+        for line in plane.lines:
+            if line.name in (_MODULES_LINE, _OPS_LINE):
+                lines[line.name] = [
+                    (ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in line.events]
+    return out
+
+
+def join_xplane(dump: dict, profile) -> tuple[dict, dict]:
+    """-> (ONE Chrome trace: the program's spans as process 1 and a
+    process per device plane holding its `XLA Modules` events, all on
+    the profiler's clock counted from the first event; the join's
+    info, with `idle` = `idle_by_span` over the first device)."""
+    placed, info = place_spans(dump, profile)
+    devices = device_events(profile)
+    starts = [ev["ts"] for ev in placed]
+    for lines in devices.values():
+        starts += [s / 1000.0 for _n, s, _e in lines.get(_MODULES_LINE, [])]
+    t0 = min(starts) if starts else 0.0
+    # (name, tid, start ns, end ns) of the complete spans, for the idle rows
+    quads = [(ev["name"], ev["tid"], ev["ts"] * 1000.0,
+              (ev["ts"] + ev["dur"]) * 1000.0)
+             for ev in placed if ev.get("ph") == "X"]
+    other = dump.get("otherData") or {}
+    events = [{"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
+               "ts": 0, "args": {"name": f"stellard {other['node_tag']}"}}]
+    for ev in placed:
+        ev["ts"] -= t0
+        ev["pid"] = 1
+        events.append(ev)
+    for pid, (plane, lines) in enumerate(sorted(devices.items()), start=2):
+        events.append({"name": "process_name", "ph": "M", "pid": pid,
+                       "tid": 0, "ts": 0, "args": {"name": plane}})
+        events.append({"name": "thread_name", "ph": "M", "pid": pid,
+                       "tid": 1, "ts": 0, "args": {"name": _MODULES_LINE}})
+        for name, s, e in lines.get(_MODULES_LINE, []):
+            events.append({"name": name.split("(", 1)[0], "cat": "device",
+                           "ph": "X", "ts": s / 1000.0 - t0,
+                           "dur": (e - s) / 1000.0, "pid": pid, "tid": 1,
+                           "args": {"module": name}})
+    if devices:
+        first = devices[sorted(devices)[0]]
+        busy = [(s, e) for _n, s, e in
+                first.get(_OPS_LINE) or first.get(_MODULES_LINE, [])]
+        edges = [t for iv in busy + [(q[2], q[3]) for q in quads] for t in iv]
+        info["idle"] = idle_by_span(
+            quads, busy, min(edges, default=0.0), max(edges, default=0.0))
+    return ({"traceEvents": events, "displayTimeUnit": "ms",
+             "otherData": {**other, "join": {
+                 k: v for k, v in info.items() if k != "idle"}}}, info)
+
+
+def _merge(intervals) -> list[list[float]]:
+    """Sorted union of (start, end) intervals."""
+    out: list[list[float]] = []
+    for s, e in sorted(intervals):
+        if e <= s:
+            continue
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
+        else:
+            out.append([s, e])
+    return out
+
+
+def _innermost_segments(spans: list) -> list[tuple[float, float, str]]:
+    """One thread's spans (name, start, end) -> disjoint (start, end,
+    name) segments, each named by the INNERMOST span open there: the one
+    that started last (self time, so a nested span is never counted in
+    its parent's row too)."""
+    bounds = sorted({t for _n, s, e in spans for t in (s, e)})
+    by_start = sorted(spans, key=lambda x: x[1])
+    heap: list = []  # (-start, end, name): the latest start on top
+    out: list[tuple[float, float, str]] = []
+    k = 0
+    for lo, hi in zip(bounds, bounds[1:]):
+        while k < len(by_start) and by_start[k][1] <= lo:
+            n, s, e = by_start[k]
+            heapq.heappush(heap, (-s, e, n))
+            k += 1
+        while heap and heap[0][1] <= lo:
+            heapq.heappop(heap)
+        if heap:
+            name = heap[0][2]
+            if out and out[-1][2] == name and out[-1][1] == lo:
+                out[-1] = (out[-1][0], hi, name)
+            else:
+                out.append((lo, hi, name))
+    return out
+
+
+def idle_by_span(spans: list, busy: list, lo: float, hi: float) -> dict:
+    """The device's idle time inside [lo, hi] by what the host was
+    doing: `spans` are (name, tid, start, end) and `busy` the device's
+    (start, end) intervals, all on one clock (any unit; the rows come
+    back in it). Each instant of an idle gap goes, for every host
+    thread, to the innermost span open on that thread (`span:<name>`),
+    and to `span:none` where no thread has a span open. Rows of one
+    thread never double count; rows of different threads may overlap,
+    so the rows can add up to more than `idle` (and without `span:none`
+    to less)."""
+    merged = _merge((max(s, lo), min(e, hi)) for s, e in busy)
+    gaps, cursor = [], lo
+    for s, e in merged:
+        if s > cursor:
+            gaps.append((cursor, s))
+        cursor = max(cursor, e)
+    if hi > cursor:
+        gaps.append((cursor, hi))
+
+    def overlap(segments: list, rows: dict | None) -> float:
+        """Two-pointer walk of disjoint sorted segments against the
+        gaps; -> the overlap in all, and by name into `rows`."""
+        total, g = 0.0, 0
+        for seg in segments:
+            s, e = seg[0], seg[1]
+            while g < len(gaps) and gaps[g][1] <= s:
+                g += 1
+            j = g
+            while j < len(gaps) and gaps[j][0] < e:
+                part = min(e, gaps[j][1]) - max(s, gaps[j][0])
+                if part > 0:
+                    total += part
+                    if rows is not None:
+                        key = f"span:{seg[2]}"
+                        rows[key] = rows.get(key, 0.0) + part
+                j += 1
+        return total
+
+    rows: dict[str, float] = {}
+    by_tid: dict = {}
+    for name, tid, s, e in spans:
+        if e > s:
+            by_tid.setdefault(tid, []).append((name, s, e))
+    covered: list[list[float]] = []
+    for tid_spans in by_tid.values():
+        segments = _innermost_segments(tid_spans)
+        overlap(segments, rows)
+        covered.extend([s, e] for s, e, _n in segments)
+    idle = sum(e - s for s, e in gaps)
+    rows[NO_SPAN] = max(0.0, idle - overlap(_merge(covered), None))
+    return {"idle": idle, "window": hi - lo, "rows": rows}
+
+
 def fetch_dump(url: str, reset: bool = False, timeout: float = 30.0) -> dict:
     """POST trace_dump to a node's HTTP RPC door; -> the trace object."""
     body = json.dumps({
@@ -332,6 +564,38 @@ def run_smoke(n_txs: int = 200, out: str | None = None) -> int:
     return 0
 
 
+def run_xplane(dump: dict, xplane_path: str, out: str | None) -> int:
+    """Join a dump with the `.xplane.pb` of a `profile` capture: write
+    the one timeline, print what the join rested on and the device's
+    idle seconds by span."""
+    from jax.profiler import ProfileData
+
+    try:
+        merged, info = join_xplane(dump, ProfileData.from_file(xplane_path))
+    except ValueError as exc:
+        print(f"traceview: {exc}", file=sys.stderr)
+        return 1
+    problems = validate_chrome_trace(merged)
+    for p in problems[:20]:
+        print(f"  - {p}", file=sys.stderr)
+    print(f"{info['anchors']} clock anchor(s) over "
+          f"{info['anchored_s']:.3f}s, drift {info['drift_ns'] / 1000.0:.1f} "
+          f"us; {len(merged['traceEvents'])} events on one timeline")
+    idle = info.get("idle")
+    if idle:
+        print(f"device idle {idle['idle'] / 1e9:.3f}s of "
+              f"{idle['window'] / 1e9:.3f}s, by the innermost span of "
+              f"each host thread:")
+        for name, ns in sorted(idle["rows"].items(),
+                               key=lambda kv: -kv[1])[:20]:
+            print(f"  {ns / 1e9:10.3f}s  {name}")
+    if out:
+        with open(out, "w") as fh:
+            json.dump(merged, fh)
+        print(f"wrote {out}")
+    return 0 if not problems else 1
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--url", help="node RPC door, e.g. http://127.0.0.1:5005")
@@ -345,6 +609,10 @@ def main(argv=None) -> int:
     ap.add_argument("--min-processes", type=int, default=3,
                     help="merge: require >=1 tx spanning this many "
                          "process lanes (default 3)")
+    ap.add_argument("--xplane", metavar="FILE",
+                    help="with --validate or --url: the .xplane.pb of a "
+                         "`profile` capture; -o gets the dump's spans and "
+                         "the device's programs on one timeline")
     ap.add_argument("--reset", action="store_true",
                     help="clear the node's ring after dumping")
     ap.add_argument("-o", "--out", help="write the trace JSON here")
@@ -374,6 +642,15 @@ def main(argv=None) -> int:
                 f"({'valid' if not problems else 'INVALID'})"
             )
         return 0 if not problems else 1
+    if args.xplane:
+        if args.validate:
+            with open(args.validate) as fh:
+                dump = json.load(fh)
+        elif args.url:
+            dump = fetch_dump(args.url, reset=args.reset)
+        else:
+            ap.error("--xplane needs a dump: --validate FILE or --url URL")
+        return run_xplane(dump, args.xplane, args.out)
     if args.validate:
         with open(args.validate) as fh:
             obj = json.load(fh)
