@@ -29,6 +29,20 @@ Three properties the plain TaggedCache (utils/taggedcache.py) lacked:
   remain in the store, so losing a cache entry costs a re-fault, never
   correctness; the epoch only orders the victims.
 
+Eviction never walks the table to find out WHETHER it has victims, and
+never lists candidates it will not evict. Two pieces of bookkeeping,
+kept current by every operation at O(1) each, answer for it: ``_eager``
+holds the eager keys in the order ``_data`` has them (a hit moves a key
+to the tail of both), so the eager cap pops its victims from that
+index's head; ``_cur_count`` counts the entries stamped with the current
+epoch, so "is any entry behind the epoch" is ``len(_data) >
+_cur_count``. Only when some are does eviction walk ``_data`` from its
+head (cold ``put``s land at the tail with an old stamp, so old-epoch
+entries are not a prefix), and it stops at the entry that frees enough
+bytes. The victims, their order and every counter but ``evict_scan*``
+are those of the list-building code this replaced
+(tests/test_ooc_state.py keeps it as the oracle).
+
 Counters ride ``get_counts.shamap_inner_cache``.
 """
 
@@ -81,10 +95,14 @@ class HotNodeCache:
         # restamp the epoch in place — no per-hit tuple churn on the
         # fault-descent hot path); OrderedDict tail = most recent
         self._data: "OrderedDict[bytes, list]" = OrderedDict()
+        # the eager keys, in the order `_data` has them (pass 0's index)
+        self._eager: "OrderedDict[bytes, None]" = OrderedDict()
         self._inflight: dict[bytes, threading.Event] = {}
         self.resident_bytes = 0
         self.epoch = 0
-        self._eager_count = 0
+        # entries stamped with the CURRENT epoch: the rest of `_data` is
+        # behind it (pass 1's count); a new epoch starts it at zero
+        self._cur_count = 0
         # counters (get_counts.shamap_inner_cache)
         self.hits = 0
         self.misses = 0
@@ -93,12 +111,18 @@ class HotNodeCache:
         self.evictions = 0
         self.evicted_bytes = 0
         self.epoch_first_evictions = 0  # victims taken for being old-epoch
-        # what eviction costs when it has to LOOK for victims: passes 0
-        # and 1 build a list of the entries that qualify by walking the
-        # whole table (pass 2 pops the LRU head and walks nothing)
-        self.evict_scans = 0      # such walks
-        self.evict_scanned = 0    # entries walked by them
-        self.evict_scan_s = 0.0   # seconds inside them
+        # what eviction costs when it has to LOOK for victims: pass 0
+        # pops them from the eager index (each entry examined is a
+        # victim), pass 1 walks `_data` from its head up to the old-epoch
+        # entry that frees enough bytes (pass 2 pops the LRU head and
+        # looks for nothing)
+        self.evict_scans = 0      # times passes 0 and 1 had to look
+        self.evict_scanned = 0    # entries they examined while looking
+        self.evict_scan_s = 0.0   # seconds they spent looking and evicting
+
+    @property
+    def _eager_count(self) -> int:
+        return len(self._eager)
 
     # -- configuration / epochs -------------------------------------------
 
@@ -114,6 +138,7 @@ class HotNodeCache:
         with self._lock:
             if epoch > self.epoch:
                 self.epoch = epoch
+                self._cur_count = 0  # every stamp is behind it now
 
     # -- cache ops ---------------------------------------------------------
 
@@ -123,8 +148,13 @@ class HotNodeCache:
             if entry is None:
                 self.misses += 1
                 return None
-            entry[2] = self.epoch
+            # a hit: restamp, move to the tail (of the eager index too)
+            if entry[2] != self.epoch:
+                entry[2] = self.epoch
+                self._cur_count += 1
             self._data.move_to_end(key)
+            if entry[3]:
+                self._eager.move_to_end(key)
             self.hits += 1
             return entry[0]
 
@@ -140,16 +170,15 @@ class HotNodeCache:
         EAGER_ENTRY_CAP)."""
         cost = node_cost(node, blob_len)
         with self._lock:
-            old = self._data.pop(key, None)
-            if old is not None:
-                self.resident_bytes -= old[1]
-                if old[3]:
-                    self._eager_count -= 1
+            if key in self._data:
+                self._drop_locked(key)
             epoch = self.epoch - 1 if cold else self.epoch
             self._data[key] = [node, cost, epoch, eager]
             self.resident_bytes += cost
             if eager:
-                self._eager_count += 1
+                self._eager[key] = None
+            if not cold:
+                self._cur_count += 1
             self._evict_locked()
 
     def get_or_load(self, key: bytes, loader: Callable[[bytes], tuple],
@@ -164,8 +193,12 @@ class HotNodeCache:
             with self._lock:
                 entry = self._data.get(key)
                 if entry is not None:
-                    entry[2] = self.epoch
+                    if entry[2] != self.epoch:
+                        entry[2] = self.epoch
+                        self._cur_count += 1
                     self._data.move_to_end(key)
+                    if entry[3]:
+                        self._eager.move_to_end(key)
                     self.hits += 1
                     return entry[0]
                 self.misses += 1
@@ -211,62 +244,68 @@ class HotNodeCache:
 
     # -- eviction ----------------------------------------------------------
 
+    def _drop_locked(self, key: bytes) -> int:
+        """Take `key` out of the table and the bookkeeping; its cost."""
+        _node, cost, epoch, eager = self._data.pop(key)
+        self.resident_bytes -= cost
+        if eager:
+            del self._eager[key]
+        if epoch == self.epoch:
+            self._cur_count -= 1
+        return cost
+
+    def _evict_one_locked(self, key: bytes) -> None:
+        cost = self._drop_locked(key)
+        self.evictions += 1
+        self.evicted_bytes += cost
+
     def _evict_locked(self) -> None:
         # pass 0: bound EAGER entries by count (each pins an unaccounted
         # whole subtree — TaggedCache-parity semantics for the eager
-        # from_store role)
-        if self._eager_count > EAGER_ENTRY_CAP:
+        # from_store role), least recently used first
+        over = len(self._eager) - EAGER_ENTRY_CAP
+        if over > 0:
             t0 = time.perf_counter()
-            victims = [k for k, e in self._data.items() if e[3]]
-            self.evict_scan_s += time.perf_counter() - t0
+            for _ in range(over):
+                self._evict_one_locked(next(iter(self._eager)))
             self.evict_scans += 1
-            self.evict_scanned += len(self._data)
-            for key in victims:
-                if self._eager_count <= EAGER_ENTRY_CAP:
-                    break
-                _n, cost, _e, _eager = self._data.pop(key)
-                self.resident_bytes -= cost
-                self._eager_count -= 1
-                self.evictions += 1
-                self.evicted_bytes += cost
+            self.evict_scanned += over
+            self.evict_scan_s += time.perf_counter() - t0
         if self.resident_bytes <= self.limit_bytes:
             return
         # pass 1: old-epoch entries in LRU order (the serving snapshot's
         # current-epoch working set survives a cold history scan)
-        cur = self.epoch
-        if any(e[2] < cur for e in self._data.values()):
+        behind = len(self._data) - self._cur_count
+        if behind:
             t0 = time.perf_counter()
-            victims = [k for k, e in self._data.items() if e[2] < cur]
-            self.evict_scan_s += time.perf_counter() - t0
-            self.evict_scans += 1
-            self.evict_scanned += len(self._data)
+            cur = self.epoch
+            need = self.resident_bytes - self.limit_bytes
+            victims = []
+            for scanned, (key, entry) in enumerate(self._data.items(), 1):
+                if entry[2] < cur:
+                    victims.append(key)
+                    need -= entry[1]
+                    if need <= 0 or len(victims) == behind:
+                        break
             for key in victims:
-                if self.resident_bytes <= self.limit_bytes:
-                    return
-                _node, cost, _e, eager = self._data.pop(key)
-                self.resident_bytes -= cost
-                if eager:
-                    self._eager_count -= 1
-                self.evictions += 1
-                self.evicted_bytes += cost
-                self.epoch_first_evictions += 1
+                self._evict_one_locked(key)
+            self.epoch_first_evictions += len(victims)
+            self.evict_scans += 1
+            self.evict_scanned += scanned
+            self.evict_scan_s += time.perf_counter() - t0
         # pass 2: pure LRU — current-epoch entries too, because the
         # byte bound always wins (re-faulting is cheap; OOM is not)
         while self.resident_bytes > self.limit_bytes and self._data:
-            _key, (_node, cost, _e, eager) = self._data.popitem(last=False)
-            self.resident_bytes -= cost
-            if eager:
-                self._eager_count -= 1
-            self.evictions += 1
-            self.evicted_bytes += cost
+            self._evict_one_locked(next(iter(self._data)))
 
     # -- introspection -----------------------------------------------------
 
     def clear(self) -> None:
         with self._lock:
             self._data.clear()
+            self._eager.clear()
             self.resident_bytes = 0
-            self._eager_count = 0
+            self._cur_count = 0
 
     def __len__(self) -> int:
         with self._lock:

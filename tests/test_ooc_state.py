@@ -9,11 +9,14 @@ routing."""
 from __future__ import annotations
 
 import hashlib
+import random
 import threading
+from collections import OrderedDict
 
 import pytest
 
-from stellard_tpu.state.hotcache import HotNodeCache
+from stellard_tpu.state import hotcache
+from stellard_tpu.state.hotcache import HotNodeCache, node_cost
 from stellard_tpu.state.shamap import (
     SHAMap,
     SHAMapItem,
@@ -252,7 +255,198 @@ class TestConcurrentFaulting:
         assert lz.get(_tag("k1")).data == b"payload-1"
 
 
+class _Leaf:
+    """A node whose cost `node_cost` reads off its item."""
+
+    def __init__(self, size: int):
+        self.item = SHAMapItem(b"\0" * 32, b"x" * size)
+
+
+class WalkingCache:
+    """The oracle: `HotNodeCache` as it was before eviction kept an
+    index, its `_evict_locked` word for word (passes 0 and 1 list every
+    candidate by walking the whole table), without the lock and the
+    in-flight latches a single thread does not need."""
+
+    def __init__(self, limit_bytes: int):
+        self.limit_bytes = int(limit_bytes)
+        self._data: "OrderedDict[bytes, list]" = OrderedDict()
+        self.resident_bytes = 0
+        self.epoch = 0
+        self._eager_count = 0
+        self.hits = self.misses = self.faults = 0
+        self.evictions = self.evicted_bytes = 0
+        self.epoch_first_evictions = 0
+
+    def set_limit(self, limit_bytes: int) -> None:
+        self.limit_bytes = max(0, int(limit_bytes))
+        self._evict_locked()
+
+    def advance_epoch(self, epoch: int) -> None:
+        if epoch > self.epoch:
+            self.epoch = epoch
+
+    def get(self, key: bytes):
+        entry = self._data.get(key)
+        if entry is None:
+            self.misses += 1
+            return None
+        entry[2] = self.epoch
+        self._data.move_to_end(key)
+        self.hits += 1
+        return entry[0]
+
+    def put(self, key, node, blob_len=0, *, cold=False, eager=False):
+        cost = node_cost(node, blob_len)
+        old = self._data.pop(key, None)
+        if old is not None:
+            self.resident_bytes -= old[1]
+            if old[3]:
+                self._eager_count -= 1
+        epoch = self.epoch - 1 if cold else self.epoch
+        self._data[key] = [node, cost, epoch, eager]
+        self.resident_bytes += cost
+        if eager:
+            self._eager_count += 1
+        self._evict_locked()
+
+    def get_or_load(self, key, loader, cold=False):
+        node = self.get(key)
+        if node is None:
+            self.faults += 1
+            node, blob_len = loader(key)
+            self.put(key, node, blob_len, cold=cold)
+        return node
+
+    def _evict_locked(self) -> None:
+        if self._eager_count > hotcache.EAGER_ENTRY_CAP:
+            victims = [k for k, e in self._data.items() if e[3]]
+            for key in victims:
+                if self._eager_count <= hotcache.EAGER_ENTRY_CAP:
+                    break
+                _n, cost, _e, _eager = self._data.pop(key)
+                self.resident_bytes -= cost
+                self._eager_count -= 1
+                self.evictions += 1
+                self.evicted_bytes += cost
+        if self.resident_bytes <= self.limit_bytes:
+            return
+        cur = self.epoch
+        if any(e[2] < cur for e in self._data.values()):
+            victims = [k for k, e in self._data.items() if e[2] < cur]
+            for key in victims:
+                if self.resident_bytes <= self.limit_bytes:
+                    return
+                _node, cost, _e, eager = self._data.pop(key)
+                self.resident_bytes -= cost
+                if eager:
+                    self._eager_count -= 1
+                self.evictions += 1
+                self.evicted_bytes += cost
+                self.epoch_first_evictions += 1
+        while self.resident_bytes > self.limit_bytes and self._data:
+            _key, (_node, cost, _e, eager) = self._data.popitem(last=False)
+            self.resident_bytes -= cost
+            if eager:
+                self._eager_count -= 1
+            self.evictions += 1
+            self.evicted_bytes += cost
+
+    def clear(self) -> None:
+        self._data.clear()
+        self.resident_bytes = 0
+        self._eager_count = 0
+
+
+# what binds in each regime: (eager cap, byte budget, do epochs move)
+REGIMES = {
+    "eager_cap": (6, 1 << 30, True),
+    "bytes_one_epoch": (1 << 30, 9_000, False),
+    "bytes_old_epochs": (1 << 30, 9_000, True),
+    "both": (6, 9_000, True),
+}
+COMPARED = ("resident_bytes", "_eager_count", "evictions", "evicted_bytes",
+            "epoch_first_evictions", "hits", "misses", "faults", "epoch")
+
+
+def _random_ops(rng: random.Random, steps: int, limit: int, epochs: bool):
+    """`steps` operations over a key space small enough that `get`s hit
+    and `put`s meet live keys (whose eager flag they may flip)."""
+    keys = [_tag(f"op{i}") for i in range(40)]
+    epoch = 0
+    for _ in range(steps):
+        key = rng.choice(keys)
+        roll = rng.random()
+        node = _Leaf(rng.randrange(0, 900)) if rng.random() < 0.5 else object()
+        blob_len = rng.randrange(0, 600)
+        if roll < 0.45:
+            yield "put", (key, node, blob_len), {
+                "eager": rng.random() < 0.5,
+                "cold": epochs and rng.random() < 0.2}
+        elif roll < 0.65:
+            yield "get", (key,), {}
+        elif roll < 0.85:
+            yield "get_or_load", (key, lambda _k, n=node, b=blob_len: (n, b)), {
+                "cold": epochs and rng.random() < 0.3}
+        elif roll < 0.93:
+            if epochs:
+                epoch += rng.choice((-1, 0, 1, 1, 2))  # some stand still
+                yield "advance_epoch", (epoch,), {}
+        elif roll < 0.99:
+            yield "set_limit", (rng.choice((limit, limit // 2, 3 * limit)),), {}
+        else:
+            yield "clear", (), {}
+
+
 class TestHotNodeCache:
+    @pytest.mark.parametrize("seed", range(6))
+    @pytest.mark.parametrize("regime", sorted(REGIMES))
+    def test_same_victims_as_the_walking_cache(self, monkeypatch, regime, seed):
+        cap, limit, epochs = REGIMES[regime]
+        monkeypatch.setattr(hotcache, "EAGER_ENTRY_CAP", cap)
+        new, oracle = HotNodeCache("t", limit_bytes=limit), WalkingCache(limit)
+        rng = random.Random(f"{regime}-{seed}")
+        for step, (op, args, kwargs) in enumerate(
+                _random_ops(rng, 1500, limit, epochs)):
+            got = getattr(new, op)(*args, **kwargs)
+            want = getattr(oracle, op)(*args, **kwargs)
+            assert got is want, (step, op)
+            # the same survivors in the same order, stamps and flags too
+            assert [(k, e[1:]) for k, e in new._data.items()] \
+                == [(k, e[1:]) for k, e in oracle._data.items()], (step, op)
+            for name in COMPARED:
+                assert getattr(new, name) == getattr(oracle, name), \
+                    (step, op, name)
+            # and the bookkeeping says what a walk would find
+            assert list(new._eager) == [k for k, e in new._data.items() if e[3]]
+            assert new._cur_count == sum(
+                e[2] == new.epoch for e in new._data.values())
+        assert new.evictions > 100  # the regime did bind
+        assert (new.epoch_first_evictions > 0) == (
+            regime in ("bytes_old_epochs", "both"))
+        if regime == "bytes_one_epoch":
+            assert (new.evict_scans, new.evict_scanned) == (0, 0)
+
+    def test_eviction_examines_no_more_than_it_evicts(self):
+        # at the shipped cap: a `put` past it examines its one victim,
+        # where the walking cache examined the 4,097 entries of the table
+        c = HotNodeCache("t", limit_bytes=1 << 30)
+        node = object()
+        for i in range(20_000):
+            c.put(i.to_bytes(32, "big"), node, eager=True)
+        assert c.evictions == 20_000 - hotcache.EAGER_ENTRY_CAP
+        assert c.evict_scans == c.evictions
+        assert c.evict_scanned <= c.evictions + 1
+        # a lazy cache at its byte budget with every entry of the current
+        # epoch: pass 1 has nothing to look for, and does not look
+        lazy = HotNodeCache("t", limit_bytes=100 * 1200)
+        lazy.advance_epoch(3)
+        for i in range(10_000):
+            lazy.put(i.to_bytes(32, "big"), node)
+        assert lazy.evictions == 10_000 - 100
+        assert (lazy.evict_scans, lazy.evict_scanned) == (0, 0)
+        assert lazy.epoch_first_evictions == 0
+
     def test_byte_bound_evicts_lru(self):
         c = HotNodeCache("t", limit_bytes=10_000)
 

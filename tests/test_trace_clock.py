@@ -66,6 +66,7 @@ from stellard_tpu.protocol.stamount import STAmount  # noqa: E402
 from stellard_tpu.protocol.sttx import SerializedTransaction  # noqa: E402
 from stellard_tpu.rpc.handlers import Context, dispatch  # noqa: E402
 from stellard_tpu.state import hotcache  # noqa: E402
+from stellard_tpu.state.shamap import inner_node_cache  # noqa: E402
 
 MASTER = KeyPair.from_passphrase("masterpassphrase")
 XRP = 1_000_000
@@ -575,15 +576,18 @@ class TestHotCacheScans:
         assert (cache.evict_scans, cache.evict_scanned) == (0, 0)
         cache.put(b"\x10" * 32, Node_())  # lazy: no eager pressure
         assert cache.evict_scans == 0
-        # the fifth eager entry: one walk of all 6, one victim
+        # the fifth eager entry: its one victim is all eviction examines
+        # (the eager index's head), not the 6 entries of the table
         cache.put(b"\x04" * 32, Node_(), eager=True)
-        assert (cache.evict_scans, cache.evict_scanned) == (1, 6)
-        assert cache.evictions == 1
-        # and again: the table holds 5 + the new one
+        assert (cache.evict_scans, cache.evict_scanned) == (1, 1)
+        assert cache.evictions == 1 and b"\x00" * 32 not in cache._data
+        # a hit moves an eager entry to the tail of the index too
+        assert cache.get(b"\x01" * 32) is not None
         cache.put(b"\x05" * 32, Node_(), eager=True)
-        assert (cache.evict_scans, cache.evict_scanned) == (2, 12)
+        assert (cache.evict_scans, cache.evict_scanned) == (2, 2)
+        assert b"\x02" * 32 not in cache._data and b"\x01" * 32 in cache._data
         j = cache.get_json()
-        assert j["evict_scans"] == 2 and j["evict_scanned"] == 12
+        assert j["evict_scans"] == 2 and j["evict_scanned"] == 2
         assert j["evict_scan_s"] >= 0
 
     def test_old_epoch_pass_counts_its_walk(self):
@@ -596,9 +600,47 @@ class TestHotCacheScans:
         cache.put(b"\x02" * 32, Node_())
         assert cache.evict_scans == 0
         cache.advance_epoch(1)
-        cache.put(b"\x03" * 32, Node_())  # 3,600 > 3,000: pass 1 walks 3
-        assert (cache.evict_scans, cache.evict_scanned) == (1, 3)
+        # 3,600 > 3,000 and two entries are behind the epoch: pass 1
+        # examines the head, which frees enough, and stops there
+        cache.put(b"\x03" * 32, Node_())
+        assert (cache.evict_scans, cache.evict_scanned) == (1, 1)
         assert cache.epoch_first_evictions == 1
+        # a cold put lands at the TAIL with an old stamp: with the head
+        # restamped by a hit, pass 1 examines all three to reach it
+        assert cache.get(b"\x02" * 32) is not None
+        cache.put(b"\x04" * 32, Node_(), cold=True)
+        assert (cache.evict_scans, cache.evict_scanned) == (2, 4)
+        assert list(cache._data) == [b"\x03" * 32, b"\x02" * 32]
+        assert cache.epoch_first_evictions == 2
+        # nothing behind the epoch: pass 2 pops the head, nothing looks
+        cache.put(b"\x05" * 32, Node_())
+        assert (cache.evict_scans, cache.evict_scanned) == (2, 4)
+        assert (cache.evictions, cache.epoch_first_evictions) == (3, 2)
+
+    def test_replay_range_past_the_eager_cap(self, chain, monkeypatch):
+        # the real path: every eager load of a tree with more inner
+        # nodes than the cap evicts, and still replays to its hashes
+        db, ledgers = chain
+        monkeypatch.setattr(hotcache, "EAGER_ENTRY_CAP", 2)
+        cache = inner_node_cache()
+        cache.clear()
+        tr = Tracer(sample=1.0)
+        before = (cache.evictions, cache.evict_scans, cache.evict_scanned)
+        out = replay_range(db, [l.hash() for l in ledgers], tracer=tr)
+        assert out["ok"] and all(
+            l["state_hash_ok"] and l["tx_hash_ok"] for l in out["ledgers"])
+        evicted, scans, scanned = (
+            now - was for now, was in zip(
+                (cache.evictions, cache.evict_scans, cache.evict_scanned),
+                before))
+        assert evicted > 0 and cache._eager_count <= 2
+        assert scanned == evicted and scans <= evicted
+        root, = spans(tr, "replay.span")
+        assert root["args"]["evict_scan_s"] > 0
+        loads = spans(tr, "ledger.load")
+        assert sum(ev["args"]["evict_scans"] for ev in loads) == scans
+        assert sum(ev["args"]["evict_scan_s"] for ev in loads) \
+            == pytest.approx(root["args"]["evict_scan_s"], abs=1e-4)
 
 
 class FakeLedger:
