@@ -202,6 +202,7 @@ class SpecState:
         # close's seal only hashes the residual. None = feature off or
         # fold failure (the close then runs the full seal — never forked)
         self.building = None
+        self.fold_failures = 0  # folds that raised and disarmed it
         self.absorbed: dict[bytes, object] = {}  # key -> item|None folded
         # speculation-index authority for this open window: the serial
         # path and the parallel executor's dispatch both allocate from
@@ -216,12 +217,15 @@ class SpecState:
         self.next_index += 1
         return i
 
-    def attach_building(self, state_root, hash_batch) -> None:
-        """Arm the pre-seal building tree over the parent state root."""
-        from ..state.shamap import SHAMap, TNType
-
-        kw = {"hash_batch": hash_batch} if hash_batch is not None else {}
-        self.building = SHAMap(TNType.ACCOUNT_STATE, state_root, **kw)
+    def attach_building(self, state_map, hash_batch) -> None:
+        """Arm the pre-seal building tree over the parent state: a
+        snapshot of the open ledger's state map, so the building tree
+        knows what its parent knows — over a lazily resumed ledger it
+        carries the parent's fault source, and every fold faults the
+        stubs on its write paths like any other merge into that tree."""
+        self.building = state_map.snapshot()
+        if hash_batch is not None:
+            self.building.hash_batch = hash_batch
         self.absorbed = {}
 
     def fold_building(self, rec: "SpecRecord") -> int:
@@ -257,6 +261,7 @@ class SpecState:
                           "incremental seal for this open ledger")
             self.building = None
             self.absorbed = {}
+            self.fold_failures += 1
             return 0
         if rec.index is not None:
             self._folded_max = rec.index
@@ -577,4 +582,5 @@ class CloseReplay:
             "bulk_merged_keys": self.bulk_merged_keys,
             "seal_adopt": self.seal_adopt,
             "seal_residual": self.seal_residual,
+            "fold_failures": self.spec.fold_failures if self.spec else 0,
         }

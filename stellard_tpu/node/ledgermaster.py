@@ -21,6 +21,7 @@ from ..node.hashrouter import SF_SIGGOOD
 from ..protocol.sttx import SerializedTransaction
 from ..protocol.ter import TER
 from ..state.ledger import Ledger
+from ..state.shamap import inner_node_cache
 from .metrics import AtomicCounters
 from .tracer import get_tracer
 
@@ -129,8 +130,16 @@ class LedgerMaster:
         # their own threads, and bare `dict +=` would lose updates
         self.delta_stats = AtomicCounters(
             "closes", "spliced", "fallback", "invalidated",
+            # closes sealed from the pre-hashed building tree, and open
+            # ledgers whose building tree a failed fold disarmed (the
+            # exception fold_building swallows, as a number)
+            "incremental_seals", "building_fold_failures",
         )
         self.last_close: dict = {}
+        # the hot-node cache's (faults, fault_s, evictions) when the
+        # last close ended: `close.total` carries the differences
+        cache = inner_node_cache()
+        self._cache_marks = (cache.faults, cache.fault_s, cache.evictions)
         # parallel speculative executor ([spec] workers=N, engine/
         # specexec.py): when active, _speculate_open dispatches to the
         # worker pool instead of executing inline, and the close drains
@@ -323,7 +332,7 @@ class LedgerMaster:
                 # from — the building tree folds speculated
                 # writes onto it and pre-hashes between closes
                 spec.attach_building(
-                    open_ledger.state_map.root, self.hash_batch
+                    open_ledger.state_map, self.hash_batch
                 )
         if tx.txid() in spec.records:
             return
@@ -998,6 +1007,8 @@ class LedgerMaster:
         self.delta_stats.add_many(
             closes=1, spliced=c["spliced"], fallback=c["fallback"],
             invalidated=c["invalidated"],
+            incremental_seals=int(c.get("seal_adopt") == "adopted"),
+            building_fold_failures=c.get("fold_failures", 0),
         )
         with self._drain_cv:
             self.tree_stats["bulk_merges"] += c.get("bulk_merges", 0)
@@ -1026,7 +1037,18 @@ class LedgerMaster:
         tr = self.tracer
         tr.complete("close.apply", "close", t0, t_apply, seq=seq)
         tr.complete("close.seal", "close", t_apply, t_seal, seq=seq)
-        tr.complete("close.total", "close", t0, now, seq=seq)
+        # what the hot-node cache did over this close CYCLE (since the
+        # last close ended: the open window's faults are the cycle's),
+        # as `replay.span` carries its `evict_scan_s`; over a run the
+        # differences sum to the cache's own counters
+        cache = inner_node_cache()
+        marks = (cache.faults, cache.fault_s, cache.evictions)
+        was, self._cache_marks = self._cache_marks, marks
+        tr.complete("close.total", "close", t0, now, seq=seq,
+                    faults=marks[0] - was[0],
+                    fault_s=round(marks[1] - was[1], 6),
+                    evictions=marks[2] - was[2],
+                    resident_bytes=cache.resident_bytes)
 
     def delta_replay_json(self) -> dict:
         """spliced/fallback/invalidation counters + close-stage latency

@@ -1116,6 +1116,13 @@ class Node:
                 self.ledger_master.start_new_ledger(self.master_keys.account_id)
             else:
                 self.ledger_master.load_ledger(led)
+                if self.path_plane is not None:
+                    # the book index starts from the mirror's offer
+                    # keys, not from a walk of the (lazy) state inside
+                    # the first close
+                    keys = self.clf.offer_keys(led)
+                    if keys is not None:
+                        self.path_plane.index.seed(led, keys)
         return self
 
     def serve(self) -> "Node":

@@ -150,7 +150,8 @@ class LiveBookIndex:
         self.incremental_advances = 0
         self.carries = 0
         self.book_rereads = 0  # books touched by incremental deltas
-        self.state_offers_scanned = 0  # offers read by full scans
+        self.state_offers_scanned = 0  # offers read by scans and seeds
+        self.seeded = 0  # times established from a list of offer keys
 
     @property
     def seq(self) -> int | None:
@@ -166,6 +167,7 @@ class LiveBookIndex:
             "carries": self.carries,
             "book_rereads": self.book_rereads,
             "state_offers_scanned": self.state_offers_scanned,
+            "seeded": self.seeded,
         }
 
     def books_if_current(self, ledger: Ledger) -> OrderBookDB | None:
@@ -262,15 +264,42 @@ class LiveBookIndex:
                 deltas[book] = deltas.get(book, 0) + d
         return deltas
 
+    def seed(self, ledger: Ledger, offer_keys) -> bool:
+        """Establish the index at `ledger` from the KEYS of its offers,
+        each read from the state by key (content-checked like any
+        fault), instead of a walk over every entry of the state: what a
+        resumed node does, whose CLF mirror lists the offers of the very
+        ledger it resumes (a walk of a lazily opened tree faults the
+        whole state into the first close). -> False, and nothing kept,
+        when a key is not an offer of this ledger: the list is not this
+        ledger's, and the first advance scans as it always did."""
+        lt_offer = int(LedgerEntryType.ltOFFER)
+        offers = []
+        for key in offer_keys:
+            item = ledger.state_map.get(key)
+            sle = STObject.from_bytes(item.data) if item is not None else None
+            if sle is None or sle.get(sfLedgerEntryType) != lt_offer:
+                return False
+            offers.append(sle)
+        with self._advance_lock:
+            self._install(ledger, ledger.hash(), offers)
+            self.seeded += 1
+        return True
+
     def _rebuild(self, ledger: Ledger, h: bytes) -> OrderBookDB:
         self.full_rebuilds += 1
         lt_offer = int(LedgerEntryType.ltOFFER)
+        entries = (STObject.from_bytes(item.data)
+                   for item in ledger.state_map.items())
+        return self._install(
+            ledger, h,
+            (sle for sle in entries
+             if sle.get(sfLedgerEntryType) == lt_offer))
+
+    def _install(self, ledger: Ledger, h: bytes, offers) -> OrderBookDB:
         counts: dict[Book, int] = {}
         scanned = 0
-        for item in ledger.state_map.items():
-            sle = STObject.from_bytes(item.data)
-            if sle.get(sfLedgerEntryType) != lt_offer:
-                continue
+        for sle in offers:
             scanned += 1
             book = book_of(sle[sfTakerPays], sle[sfTakerGets])
             counts[book] = counts.get(book, 0) + 1

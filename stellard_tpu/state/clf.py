@@ -309,6 +309,16 @@ class CLFMirror:
             return None
         return led
 
+    def offer_keys(self, ledger) -> Optional[list]:
+        """The state indexes of every offer of `ledger`, from the row
+        mirror — or None unless the mirror is in lockstep with exactly
+        that ledger (rows and the LCL pointer commit in one
+        transaction, so an equal pointer means the rows are its)."""
+        if self.last_closed_hash != ledger.hash():
+            return None
+        return [bytes.fromhex(r[0])
+                for r in self.db.query("SELECT index_hex FROM offers")]
+
     def get_json(self) -> dict:
         return {
             "last_closed": (self.last_closed_hash or b"").hex(),

@@ -107,6 +107,9 @@ class HotNodeCache:
         self.hits = 0
         self.misses = 0
         self.faults = 0          # loader invocations (store round-trips)
+        # seconds inside those loaders: the store fetch, the content
+        # check against the key and the parse of every faulted node
+        self.fault_s = 0.0
         self.fault_shared = 0    # faults answered by another thread's load
         self.evictions = 0
         self.evicted_bytes = 0
@@ -227,10 +230,12 @@ class HotNodeCache:
                 self.faults += 1
                 t0 = time.perf_counter()
                 node, blob_len = loader(key)
+                t1 = time.perf_counter()
+                self.fault_s += t1 - t0
                 tr = self.tracer
                 if tr is not None:
-                    tr.complete("cache.fault", "state", t0,
-                                time.perf_counter(), bytes=blob_len)
+                    tr.complete("cache.fault", "state", t0, t1,
+                                bytes=blob_len)
             except BaseException:
                 with self._lock:
                     self._inflight.pop(key, None)
@@ -326,6 +331,7 @@ class HotNodeCache:
                 "hits": self.hits,
                 "misses": self.misses,
                 "faults": self.faults,
+                "fault_s": round(self.fault_s, 6),
                 "fault_shared": self.fault_shared,
                 "evictions": self.evictions,
                 "evicted_bytes": self.evicted_bytes,

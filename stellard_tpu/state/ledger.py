@@ -104,8 +104,13 @@ class Ledger:
         self.close_resolution = close_resolution
         self.close_flags = close_flags
         kw = {"hash_batch": hash_batch} if hash_batch else {}
-        self.tx_map = tx_map or SHAMap(TNType.TX_MD, **kw)
-        self.state_map = state_map or SHAMap(TNType.ACCOUNT_STATE, **kw)
+        # `is None`, never truthiness: a SHAMap's truth is its length,
+        # a walk of every leaf — on a lazily opened tree a fault of the
+        # whole state for each Ledger built over it
+        self.tx_map = (tx_map if tx_map is not None
+                       else SHAMap(TNType.TX_MD, **kw))
+        self.state_map = (state_map if state_map is not None
+                          else SHAMap(TNType.ACCOUNT_STATE, **kw))
         self.closed = False
         self.accepted = False
         self.validated = False
@@ -365,7 +370,11 @@ class Ledger:
             inflation_seq=self.inflation_seq,
             parent_close_time=self.close_time,
             close_resolution=self.close_resolution,
-            tx_map=SHAMap(TNType.TX_MD, hash_batch=self.tx_map.hash_batch),
+            # the fresh tx map takes the default hasher, as it always
+            # has (the truthiness test in __init__ used to swap an
+            # empty map handed over for a default one); putting it on
+            # the hash plane is a routing change of its own (PERF.md)
+            tx_map=SHAMap(TNType.TX_MD),
             state_map=self.state_map.snapshot(),
         )
         child.base_fee = self.base_fee

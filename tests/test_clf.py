@@ -142,6 +142,56 @@ class TestMirror:
         finally:
             stop_node(node2)
 
+    def test_resume_seeds_the_book_index_from_the_mirrors_offer_keys(
+            self, tmp_path):
+        """A node resumed with start_up=load has its book index at the
+        resumed ledger before its first close, read from the offers the
+        mirror lists (in lockstep with the pointer it resumed from), not
+        from a walk of the state."""
+        from stellard_tpu.paths.orderbook import OrderBookDB
+
+        node = make_node(tmp_path)
+        master = node.master_keys
+        bob = KeyPair.from_passphrase("bob")
+        try:
+            node.submit(payment(master, 1, bob.account_id, 500 * XRP))
+            node.close_ledger()
+            for seq, usd in ((1, 5), (2, 7)):
+                offer = SerializedTransaction.build(
+                    TxType.ttOFFER_CREATE, bob.account_id, seq, 10,
+                    {sfTakerPays: STAmount.from_iou(
+                        USD, master.account_id, usd, 0),
+                     sfTakerGets: STAmount.from_drops(usd * XRP)},
+                )
+                offer.sign(bob)
+                node.submit(offer)
+            node.close_ledger()
+            lcl = node.ledger_master.closed_ledger()
+            keys = node.clf.offer_keys(lcl)
+            assert len(keys) == 2
+            assert all(lcl.state_map.get(k) is not None for k in keys)
+            # not this ledger's mirror: no list at all
+            older = node.ledger_master.get_ledger_by_seq(lcl.seq - 1)
+            assert node.clf.offer_keys(older) is None
+        finally:
+            stop_node(node)
+
+        node2 = make_node(tmp_path, start_up="load")
+        try:
+            got = node2.ledger_master.closed_ledger()
+            idx = node2.path_plane.index
+            assert (idx.seeded, idx.full_rebuilds) == (1, 0)
+            assert idx.state_offers_scanned == 2
+            books = node2.path_plane.books_if_current(got)
+            assert books is not None
+            assert books.books == OrderBookDB().setup(got).books
+            assert len(books.books) == 1
+            node2.close_ledger()  # continuity holds: no scan now either
+            assert idx.full_rebuilds == 0
+            assert idx.seq == got.seq + 1
+        finally:
+            stop_node(node2)
+
     def test_atomicity_on_failed_commit(self, tmp_path):
         """A failure mid-commit must roll back rows AND state pointer."""
         db = LedgerSqlDatabase(str(tmp_path / "clf.db"))

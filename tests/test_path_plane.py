@@ -274,6 +274,48 @@ class TestLiveBookIndexIdentity:
         assert idx.full_rebuilds == 2
         assert db.books == full_books(led)
 
+    def test_seed_from_offer_keys_equals_the_full_scan(self):
+        """A list of offer keys (a resumed node's CLF mirror) gives the
+        same index as the walk of the state, and the next close
+        advances from it incrementally."""
+        from stellard_tpu.protocol.formats import LedgerEntryType
+        from stellard_tpu.protocol.sfields import sfLedgerEntryType
+        from stellard_tpu.protocol.stobject import STObject
+
+        net = liquid_net()
+        offer(net, ALICE, drops(100 * M), iou(100))
+        offer(net, BOB, drops(50 * M), iou(70))
+        offer(net, CAROL, iou(500), drops(1 * M))  # too dear to cross
+        led = close(net)
+        keys = [it.tag for it in led.state_map.items()
+                if STObject.from_bytes(it.data).get(sfLedgerEntryType)
+                == int(LedgerEntryType.ltOFFER)]
+        assert len(keys) == 3
+        idx = LiveBookIndex()
+        assert idx.seed(led, keys) is True
+        assert (idx.seeded, idx.full_rebuilds) == (1, 0)
+        assert idx.state_offers_scanned == 3
+        assert idx.books_if_current(led).books == full_books(led)
+        assert len(full_books(led)) == 2
+        offer(net, ALICE, drops(30 * M), iou(20))
+        check_identity(idx, close(net))
+        assert (idx.incremental_advances, idx.full_rebuilds) == (1, 0)
+
+    @pytest.mark.parametrize("wrong", ["missing", "not_an_offer"])
+    def test_seed_refuses_a_list_that_is_not_this_ledgers(self, wrong):
+        from stellard_tpu.state import indexes
+
+        net = liquid_net()
+        offer(net, ALICE, drops(100 * M), iou(100))
+        led = close(net)
+        key = (b"\x5a" * 32 if wrong == "missing"
+               else indexes.account_root_index(ALICE.account_id))
+        idx = LiveBookIndex()
+        assert idx.seed(led, [key]) is False
+        assert idx.seeded == 0 and idx.books_if_current(led) is None
+        check_identity(idx, led)  # the first advance scans, as before
+        assert idx.full_rebuilds == 1
+
     def test_books_if_current_never_mutates(self):
         net = liquid_net()
         idx = LiveBookIndex()

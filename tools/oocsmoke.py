@@ -17,6 +17,9 @@ End to end, on the REAL node stack:
    - the capped run actually FAULTED (nonzero
      shamap_inner_cache.faults — anti-vacuity: a smoke that never
      exercised the out-of-core path proves nothing);
+   - neither resumed node reports a building-tree fold failure, and
+     both sealed closes from the building tree
+     (delta_replay.building_fold_failures == 0, incremental_seals > 0);
    - capped-run RSS growth during the flood stays bounded;
    - online deletion rotated with a shard SEAL, and an account_tx for
      a window BELOW the sql_trim retain floor is served from a shard
@@ -182,6 +185,11 @@ def phase_run(state_dir: str, cache_mb: int, start_seq: int) -> None:
             "rss_mb_before": rss0,
             "rss_mb_after": rss1,
             "inner_cache": counters["shamap_inner_cache"],
+            "delta_replay": {
+                k: counters["delta_replay"][k]
+                for k in ("closes", "incremental_seals",
+                          "building_fold_failures")
+            },
             "history_shards": counters.get("history_shards"),
             "online_delete": deleter,
             "retain_floor": floor,
@@ -239,6 +247,13 @@ def run_smoke() -> int:
                 "anti-vacuity: capped run recorded ZERO faults — the "
                 "out-of-core path never ran"
             )
+        for name, run in runs.items():
+            dr = run["delta_replay"]
+            if dr["building_fold_failures"] or not dr["incremental_seals"]:
+                failures.append(
+                    f"the lazily resumed {name} node lost its incremental "
+                    f"seal: {dr}"
+                )
         delta = cap["rss_mb_after"] - cap["rss_mb_before"]
         if delta > RSS_DELTA_CAP_MB:
             failures.append(
