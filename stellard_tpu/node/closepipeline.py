@@ -33,6 +33,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
 
 log = logging.getLogger("stellard.closepipeline")
@@ -244,6 +245,11 @@ class ClosePipeline:
                 self._persist(entry)
                 self.persisted += 1
                 ok = True
+                if entry.ledger is not None:
+                    # durable: the ledger's objects belong to history
+                    # now. On this thread, off the close path, and
+                    # inside what flush() waits for
+                    HEAP_AGING.age()
             except Exception:  # noqa: BLE001 — keep persisting later ledgers
                 self.failed += 1
                 if entry.ledger is not None:

@@ -736,6 +736,7 @@ class LedgerMaster:
         queued txs fill the new open ledger up to the soft cap (the
         [txq] enabled=0 kill-switch keeps the legacy held re-apply path
         byte-for-byte). Caller holds the lock."""
+        self._retire_open()
         self.current = new_lcl.open_successor()
         for tx in leftovers:
             ter, _applied = self._open_apply(
@@ -758,6 +759,17 @@ class LedgerMaster:
                 )
                 if ter == TER.terPRE_SEQ:
                     self._hold(tx, expire)
+
+    def _retire_open(self) -> None:
+        """The open ledger is about to be replaced: take its
+        speculation state off it. The state's view points back at the
+        ledger, a cycle that held every record of the window (and the
+        building tree) until the collector's next walk of the old
+        generation; cut here, the window's records die with the close
+        that consumed them, by reference count. Caller holds the lock."""
+        old = self.current
+        if old is not None and getattr(old, "_spec_state", None) is not None:
+            old._spec_state = None
 
     def _drain_spec(self, spec) -> None:
         """Seal the open window's parallel-speculation session before
@@ -795,6 +807,7 @@ class LedgerMaster:
         with self._lock:
             ledger.accepted = True
             self._push_closed(ledger)
+            self._retire_open()
             self.current = ledger.open_successor()
             self._reindex_chain(ledger)
 

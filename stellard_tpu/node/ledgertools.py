@@ -21,6 +21,7 @@ from ..protocol.sttx import SerializedTransaction
 from ..protocol.stobject import STObject
 from ..protocol.ter import TER
 from ..state.ledger import Ledger
+from .heapaging import HEAP_AGING
 from .ledgermaster import CanonicalTXSet, LedgerMaster
 from .tracer import GC_PROBE, get_tracer
 
@@ -186,11 +187,13 @@ def replay_ledger(
     and both tree hashes) under it."""
     tr = tracer if tracer is not None else get_tracer()
     probed = GC_PROBE.install(tr)
+    HEAP_AGING.acquire()
     try:
         with tr.span("replay.ledger", "replay") as span:
             return _replay_ledger(db, ledger_hash, hash_batch, verify_many,
                                   _txs, _target, tr, span)
     finally:
+        HEAP_AGING.release()
         if probed:
             GC_PROBE.remove(tr)
 
@@ -281,6 +284,7 @@ def replay_range(
     the hot cache's victim scans (``evict_scan_s``) took of it."""
     tr = tracer if tracer is not None else get_tracer()
     probed = GC_PROBE.install(tr)
+    HEAP_AGING.acquire()
     try:
         with tr.span("replay.span", "replay") as span:
             marks = _runtime_marks()
@@ -295,6 +299,7 @@ def replay_range(
                 }
             return out
     finally:
+        HEAP_AGING.release()
         if probed:
             GC_PROBE.remove(tr)
 
@@ -317,11 +322,13 @@ def _replay_range(db, ledger_hashes, hash_batch, verify_many, tr) -> dict:
             _reverify_memoized(
                 [tx for txs in per_ledger for tx in txs], verify_many
             )
-    stats = [
-        replay_ledger(db, h, hash_batch=hash_batch, _txs=txs,
-                      _target=target, tracer=tr)
-        for h, txs, target in zip(ledger_hashes, per_ledger, targets)
-    ]
+    stats = []
+    for h, txs, target in zip(ledger_hashes, per_ledger, targets):
+        stats.append(replay_ledger(db, h, hash_batch=hash_batch, _txs=txs,
+                                   _target=target, tracer=tr))
+        # behind the ledger's span: what it left alive (the first time,
+        # every target of the range) lives to the end of the range
+        HEAP_AGING.age()
     elapsed = time.perf_counter() - t0
     total = sum(s["tx_count"] for s in stats)
     return {

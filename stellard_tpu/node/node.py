@@ -22,6 +22,7 @@ from ..protocol.ter import TER
 from ..state.ledger import Ledger
 from .config import DEFAULT_KERNEL_TUNING, Config
 from .hashrouter import HashRouter
+from .heapaging import HEAP_AGING
 from .jobqueue import JobQueue
 from .ledgermaster import LedgerMaster
 from .networkops import NetworkOPs, TxStatus
@@ -375,6 +376,7 @@ class Node:
             cfg, tracer=self.tracer
         )
         self._gc_probed = False
+        self._heap_owned = False
         self.verify_prewarm: Optional[threading.Thread] = None
         if cfg.signature_backend != "cpu":
             # compile + measure the device shapes in the background;
@@ -1123,6 +1125,12 @@ class Node:
                     keys = self.clf.offer_keys(led)
                     if keys is not None:
                         self.path_plane.index.seed(led, keys)
+        # from here to stop() this node decides when the old generation
+        # is walked (node/heapaging.py); what set-up built stays
+        if not self._heap_owned:
+            HEAP_AGING.acquire()
+            self._heap_owned = True
+        HEAP_AGING.age()
         return self
 
     def serve(self) -> "Node":
@@ -1477,6 +1485,9 @@ class Node:
 
             GC_PROBE.remove(self.tracer)
             self._gc_probed = False
+        if self._heap_owned:
+            HEAP_AGING.release()
+            self._heap_owned = False
         self.load_manager.stop()
         # the executor first: any open speculation window completes
         # serially before the chain machinery below winds down
@@ -1536,6 +1547,7 @@ class Node:
         embedders that drive persistence directly."""
         self.persist_ledger_data(ledger, results)
         self._commit_clf(ledger)
+        HEAP_AGING.age()
 
     def _commit_clf(self, ledger: Ledger) -> None:
         # CLF commit: one scoped SQL transaction — entry-row delta + LCL

@@ -79,6 +79,7 @@ import zlib
 from collections import deque
 from typing import Optional
 
+from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
 
 __all__ = ["Tracer", "SpanToken", "get_tracer", "GC_PROBE",
@@ -783,6 +784,9 @@ class _GcProbe:
             out[f"gen{g}_collections"] = self.collections[g]
             out[f"gen{g}_pause_s"] = round(self.pause_s[g], 6)
             out[f"gen{g}_collected"] = self.collected[g]
+        # who walks the old generation and how often (node/heapaging.py;
+        # counted with `[trace] enabled=0` too)
+        out.update(HEAP_AGING.get_json())
         return out
 
 

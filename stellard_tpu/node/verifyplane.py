@@ -40,6 +40,7 @@ from ..utils.devicewatch import (
     call_with_deadline,
     resolve_timeouts,
 )
+from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
 
 log = logging.getLogger("stellard.device")
@@ -542,6 +543,8 @@ class VerifyPlane:
                     COMPILES.observers.remove(on_program)
                 tr.end(span, error=self.prewarm_error,
                        wedged=self.device_wedged)
+                # the traced programs' objects live as long as the plane
+                HEAP_AGING.age()
 
         t = threading.Thread(target=run, name="verify-prewarm", daemon=True)
         t.start()

@@ -22,19 +22,21 @@ def affected_accounts(meta_blob: "bytes | STObject") -> list[bytes]:
     meta = (meta_blob if isinstance(meta_blob, STObject)
             else STObject.from_bytes(meta_blob))
     out: set[bytes] = set()
-
-    def walk(obj: STObject) -> None:
-        for f, v in obj.fields():
-            if f.type_id == STI.ACCOUNT:
-                out.add(v)
-            elif isinstance(v, STAmount) and not v.is_native:
-                if v.issuer != ACCOUNT_ZERO:
-                    out.add(v.issuer)
-            elif isinstance(v, STObject):
-                walk(v)
-            elif isinstance(v, STArray):
-                for _, inner in v:
-                    walk(inner)
-
-    walk(meta)
+    _collect_accounts(meta, out)
     return sorted(out)
+
+
+def _collect_accounts(obj: STObject, out: set) -> None:
+    # at module level: a local function that calls itself is a cycle
+    # only the collector frees, one for every transaction persisted
+    for f, v in obj.fields():
+        if f.type_id == STI.ACCOUNT:
+            out.add(v)
+        elif isinstance(v, STAmount) and not v.is_native:
+            if v.issuer != ACCOUNT_ZERO:
+                out.add(v.issuer)
+        elif isinstance(v, STObject):
+            _collect_accounts(v, out)
+        elif isinstance(v, STArray):
+            for _, inner in v:
+                _collect_accounts(inner, out)

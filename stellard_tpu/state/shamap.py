@@ -37,6 +37,7 @@ from ..utils.hashes import (
     HP_TXN_ID,
     HP_TX_NODE,
     prefix_hash,
+    sha512_half,
 )
 
 __all__ = [
@@ -506,6 +507,48 @@ def _walk_leaves(node) -> Iterator[Leaf]:
             yield from _walk_leaves(c)
 
 
+def _load_eager(h: bytes, fetch, cache, verify: bool):
+    """``SHAMap.from_store``'s eager walk: the node ``h`` names with its
+    whole subtree. At module level: as a local function of
+    ``from_store`` it called itself through its own cell, a cycle a
+    load that only the collector freed (with ``fetch`` and the store
+    handle behind it)."""
+    if cache is not None:
+        hit = cache.get(h)
+        # a LazyInner hit (faulted by the out-of-core plane)
+        # must not leak into an EAGER tree: its descendants are
+        # stubs, and eager trees (source=None) promise
+        # stub-free structure to the native merge fast path
+        if hit is not None and type(hit) is not LazyInner:
+            return hit
+    blob = fetch(h)
+    if blob is None:
+        raise MissingNodeError(f"missing node {h.hex()}")
+    node = deserialize_node_prefix(blob)
+    if verify:
+        # prefix-format blob == exactly the hashed bytes
+        actual = sha512_half(blob)
+        if actual != h:
+            raise ValueError(
+                f"node content hash mismatch: key {h.hex()[:16]} "
+                f"content {actual.hex()[:16]}"
+            )
+    if isinstance(node, InnerStub):
+        children = tuple(
+            _load_eager(ch, fetch, cache, verify) if ch != ZERO256 else None
+            for ch in node.child_hashes
+        )
+        node = Inner(children, hash=h)
+        if cache is not None:
+            # eager: this entry pins its whole materialized
+            # subtree, so it rides the EAGER_ENTRY_CAP count
+            # bound, not the per-node byte budget
+            cache.put(h, node, eager=True)
+    else:
+        node._hash = h
+    return node
+
+
 # --------------------------------------------------------------------------
 # batched hashing
 
@@ -516,19 +559,22 @@ def _collect_unhashed(root) -> list[list]:
     mutation always rebuilds the whole path from the root with fresh
     (hashless) nodes."""
     levels: list[list] = []
-
-    def visit(node, depth):
-        if node is None or node._hash is not None:
-            return
-        while len(levels) <= depth:
-            levels.append([])
-        levels[depth].append(node)
-        if isinstance(node, Inner):
-            for c in node.children:
-                visit(c, depth + 1)
-
-    visit(root, 0)
+    _visit_unhashed(root, 0, levels)
     return levels
+
+
+def _visit_unhashed(node, depth: int, levels: list) -> None:
+    # at module level: a local function that calls itself is a cycle
+    # (function, cell, function) that only the collector frees, and
+    # this one's cell would hold `levels` and every node in it
+    if node is None or node._hash is not None:
+        return
+    while len(levels) <= depth:
+        levels.append([])
+    levels[depth].append(node)
+    if isinstance(node, Inner):
+        for c in node.children:
+            _visit_unhashed(c, depth + 1, levels)
 
 
 def _default_hasher(prefixes, payloads):
@@ -1034,6 +1080,10 @@ class SHAMap:
                 walk(ca, cb)
 
         walk(self.root, other.root)
+        # `walk` calls itself through its own cell: emptied here, the
+        # function (and `delta`, which its caller drops) dies by
+        # reference count and not at the collector's next pass
+        walk = None
         if len(delta) > limit:
             raise ValueError("delta exceeds limit")
         return delta
@@ -1097,6 +1147,7 @@ class SHAMap:
 
         if not (isinstance(self.root, Inner) and self.root.is_empty()):
             visit(self.root)
+        visit = None  # out of its own cell: no cycle left holding `nodes`
         for start in range(0, len(nodes), self.FLUSH_CHUNK):
             chunk = nodes[start : start + self.FLUSH_CHUNK]
             buf, offsets = encode_nodes(chunk)
@@ -1164,45 +1215,7 @@ class SHAMap:
                 root = Inner(tuple(children))
             return cls(leaf_type, root, hash_batch, source=source)
         cache = inner_node_cache() if use_cache else None
-
-        def load(h: bytes):
-            if cache is not None:
-                hit = cache.get(h)
-                # a LazyInner hit (faulted by the out-of-core plane)
-                # must not leak into an EAGER tree: its descendants are
-                # stubs, and eager trees (source=None) promise
-                # stub-free structure to the native merge fast path
-                if hit is not None and type(hit) is not LazyInner:
-                    return hit
-            blob = fetch(h)
-            if blob is None:
-                raise MissingNodeError(f"missing node {h.hex()}")
-            node = deserialize_node_prefix(blob)
-            if verify:
-                # prefix-format blob == exactly the hashed bytes
-                from ..utils.hashes import sha512_half
-
-                actual = sha512_half(blob)
-                if actual != h:
-                    raise ValueError(
-                        f"node content hash mismatch: key {h.hex()[:16]} "
-                        f"content {actual.hex()[:16]}"
-                    )
-            if isinstance(node, InnerStub):
-                children = tuple(
-                    load(ch) if ch != ZERO256 else None for ch in node.child_hashes
-                )
-                node = Inner(children, hash=h)
-                if cache is not None:
-                    # eager: this entry pins its whole materialized
-                    # subtree, so it rides the EAGER_ENTRY_CAP count
-                    # bound, not the per-node byte budget
-                    cache.put(h, node, eager=True)
-            else:
-                node._hash = h
-            return node
-
-        root = load(root_hash)
+        root = _load_eager(root_hash, fetch, cache, verify)
         if isinstance(root, Leaf):
             children = [None] * 16
             children[_nibble(root.item.tag, 0)] = root

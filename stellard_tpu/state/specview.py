@@ -40,6 +40,7 @@ else raising AttributeError is a seam audit failure, not a fallback.
 
 from __future__ import annotations
 
+import weakref
 from bisect import bisect_right, insort
 from typing import Optional
 
@@ -82,7 +83,10 @@ class _StateMapShim:
     __slots__ = ("_view",)
 
     def __init__(self, view: "SpecView"):
-        self._view = view
+        # weak: the view owns this shim, and a strong reference back
+        # would make every window's view (with the open ledger behind
+        # it) a cycle that only the collector frees
+        self._view = weakref.proxy(view)
 
     def succ(self, key: bytes):
         v = self._view

@@ -46,3 +46,16 @@ def _stellard_env_guard():
         if k not in saved:
             del os.environ[k]
     os.environ.update(saved)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _heap_as_found():
+    """A node owns the process's old generation from setup() to stop()
+    (node/heapaging.py). A test file that leaves one running must not
+    hand the next file of its worker a frozen heap and a threshold out
+    of reach: what it left is given back here."""
+    yield
+    from stellard_tpu.node.heapaging import HEAP_AGING
+
+    while HEAP_AGING.owners:
+        HEAP_AGING.release()

@@ -51,11 +51,7 @@ def make_node(tmp_path, start_up="fresh") -> Node:
 
 
 def stop_node(n: Node) -> None:
-    n.verify_plane.stop()
-    n.job_queue.stop()
-    n.txdb.close()
-    n.clf.db.close()
-    n.nodestore.close()
+    n.stop()
 
 
 def payment(key, seq, dest, drops, fee=10):

@@ -290,8 +290,7 @@ class TestKnobAndCounters:
             assert counts["delta_replay"]["closes"] == 1
             assert "invalidated" in counts["delta_replay"]
         finally:
-            n.verify_plane.stop()
-            n.job_queue.stop()
+            n.stop()
 
     def test_disabled_knob_records_nothing(self):
         lm = LedgerMaster()

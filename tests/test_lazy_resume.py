@@ -113,13 +113,11 @@ def outcomes_ok(pump, entries):
         assert (ter, applied) == want
 
 
-@pytest.fixture(scope="module")
-def reference(store, tmp_path_factory):
+def plain_hashes(store, tmp):
     """The plain path's hashes: the stored ledger loaded eagerly into a
     cpu/hashlib node that applies serially and seals in full."""
     directory, entries = store
-    workdir, meta = prepared.copy_for_run(
-        directory, str(tmp_path_factory.mktemp("plain")))
+    workdir, meta = prepared.copy_for_run(directory, str(tmp))
     ini = nodedrive.ini_text(PLAIN_INI, workdir=os.path.join(workdir, "db"),
                              start_up="fresh")
     node = Node(Config.from_ini(ini)).setup()
@@ -138,6 +136,11 @@ def reference(store, tmp_path_factory):
     inner_node_cache().clear()
     shutil.rmtree(workdir, ignore_errors=True)
     return hashes
+
+
+@pytest.fixture(scope="module")
+def reference(store, tmp_path_factory):
+    return plain_hashes(store, tmp_path_factory.mktemp("plain"))
 
 
 @pytest.fixture(params=["native", "python"])

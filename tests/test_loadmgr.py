@@ -265,8 +265,7 @@ class TestEndToEndLoad:
     def node(self):
         n = Node(Config(standalone=True, signature_backend="cpu")).setup()
         yield n
-        n.verify_plane.stop()
-        n.job_queue.stop()
+        n.stop()
 
     def test_scaled_fee_rejects_underpayer(self, node):
         """With load escalation active, a tx paying the normal fee gets

@@ -160,8 +160,7 @@ class TestValidatorSources:
                 assert entries[v_file] == "from-file"
                 assert entries[v_site] == "from-site"
             finally:
-                node.verify_plane.stop()
-                node.job_queue.stop()
+                node.stop()
         finally:
             httpd.shutdown()
 
@@ -173,8 +172,7 @@ class TestValidatorSources:
             validators_site="http://127.0.0.1:9/stellar.txt",
         )
         node = Node(cfg).setup()
-        node.verify_plane.stop()
-        node.job_queue.stop()
+        node.stop()
 
 
 class TestLocalCredentials:
@@ -185,14 +183,12 @@ class TestLocalCredentials:
                      database_path=str(tmp_path / "tx.db"))
         n1 = Node(cfg).setup()
         pub1 = n1.node_keys.public
-        n1.verify_plane.stop()
-        n1.job_queue.stop()
+        n1.stop()
         n2 = Node(cfg).setup()
         try:
             assert n2.node_keys.public == pub1  # wallet.db role
         finally:
-            n2.verify_plane.stop()
-            n2.job_queue.stop()
+            n2.stop()
 
     def test_ephemeral_without_database_path(self):
         from stellard_tpu.node.node import Node
@@ -202,8 +198,7 @@ class TestLocalCredentials:
         try:
             assert n.node_keys is not None
         finally:
-            n.verify_plane.stop()
-            n.job_queue.stop()
+            n.stop()
 
 
 class TestIntakeOrdering:

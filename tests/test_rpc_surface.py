@@ -59,8 +59,7 @@ def node(tmp_path):
         sfTakerGets: STAmount.from_drops(10 * XRP)})
     n.close_ledger()
     yield n
-    n.verify_plane.stop()
-    n.job_queue.stop()
+    n.stop()
 
 
 def call(node_, method, role=Role.ADMIN, **params):
