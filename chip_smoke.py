@@ -62,7 +62,7 @@ RPC_CHECKS = 7
 # Unacknowledged submissions per wave. NetworkOPs sheds a submission once
 # more than TX_BACKLOG_SHED (100) verified transactions wait for the apply
 # job; shedding depends on timing and would break the byte-identity gate,
-# so the window stays below it (bench.py's _drive_node does the same).
+# so the window stays below it.
 WINDOW = 96
 # close times are hashed into the ledger: two runs on the wall clock never
 # match, so both nodes close on this pinned schedule
@@ -117,7 +117,6 @@ def build_ini(workdir: str, backend: str, mesh: int = 0,
         f"[node_db]\ntype=segstore\npath={store}\n\n"
         f"[database_path]\n{sqlite}\n\n"
         "[rpc_port]\n0\n\n"
-        "[kernel_tuning]\nnone\n\n"
         "[spec]\nworkers=1\n\n"
         # admission control stays on but non-binding: a single-account
         # flood would otherwise be shed by the adaptive caps, and

@@ -930,41 +930,38 @@ class _RoutedFlat:
 
 # flat batches below this never route to a device backend: a handful of
 # residual nodes can never amortize a device round-trip (the incremental
-# seal's drain leftovers are the motivating case). Env-overridable via
-# STELLARD_HASH_MIN_DEVICE_NODES on the watchdog.
+# seal's drain leftovers are the motivating case). [hash_backend]
+# min_device_nodes= overrides it.
 DEVICE_HASH_FLOOR = 64
 
 
 def make_watched_hasher(backend: str,
                         min_device_nodes: Optional[int] = None,
                         mesh=None,
-                        routing: Optional[str] = None,
+                        routing: str = "cost",
                         first_timeout: Optional[float] = None,
                         ) -> BatchHasher:
     """The ONE wiring for a possibly-device hasher: the tpu backend is
     wrapped in the wedge watchdog with a cpu fallback (a hung device
     call must degrade, not freeze) and the small-batch device floor; host
-    backends pass through untouched. Used by the node and the bench
-    legs so both always measure/run the identical construction.
+    backends pass through untouched.
 
     ``mesh`` is the [hash_backend] width axis (parse_mesh values). When
     it requests more than one chip, the watchdog gets BOTH a wide inner
     and a width-1 inner — the N-chip and 1-chip arms of the three-way
     measured-cost routing (host / 1-chip / N-chip), so small batches
     stay on host, medium batches on one chip, and only batches that
-    amortize the collective go wide. ``routing`` ("cost"/"device")
-    overrides STELLARD_HASH_ROUTING; ``first_timeout`` the wedge
-    deadline."""
+    amortize the collective go wide. ``routing`` is "cost" or "device";
+    ``first_timeout`` the wedge deadline; ``min_device_nodes`` None
+    means DEVICE_HASH_FLOOR."""
     opts = {}
     if backend == "tpu" and mesh is not None:
         opts["mesh"] = mesh
     hasher = make_hasher(backend, **opts)
     if backend == "tpu":
         floor = min_device_nodes
-        if floor is None:  # explicit arg > env > device-backend default
-            floor = int(os.environ.get(
-                "STELLARD_HASH_MIN_DEVICE_NODES", str(DEVICE_HASH_FLOOR)
-            ))
+        if floor is None:
+            floor = DEVICE_HASH_FLOOR
         inner_one = None
         if mesh_wants_width(mesh if mesh is not None else "auto"):
             # the 1-chip arm: the SAME sharded program at width 1
@@ -975,59 +972,6 @@ def make_watched_hasher(backend: str,
             first_timeout=first_timeout,
         )
     return hasher
-
-
-def apply_kernel_tuning(path: str) -> Optional[dict]:
-    """Apply an on-chip sweep's winning kernel configuration
-    (tools/kernel_sweep.py writes KERNEL_TUNING.json) as env defaults,
-    BEFORE any kernel module reads them. Explicit env settings win —
-    which also means the values are process-global and first-writer-
-    wins: a second tuning file applied in the same process is silently
-    inert (the kernel knobs are read once at module import, so env is
-    the only channel). Returns the parsed tuning dict when applied
-    (callers also use its 'batch'), else None — malformed or
-    unreadable files apply NOTHING (never a half-tuned combination).
-    Used by bench.py (repo root) and the node ([kernel_tuning] config
-    knob) so a daemon run honors the measured winner, not a hardcoded
-    default."""
-    import json
-
-    try:
-        with open(path) as f:
-            t = json.load(f)
-        # read every value BEFORE setting any env var: a partial file
-        # must not apply a half-tuned (never-measured) combination
-        values = {
-            "STELLARD_VERIFY_UNROLL": str(int(t["unroll"])),
-            "STELLARD_COMB_SELECT": str(t["comb"]),
-            "STELLARD_HOIST_SELECT": str(int(t.get("hoist", 0))),
-            "STELLARD_GROUP_OPS": str(int(t.get("group", 0))),
-            "STELLARD_VERIFY_IMPL": str(t.get("impl", "xla")),
-            "STELLARD_PALLAS_BLOCK": str(int(t.get("block", 512))),
-        }
-        # wire format is semantics-neutral (identical verdicts, pinned
-        # by tests) so a measured winner auto-applies — but a tuning row
-        # from before the wire field existed carries NO opinion, and
-        # must not drag the bench back to the fatter digits wire
-        if "wire" in t:
-            values["STELLARD_WIRE"] = str(t["wire"])
-        if values.get("STELLARD_WIRE", "raw") not in ("raw", "digits"):
-            raise ValueError(values["STELLARD_WIRE"])
-        if values["STELLARD_VERIFY_IMPL"] not in ("xla", "pallas"):
-            # a hand-edited file must not park a crash at the first
-            # device batch (_resolve_kernel validates the same set)
-            raise ValueError(values["STELLARD_VERIFY_IMPL"])
-        # NOTE: "check" (STELLARD_VERIFY_CHECK) is deliberately NOT
-        # auto-applied. Unlike the knobs above it changes the computed
-        # verify FUNCTION (byte-compare vs projective equality) — a
-        # consensus-semantics choice that must be an explicit operator
-        # decision (env var), never a perf-sweep side effect.
-        int(t["batch"])  # validated for callers
-    except (OSError, ValueError, KeyError, TypeError):
-        return None
-    for k, v in values.items():
-        os.environ.setdefault(k, v)
-    return t
 
 
 class _HashCostModel:
@@ -1069,8 +1013,8 @@ class _HashCostModel:
         return ms if cur is None else (1 - self.EWMA) * cur + self.EWMA * ms
 
     def get_json(self) -> dict:
-        """Routing-model snapshot (bench provenance / BENCH_DETAIL /
-        the get_counts crypto block). `buckets` keeps the legacy
+        """Routing-model snapshot (the get_counts crypto block).
+        `buckets` keeps the legacy
         single-arm view (the primary device arm); `arms` is the full
         three-way snapshot."""
         with self._lock:
@@ -1189,9 +1133,9 @@ class WatchdogHasher(BatchHasher):
     def __init__(self, inner: BatchHasher, fallback: BatchHasher,
                  first_timeout: Optional[float] = None,
                  warm_timeout: Optional[float] = None,
-                 min_device_nodes: Optional[int] = None,
+                 min_device_nodes: int = 0,
                  inner_one: Optional[BatchHasher] = None,
-                 routing: Optional[str] = None):
+                 routing: str = "cost"):
         from ..utils.devicewatch import resolve_timeouts
 
         self.inner = inner
@@ -1207,38 +1151,28 @@ class WatchdogHasher(BatchHasher):
         # measured-cost routing (same stance as VerifyPlane's model: the
         # device must EARN traffic; a losing device floors at the host
         # path instead of dragging a leg, and is re-explored bounded).
-        # routing="device" (or STELLARD_HASH_ROUTING=device) restores
-        # route-everything-device — the widest arm.
+        # routing="device" restores route-everything-device — the
+        # widest arm.
         # (A separate small model rather than verifyplane._LatencyModel:
         # the units differ — per-node hash rates vs per-signature verify
         # costs — and the verify model is entangled with pad-bucket
         # warmth bookkeeping this wrapper has no analog for.)
-        mode = routing if routing else os.environ.get(
-            "STELLARD_HASH_ROUTING", "cost"
-        )
-        if mode not in ("cost", "device"):
+        if routing not in ("cost", "device"):
             raise ValueError(
-                f"hash routing must be cost|device, got {mode!r}"
+                f"hash routing must be cost|device, got {routing!r}"
             )
-        self.routing = mode
-        self._route_by_cost = mode != "device"
+        self.routing = routing
+        self._route_by_cost = routing != "device"
         # device floor: flat batches below this size never route to the
         # device, and tree hashing with a caller-supplied dirty-count
         # hint below it goes straight to the host level-batcher — the
         # incremental seal's residuals must not burn a device round-trip
-        # per close. Explicit arg wins; STELLARD_HASH_MIN_DEVICE_NODES
-        # next; default 0 (a watchdog wrapped around a HOST inner — the
-        # test harness shape — must not divert its inner's traffic).
+        # per close. Default 0 (a watchdog wrapped around a HOST inner —
+        # the test harness shape — must not divert its inner's traffic);
         # make_watched_hasher applies the device-backend default.
-        if min_device_nodes is None:
-            floor = int(os.environ.get("STELLARD_HASH_MIN_DEVICE_NODES", "0"))
-        else:
-            floor = int(min_device_nodes)
+        floor = int(min_device_nodes)
         if floor < 0:
-            raise ValueError(
-                "STELLARD_HASH_MIN_DEVICE_NODES must be >= 0, got "
-                f"{floor}"
-            )
+            raise ValueError(f"min_device_nodes must be >= 0, got {floor}")
         self.min_device_nodes = floor
         self._arm_names = (
             ("dev1", "devN") if inner_one is not None else ("device",)
@@ -1494,7 +1428,7 @@ class WatchdogHasher(BatchHasher):
 
 # candidate batches below this never route to a device: a path_find with
 # a handful of candidates can never amortize a dispatch (the sig/hash
-# planes' DEVICE_*_FLOOR stance). Env-overridable on the evaluator.
+# planes' DEVICE_*_FLOOR stance). [paths] min_device_batch= overrides it.
 PATHQ_DEVICE_FLOOR = 256
 
 
@@ -1516,15 +1450,11 @@ class PathQualityEvaluator:
     the host arm.
     """
 
-    def __init__(self, mesh=None, min_device_batch: Optional[int] = None,
-                 routing: Optional[str] = None):
+    def __init__(self, mesh=None,
+                 min_device_batch: int = PATHQ_DEVICE_FLOOR,
+                 routing: str = "cost"):
         self.mesh = parse_mesh(mesh)
-        if min_device_batch is None:
-            min_device_batch = int(os.environ.get(
-                "STELLARD_PATHQ_MIN_DEVICE_BATCH", str(PATHQ_DEVICE_FLOOR)
-            ))
-        routing = (routing or os.environ.get(
-            "STELLARD_PATHQ_ROUTING", "cost")).strip().lower()
+        routing = routing.strip().lower()
         if routing not in ("cost", "device", "host"):
             raise ValueError(
                 f"path evaluator routing must be cost|device|host, "
@@ -1630,12 +1560,3 @@ class PathQualityEvaluator:
             **counters,
             "model": self._model.get_json(),
         }
-
-
-def make_path_evaluator(mesh=None, min_device_batch: Optional[int] = None,
-                        routing: Optional[str] = None) -> PathQualityEvaluator:
-    """The ONE wiring for the path-quality evaluator (node, bench and
-    smokes all construct the identical arrangement)."""
-    return PathQualityEvaluator(
-        mesh=mesh, min_device_batch=min_device_batch, routing=routing,
-    )

@@ -58,6 +58,20 @@ def _reject_unknown(section: str, kv: dict, known: tuple) -> None:
         )
 
 
+def _reject_kernel_tuning(values: list) -> None:
+    """The retired [kernel_tuning] section: configs written before it
+    went still carry ``none``/``off`` (or nothing), which parse and do
+    nothing. A path is refused — an operator who points at a sweep file
+    must not believe it applied."""
+    for value in values:
+        if value.lower() not in ("none", "off"):
+            raise ValueError(
+                f"[kernel_tuning] {value!r}: kernel variants are no "
+                "longer selectable from a file; remove the section (or "
+                "leave it none/off)"
+            )
+
+
 def _crypto_mesh(section: str, backend: str, kv: dict, default: str) -> str:
     """Validated `mesh=` for a crypto section: parse_mesh canonicalizes
     (0/N/auto; garbage raises), and a mesh request on a HOST backend is
@@ -77,9 +91,7 @@ def _crypto_mesh(section: str, backend: str, kv: dict, default: str) -> str:
 
 
 def _crypto_routing(section: str, kv: dict) -> str:
-    if "routing" not in kv:
-        return ""
-    routing = kv["routing"].strip().lower()
+    routing = kv.get("routing", "cost").strip().lower()
     if routing not in ("cost", "device"):
         # a routing toggle must not fail open into an unintended mode
         raise ValueError(
@@ -131,10 +143,6 @@ def resolve_spec_workers(workers, cpu_count=None, log=None) -> int:
             )
         return 1
     return min(8, ncpu)
-
-
-# default [kernel_tuning] path, shared with Node's outcome logging
-DEFAULT_KERNEL_TUNING = "KERNEL_TUNING.json"
 
 
 @dataclass
@@ -220,9 +228,9 @@ class Config:
     hash_mesh: str = "auto"
     # routing= cost (default: measured-latency host/1-chip/N-chip
     # routing) | device (force every eligible batch onto the widest
-    # arm — the anti-vacuity mode smokes/benches use)
-    verify_routing: str = ""  # "" = env default (STELLARD_VERIFY_ROUTING)
-    hash_routing: str = ""    # "" = env default (STELLARD_HASH_ROUTING)
+    # arm — the anti-vacuity mode the smokes use)
+    verify_routing: str = "cost"
+    hash_routing: str = "cost"
     # host-side thread pool for the cpu signature backend
     verify_threads: int = 4
     # device-wedge watchdog deadlines (utils.devicewatch defaults when
@@ -231,13 +239,8 @@ class Config:
     verify_device_warm_timeout_s: Optional[float] = None
     hash_device_first_timeout_s: Optional[float] = None
     # flat-batch device floor for the hash plane (None = the
-    # make_watched_hasher default / STELLARD_HASH_MIN_DEVICE_NODES)
+    # make_watched_hasher default, DEVICE_HASH_FLOOR)
     hash_min_device_nodes: Optional[int] = None
-    # [kernel_tuning]: path to an on-chip sweep's KERNEL_TUNING.json —
-    # applied as env defaults at node setup so a daemon honors the
-    # measured kernel winner (default: the file name in the CWD, if
-    # any; "none"/"off" disables)
-    kernel_tuning: str = DEFAULT_KERNEL_TUNING
 
     # -- ledger-close pipeline ([close_pipeline]) --------------------------
     # enabled=1: standalone closes hand persistence (NodeStore flush,
@@ -635,7 +638,7 @@ class Config:
             device_only=("routing", "min_device_nodes",
                          "device_first_timeout_s"),
         )
-        cfg.kernel_tuning = one("kernel_tuning", cfg.kernel_tuning)
+        _reject_kernel_tuning(s.get("kernel_tuning", []))
         cp = _kv(s.get("close_pipeline", []))
         if "enabled" in cp:
             cfg.close_pipeline_enabled = cp["enabled"].lower() not in (

@@ -20,7 +20,7 @@ from ..protocol.keys import KeyPair, decode_seed
 from ..protocol.sttx import SerializedTransaction
 from ..protocol.ter import TER
 from ..state.ledger import Ledger
-from .config import DEFAULT_KERNEL_TUNING, Config
+from .config import Config
 from .hashrouter import HashRouter
 from .heapaging import HEAP_AGING
 from .jobqueue import JobQueue
@@ -133,44 +133,6 @@ def _result_token(txid: bytes, results: dict, meta: Optional[bytes]) -> str:
     return TER.tesSUCCESS.token
 
 
-def _apply_kernel_tuning(cfg: Config) -> None:
-    """[kernel_tuning]: a sweep's winning kernel configuration as env
-    defaults (explicit env settings win), applied BEFORE any kernel
-    module reads them. Outcomes are operator-visible: a missing DEFAULT
-    path is normal; an explicitly configured path that fails to apply
-    is a loud warning (stated stance: degraded subsystems report, never
-    stay silent)."""
-    if not cfg.kernel_tuning or cfg.kernel_tuning.lower() in ("none", "off"):
-        return
-    import logging
-
-    from ..crypto.backend import apply_kernel_tuning
-
-    tuned = apply_kernel_tuning(cfg.kernel_tuning)
-    lg = logging.getLogger("stellard.device")
-    if tuned is not None:
-        lg.info(
-            "kernel tuning applied from %s (impl=%s batch=%s)",
-            cfg.kernel_tuning, tuned.get("impl", "xla"),
-            tuned.get("batch"),
-        )
-    elif os.path.exists(cfg.kernel_tuning):
-        # present but unusable is a fault at ANY path — the
-        # operator believes the measured winner is applied
-        lg.warning(
-            "[kernel_tuning] %s exists but is malformed — "
-            "running with hardcoded kernel defaults",
-            cfg.kernel_tuning,
-        )
-    elif cfg.kernel_tuning != DEFAULT_KERNEL_TUNING:
-        # a missing DEFAULT path is normal; a missing
-        # explicitly-configured path is an operator mistake
-        lg.warning(
-            "[kernel_tuning] %s not found — running with "
-            "hardcoded kernel defaults", cfg.kernel_tuning,
-        )
-
-
 def make_crypto_planes(cfg: Config, tracer=None):
     """-> (hasher, verify_plane): the ONE config -> device-plane wiring,
     shared by ``Node.setup`` and the offline ``--replay`` tool so both
@@ -184,7 +146,6 @@ def make_crypto_planes(cfg: Config, tracer=None):
 
     from ..crypto.backend import ensure_jax, make_watched_hasher
 
-    _apply_kernel_tuning(cfg)
     if "tpu" in (cfg.signature_backend, cfg.hash_backend):
         # a backend named `tpu` runs on whatever platform JAX gives it
         # (the test suite relies on that: a virtual CPU mesh). Touch
@@ -205,7 +166,7 @@ def make_crypto_planes(cfg: Config, tracer=None):
         cfg.hash_backend,
         min_device_nodes=cfg.hash_min_device_nodes,
         mesh=cfg.hash_mesh,
-        routing=cfg.hash_routing or None,
+        routing=cfg.hash_routing,
         first_timeout=cfg.hash_device_first_timeout_s,
     )
     # [tree] fused=0 kill-switch: compute_hashes / the seal drainer
@@ -218,7 +179,7 @@ def make_crypto_planes(cfg: Config, tracer=None):
         max_batch=cfg.verify_max_batch,
         min_device_batch=cfg.verify_min_device_batch,
         backend_opts=cfg.verify_backend_opts(),
-        routing=cfg.verify_routing or None,
+        routing=cfg.verify_routing,
         device_first_timeout=cfg.verify_device_first_timeout_s,
         device_warm_timeout=cfg.verify_device_warm_timeout_s,
         tracer=tracer,
@@ -958,12 +919,12 @@ class Node:
         # a warm index without ever rescanning unchanged books.
         self.path_plane = None
         if cfg.paths_enabled:
-            from ..crypto.backend import make_path_evaluator
+            from ..crypto.backend import PathQualityEvaluator
             from ..paths.plane import PathPlane
 
             evaluator = None
             if cfg.paths_device_prune:
-                evaluator = make_path_evaluator(
+                evaluator = PathQualityEvaluator(
                     mesh=cfg.paths_mesh,
                     min_device_batch=cfg.paths_min_device_batch,
                     routing=cfg.paths_routing,

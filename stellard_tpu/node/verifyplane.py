@@ -26,7 +26,6 @@ Callers either:
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import time
 from concurrent.futures import Future
@@ -230,7 +229,7 @@ class VerifyPlane:
         device_warm_timeout: Optional[float] = None,
         tracer=None,
         backend_opts: Optional[dict] = None,
-        routing: Optional[str] = None,
+        routing: str = "cost",
     ):
         from ..crypto.backend import mesh_wants_width
         from .tracer import get_tracer
@@ -269,17 +268,14 @@ class VerifyPlane:
         self._device_capable = backend != "cpu"
         # routing=device forces every eligible (>= min_device_batch)
         # batch onto the widest device arm — the anti-vacuity mode the
-        # meshsmoke gate and on-chip benches use; cost (default) is the
-        # measured-latency routing. Explicit arg > env > default.
-        mode = routing if routing else os.environ.get(
-            "STELLARD_VERIFY_ROUTING", "cost"
-        )
-        if mode not in ("cost", "device"):
+        # meshsmoke gate uses; cost (default) is the measured-latency
+        # routing.
+        if routing not in ("cost", "device"):
             raise ValueError(
-                f"verify routing must be cost|device, got {mode!r}"
+                f"verify routing must be cost|device, got {routing!r}"
             )
-        self.routing = mode
-        self._route_by_cost = mode != "device"
+        self.routing = routing
+        self._route_by_cost = routing != "device"
         # device-wedge watchdog deadlines (utils.devicewatch): the first
         # call to a pad-bucket shape legitimately compiles (~1-3 min on
         # chip), so unseen shapes get the generous deadline and warmed

@@ -75,13 +75,7 @@ from .fe25519 import (
 WINDOW = 4
 NWINDOWS = 64  # ceil(256/4); scalars are < l < 2^253
 
-# static unroll factor for the 64-iteration scalar-walk loop: >1 gives
-# XLA a bigger window to software-pipeline at the cost of compile time.
-# Read once at import (a jit-time constant); default 1 keeps the graph
-# byte-identical to the rolled form (and the compilation cache warm).
-_UNROLL = int(os.environ.get("STELLARD_VERIFY_UNROLL", "1"))
-
-# comb-table selection strategy (A/B'd by tools/kernel_sweep.py):
+# comb-table selection strategy:
 #   mxu       — one [60,16]@[16,B] f32 matmul at HIGHEST precision
 #               (3 MXU passes; exact for 13-bit limbs)
 #   mxu_split — TWO one-pass matmuls on 7-bit/6-bit limb halves
@@ -90,22 +84,6 @@ _UNROLL = int(os.environ.get("STELLARD_VERIFY_UNROLL", "1"))
 #   vpu       — int32 one-hot contraction on the VPU (no int<->float
 #               converts, ~960 lane mult-adds per window)
 _COMB_SELECT = os.environ.get("STELLARD_COMB_SELECT", "mxu")
-
-# hoist ALL 64 window selections of both scalar walks out of the loop
-# into two wide contractions (1) vs select per-iteration inside the loop
-# (0). Hoisting materialises [64, 4, 20, B] / [64, 3, 20, B] selected-
-# window tensors in HBM; measured on-chip (r4) that LOSES to in-loop
-# selection and the gap grows with batch (16384: 63.7k vs 99.9k sigs/s),
-# so the default is the measured winner. Kept as a knob because the
-# op-count model says it should win — future XLA versions may differ.
-_HOIST_SELECT = os.environ.get("STELLARD_HOIST_SELECT", "0") == "1"
-
-# merge the 3-4 independent field muls/squares inside each point formula
-# into one wider op (concat along the batch axis). Measured on-chip (r4,
-# batch 16384): grouping LOSES 100.7k -> 63.2k sigs/s — the concats and
-# slices around each widened op cost more than the op-count saving —
-# so the default is ungrouped. Knob kept for re-measurement.
-_GROUP_OPS = os.environ.get("STELLARD_GROUP_OPS", "0") == "1"
 
 # final-check formulation:
 #   bytes — encode([S]B + [h](-A)) and byte-compare against R: the
@@ -151,34 +129,6 @@ def pt_identity(batch_shape=()):
     )
 
 
-def _mul_many(pairs):
-    """K independent field multiplies as ONE wide multiply.
-
-    A TPU core executes the post-fusion op sequence serially, so K
-    narrow multiplies cost ~K times one wide one; concatenating the
-    operands along the minor (lane) axis turns them into a single
-    K-times-wider op at the same lane-op count. All operands must share
-    one shape [20, *batch]."""
-    k = len(pairs)
-    if k == 1 or not _GROUP_OPS:
-        return [fe_mul(a, b) for a, b in pairs]
-    n = pairs[0][0].shape[-1]
-    a = jnp.concatenate([p[0] for p in pairs], axis=-1)
-    b = jnp.concatenate([p[1] for p in pairs], axis=-1)
-    c = fe_mul(a, b)
-    return [c[..., i * n : (i + 1) * n] for i in range(k)]
-
-
-def _square_many(xs):
-    """K independent field squarings as ONE wide squaring (see
-    _mul_many)."""
-    if len(xs) == 1 or not _GROUP_OPS:
-        return [fe_square(x) for x in xs]
-    n = xs[0].shape[-1]
-    c = fe_square(jnp.concatenate(xs, axis=-1))
-    return [c[..., i * n : (i + 1) * n] for i in range(len(xs))]
-
-
 def pt_to_cached(p):
     """extended -> cached: 1M + 3 add."""
     x, y, z, t = p[0], p[1], p[2], p[3]
@@ -189,34 +139,35 @@ def pt_to_cached(p):
 
 
 def pt_add_cached(p, q_cached):
-    """Complete unified addition, q in cached form: 8M (2 wide ops)."""
+    """Complete unified addition, q in cached form: 8M."""
     x1, y1, z1, t1 = p[0], p[1], p[2], p[3]
     ypx2, ymx2, t2d2, z22 = q_cached[0], q_cached[1], q_cached[2], q_cached[3]
-    a, b, c, d = _mul_many(
-        [(fe_sub(y1, x1), ymx2), (fe_add(y1, x1), ypx2), (t1, t2d2), (z1, z22)]
-    )
+    ymx1, ypx1 = fe_sub(y1, x1), fe_add(y1, x1)
+    a = fe_mul(ymx1, ymx2)
+    b = fe_mul(ypx1, ypx2)
+    c = fe_mul(t1, t2d2)
+    d = fe_mul(z1, z22)
     e = fe_sub(b, a)
     f = fe_sub(d, c)
     g = fe_add(d, c)
     h = fe_add(b, a)
-    x3, y3, z3, t3 = _mul_many([(e, f), (g, h), (f, g), (e, h)])
-    return pt_stack(x3, y3, z3, t3)
+    return pt_stack(fe_mul(e, f), fe_mul(g, h), fe_mul(f, g), fe_mul(e, h))
 
 
 def pt_add_mixed(p, q_niels):
     """Complete unified addition, q in niels form (Z2 = 1): 7M."""
     x1, y1, z1, t1 = p[0], p[1], p[2], p[3]
     ypx2, ymx2, t2d2 = q_niels[0], q_niels[1], q_niels[2]
-    a, b, c = _mul_many(
-        [(fe_sub(y1, x1), ymx2), (fe_add(y1, x1), ypx2), (t1, t2d2)]
-    )
+    ymx1, ypx1 = fe_sub(y1, x1), fe_add(y1, x1)
+    a = fe_mul(ymx1, ymx2)
+    b = fe_mul(ypx1, ypx2)
+    c = fe_mul(t1, t2d2)
     d = fe_add(z1, z1)
     e = fe_sub(b, a)
     f = fe_sub(d, c)
     g = fe_add(d, c)
     h = fe_add(b, a)
-    x3, y3, z3, t3 = _mul_many([(e, f), (g, h), (f, g), (e, h)])
-    return pt_stack(x3, y3, z3, t3)
+    return pt_stack(fe_mul(e, f), fe_mul(g, h), fe_mul(f, g), fe_mul(e, h))
 
 
 def pt_add(p, q):
@@ -231,17 +182,22 @@ def pt_double(p, need_t: bool = True):
     multiply (the doubling itself reads just X/Y/Z). The T slot of a
     ``need_t=False`` result is a placeholder and must not be read."""
     x1, y1, z1 = p[0], p[1], p[2]
-    a, b, zz, sq = _square_many([x1, y1, z1, fe_add(x1, y1)])
+    xpy = fe_add(x1, y1)
+    a = fe_square(x1)
+    b = fe_square(y1)
+    zz = fe_square(z1)
+    sq = fe_square(xpy)
     c = fe_add(zz, zz)
     e = fe_sub(fe_sub(sq, a), b)
     g = fe_sub(b, a)  # a_coeff=-1: G = aA + B = B - A
     f = fe_sub(g, c)  # F = G - C
     h = fe_sub(fe_neg(a), b)  # H = aA - B = -A - B
-    if need_t:
-        x3, y3, z3, t3 = _mul_many([(e, f), (g, h), (f, g), (e, h)])
-    else:
-        x3, y3, z3 = _mul_many([(e, f), (g, h), (f, g)])
-        t3 = z3  # placeholder, never read (any bounded value works)
+    x3 = fe_mul(e, f)
+    y3 = fe_mul(g, h)
+    z3 = fe_mul(f, g)
+    # without T the slot holds a placeholder that is never read (any
+    # bounded value works)
+    t3 = fe_mul(e, h) if need_t else z3
     return pt_stack(x3, y3, z3, t3)
 
 
@@ -324,23 +280,6 @@ def _build_cached_table(p):
     m8 = pt_double(m4)
     cached = [ident, c1, c2, c3, c4] + [pt_to_cached(m) for m in (m5, m6, m7, m8)]
     return jnp.stack(cached, axis=0)
-
-
-def _build_cached_table_signed(p):
-    """p extended [4, 20, *batch] -> [17, 4, 20, *batch] cached multiples
-    for signed digits -8..8 (index d + 8).
-
-    Baking the negative entries into the table (cached-form negation:
-    swap Y+X/Y-X, negate 2dT) lets the per-window selection be one plain
-    one-hot contraction with no post-selection fixups — which in turn
-    lets ALL 64 window selections hoist out of the scalar-walk loop as a
-    single contraction."""
-    pos = _build_cached_table(p)  # [9, 4, 20, *batch], digits 0..8
-    negs = [
-        jnp.stack([pos[k, 1], pos[k, 0], fe_neg(pos[k, 2]), pos[k, 3]], axis=0)
-        for k in range(8, 0, -1)
-    ]  # digits -8..-1
-    return jnp.concatenate([jnp.stack(negs, axis=0), pos], axis=0)
 
 
 def _select_cached(tbl, digit):
@@ -525,56 +464,7 @@ def verify_kernel(a_words, r_words, s_windows, h_digits, s_canonical):
             .reshape((3, NLIMB) + w.shape)
         )
 
-    if _HOIST_SELECT:
-        # Hoisted window selections: ALL 64 windows of both scalar walks
-        # selected before the loop in two wide contractions, so the loop
-        # body is pure point arithmetic. Measured on-chip (r4) this
-        # LOSES — the [64, ., 20, B] selected-window tensors live in HBM
-        # and the loop re-reads them — but the knob stays for A/B.
-        htbl = _build_cached_table_signed(pt_neg(a_point))  # [17,4,20,B]
-        onehot_h = (
-            hd[:, None, :]
-            == (jnp.arange(17, dtype=hd.dtype) - 8)[None, :, None]
-        ).astype(jnp.int32)  # [64, 17, B]
-        hsel = jnp.einsum("wsb,scdb->wcdb", onehot_h, htbl)  # [64,4,20,B]
-        # [S]B comb windows in one wide contraction (all 64 at once):
-        if _COMB_SELECT == "vpu":
-            onehot_i = (
-                sw[:, None, :]
-                == jnp.arange(16, dtype=sw.dtype)[None, :, None]
-            ).astype(jnp.int32)  # [64, 16, B]
-            csel = jnp.einsum(
-                "jlw,jwb->jlb", comb.astype(jnp.int32), onehot_i
-            )
-        else:
-            onehot_s = (
-                sw[:, None, :]
-                == jnp.arange(16, dtype=sw.dtype)[None, :, None]
-            ).astype(jnp.float32)  # [64, 16, B]
-            if _COMB_SELECT == "mxu_split":
-                comb_i = comb.astype(jnp.int32)
-                lo = (comb_i & 0x7F).astype(jnp.float32)
-                hi = (comb_i >> 7).astype(jnp.float32)
-                sel_lo = jnp.einsum(
-                    "jlw,jwb->jlb", lo, onehot_s
-                ).astype(jnp.int32)
-                sel_hi = jnp.einsum(
-                    "jlw,jwb->jlb", hi, onehot_s
-                ).astype(jnp.int32)
-                csel = (sel_hi << 7) + sel_lo
-            else:
-                csel = jnp.einsum(
-                    "jlw,jwb->jlb",
-                    comb,
-                    onehot_s,
-                    precision=lax.Precision.HIGHEST,
-                ).astype(jnp.int32)
-        csel = csel.reshape(
-            (NWINDOWS, 3, NLIMB) + sw.shape[1:]
-        )  # [64, 3, 20, B]
-    else:
-        htbl = _build_cached_table(pt_neg(a_point))  # [9, 4, 20, B]
-        hsel = csel = None
+    htbl = _build_cached_table(pt_neg(a_point))  # [9, 4, 20, B]
 
     zero = _batch_zero(sw)
     acc0_h = pt_identity(sw.shape[1:]) + zero
@@ -587,30 +477,19 @@ def verify_kernel(a_words, r_words, s_windows, h_digits, s_canonical):
             # only the add after the chain reads T: skip its multiply
             # on all but the last doubling (saves 3 of ~34 muls/window)
             acc_h = pt_double(acc_h, need_t=(i == WINDOW - 1))
-        if _HOIST_SELECT:
-            hs = lax.dynamic_index_in_dim(
-                hsel, NWINDOWS - 1 - j, axis=0, keepdims=False
-            )
-            cs = lax.dynamic_index_in_dim(csel, j, axis=0, keepdims=False)
-        else:
-            d = lax.dynamic_index_in_dim(
-                hd, NWINDOWS - 1 - j, axis=0, keepdims=False
-            )
-            hs = _select_cached(htbl, d)
-            tj = lax.dynamic_index_in_dim(comb, j, axis=0, keepdims=False)
-            w = lax.dynamic_index_in_dim(sw, j, axis=0, keepdims=False)
-            cs = comb_entry(tj, w)
+        d = lax.dynamic_index_in_dim(
+            hd, NWINDOWS - 1 - j, axis=0, keepdims=False
+        )
+        hs = _select_cached(htbl, d)
+        tj = lax.dynamic_index_in_dim(comb, j, axis=0, keepdims=False)
+        w = lax.dynamic_index_in_dim(sw, j, axis=0, keepdims=False)
+        cs = comb_entry(tj, w)
         acc_h = pt_add_cached(acc_h, hs)
         # [S]B: comb window j, mixed add of the selected entry
         acc_s = pt_add_mixed(acc_s, cs)
         return acc_h, acc_s
 
-    if _UNROLL > 1:
-        acc_h, acc_s = lax.fori_loop(
-            0, NWINDOWS, body, (acc0_h, acc0_s), unroll=_UNROLL
-        )
-    else:
-        acc_h, acc_s = lax.fori_loop(0, NWINDOWS, body, (acc0_h, acc0_s))
+    acc_h, acc_s = lax.fori_loop(0, NWINDOWS, body, (acc0_h, acc0_s))
     rp = pt_add_cached(acc_s, pt_to_cached(acc_h))
     return final_check(rp, rw, r_point, valid, r_canon, s_canonical)
 

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax import lax
 from contextlib import contextmanager
 
-import os
 
 NLIMB = 20
 BITS = 13
@@ -208,26 +207,6 @@ def _finish_mul_t(lo: jnp.ndarray, hi: jnp.ndarray) -> jnp.ndarray:
     return _carry(c, 2)  # limbs <= MASK + 33 < BOUND
 
 
-def _finish_mul(lo_cols: list, hi_cols: list) -> jnp.ndarray:
-    return _finish_mul_t(jnp.stack(lo_cols, axis=0), jnp.stack(hi_cols, axis=0))
-
-
-# Multiply formulation. The original "legacy" form emits every one of the
-# ~400 limb products and ~580 column adds as its own [*batch]-shaped 1-D
-# XLA op (the per-limb Python slicing drops the limb axis), and measured
-# on-chip the kernel's cost tracks that op COUNT, not its FLOPs — a TPU
-# core runs the post-fusion op sequence serially, so thousands of
-# vector-register-sized ops are pure sequencing overhead. The "rowpad"
-# form keeps the limb axis inside the tensors: 20 shifted row-products,
-# padded to the 39-column width and summed in one reduction — ~45 wide
-# ops instead of ~1000 tiny ones, identical arithmetic and bounds.
-_FE_MUL_IMPL = os.environ.get("STELLARD_FE_MUL", "rowpad")
-if _FE_MUL_IMPL not in ("rowpad", "legacy"):
-    raise ValueError(
-        f"STELLARD_FE_MUL={_FE_MUL_IMPL!r}: expected 'rowpad' or 'legacy'"
-    )
-
-
 def _rows_padsum(rows: list) -> jnp.ndarray:
     """rows[i]: [len_i, *batch] partial products whose limb 0 sits at
     column offset off_i; returns [39, *batch] column sums."""
@@ -240,57 +219,26 @@ def _rows_padsum(rows: list) -> jnp.ndarray:
 
 
 def fe_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Schoolbook 20x20 product -> 39 column sums + fold."""
+    """Schoolbook 20x20 product -> 39 column sums + fold.
+
+    The limb axis stays inside the tensors: 20 shifted row-products,
+    padded to the 39-column width and summed in one reduction (~45 wide
+    ops). A TPU core runs the post-fusion op sequence serially, so the
+    kernel's cost tracks the op COUNT, not its FLOPs."""
     a, b = jnp.broadcast_arrays(*_align2(a, b))
-    if _FE_MUL_IMPL == "legacy":
-        ai = [a[i] for i in range(NLIMB)]
-        bi = [b[i] for i in range(NLIMB)]
-        lo_cols, hi_cols = [], []
-        for k in range(2 * NLIMB - 1):
-            terms = [
-                ai[i] * bi[k - i]
-                for i in range(max(0, k - 19), min(NLIMB, k + 1))
-            ]
-            s = terms[0]
-            for t in terms[1:]:
-                s = s + t
-            (lo_cols if k < NLIMB else hi_cols).append(s)
-        return _finish_mul(lo_cols, hi_cols)
-    # rowpad: row i = a_i * b lands at columns i..i+19
+    # row i = a_i * b lands at columns i..i+19
     cols = _rows_padsum([(i, a[i] * b) for i in range(NLIMB)])
     return _finish_mul_t(cols[:NLIMB], cols[NLIMB:])
 
 
 def fe_square(a: jnp.ndarray) -> jnp.ndarray:
     """Symmetric schoolbook square: halved off-diagonal work."""
-    if _FE_MUL_IMPL == "legacy":
-        ai = [a[i] for i in range(NLIMB)]
-        lo_cols, hi_cols = [], []
-        for k in range(2 * NLIMB - 1):
-            i = max(0, k - 19)
-            j = k - i
-            terms = []
-            while i < j:
-                terms.append(ai[i] * ai[j])
-                i += 1
-                j -= 1
-            s = None
-            if terms:
-                s = terms[0]
-                for t in terms[1:]:
-                    s = s + t
-                s = s + s  # off-diagonal pairs count twice
-            if i == j:
-                d = ai[i] * ai[i]
-                s = d if s is None else s + d
-            (lo_cols if k < NLIMB else hi_cols).append(s)
-        return _finish_mul(lo_cols, hi_cols)
-    # rowpad: row i = a_i * (a_i, 2a_{i+1}, .., 2a_19) lands at columns
+    # row i = a_i * (a_i, 2a_{i+1}, .., 2a_19) lands at columns
     # 2i..i+19; every i<j pair appears once, doubled. Bounds: column k
     # sums the pairs (i, k-i) with i <= k-i < 20 — at most 10 of them
     # (k = 19: (0,19)..(9,10); k = 20: (1,19)..(10,10)) — each term
     # <= 2*BOUND^2 = 1.805e8, so the worst column is 10 * 1.805e8 =
-    # 1.805e9 < 2^31, the same slack the legacy halved form relied on.
+    # 1.805e9 < 2^31.
     rows = []
     for i in range(NLIMB):
         seg = a[i] * a[i:]  # [NLIMB - i, *batch]

@@ -2121,7 +2121,7 @@ def do_blacklist(ctx: Context) -> dict:
 @handler("profile", Role.ADMIN)
 def do_profile(ctx: Context) -> dict:
     """Device-plane profiler control (SURVEY §5 tracing). The reference's
-    Profile.cpp was a load generator (bench.py is that harness here);
+    Profile.cpp was a load generator (benchmarks/ is that harness here);
     this build's `profile` instead captures a JAX/XLA profiler trace of
     what the device actually executes — TensorBoard XPlane format.
 

@@ -31,23 +31,6 @@ enable_compilation_cache()
 import pytest  # noqa: E402
 
 
-@pytest.fixture(autouse=True)
-def _stellard_env_guard():
-    """Snapshot/restore STELLARD_* env around every test: node setup
-    applies [kernel_tuning] as process-wide env setdefaults, and tests
-    force kernel knobs — neither may leak into later tests. (Module-
-    import-time sets in test files intentionally persist: the kernel
-    modules read them once at import.)"""
-    saved = {
-        k: v for k, v in os.environ.items() if k.startswith("STELLARD_")
-    }
-    yield
-    for k in [k for k in os.environ if k.startswith("STELLARD_")]:
-        if k not in saved:
-            del os.environ[k]
-    os.environ.update(saved)
-
-
 @pytest.fixture(autouse=True, scope="module")
 def _heap_as_found():
     """A node owns the process's old generation from setup() to stop()

@@ -314,12 +314,13 @@ class TestHashCostRouting:
                 for p, d in zip(prefixes, payloads)
             ]
 
-    def _mk(self, dev_delay, host_delay):
+    def _mk(self, dev_delay, host_delay, routing="cost"):
         from stellard_tpu.crypto.backend import WatchdogHasher
 
         dev = self._Fake(dev_delay)
         host = self._Fake(host_delay)
-        w = WatchdogHasher(dev, host, first_timeout=30, warm_timeout=30)
+        w = WatchdogHasher(dev, host, first_timeout=30, warm_timeout=30,
+                           routing=routing)
         return w, dev, host
 
     def test_slow_device_floors_at_host(self):
@@ -341,9 +342,9 @@ class TestHashCostRouting:
         assert host.calls == 1
         assert dev.calls >= 10
 
-    def test_device_mode_restores_unconditional_routing(self, monkeypatch):
-        monkeypatch.setenv("STELLARD_HASH_ROUTING", "device")
-        w, dev, host = self._mk(dev_delay=0.02, host_delay=0.0)
+    def test_device_mode_restores_unconditional_routing(self):
+        w, dev, host = self._mk(dev_delay=0.02, host_delay=0.0,
+                                routing="device")
         batch = ([0x1234] * 4, [b"x" * 40] * 4)
         for _ in range(6):
             w.prefix_hash_batch(*batch)

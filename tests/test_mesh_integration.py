@@ -8,7 +8,6 @@ shards, the psum count path, and the VerifyPlane wiring end-to-end.
 
 from __future__ import annotations
 
-import os
 
 import numpy as np
 import pytest
@@ -57,44 +56,34 @@ class TestMeshVerifier:
         assert np.array_equal(got, want)
         assert not got[list(corrupt)].any()
 
-    def test_pallas_impl_shards_over_the_mesh(self):
+    def test_pallas_impl_shards_over_the_mesh(self, monkeypatch):
         """STELLARD_VERIFY_IMPL=pallas in mesh mode: each device runs
         the whole-verify-in-VMEM kernel on its batch shard (explicit
         shard_map — a pallas_call is a custom call XLA cannot
         auto-partition). Interpreter mode on the CPU mesh."""
-        os.environ["STELLARD_VERIFY_IMPL"] = "pallas"
-        # forced, not setdefault: an earlier node test's [kernel_tuning]
-        # application may have set the 512 production default, and an
-        # 8-shard interpreter run at block 512 is minutes of dead time.
-        # (If ed25519_pallas is already imported this is a no-op — the
-        # test sizes its batch from the ACTUAL P.BLOCK below.)
-        prev_block = os.environ.get("STELLARD_PALLAS_BLOCK")
-        os.environ["STELLARD_PALLAS_BLOCK"] = "128"
-        try:
-            from stellard_tpu.ops import ed25519_pallas as P
+        monkeypatch.setenv("STELLARD_VERIFY_IMPL", "pallas")
+        # an 8-shard interpreter run at the block of 512 is minutes of
+        # dead time. (If ed25519_pallas is already imported this is a
+        # no-op — the test sizes its batch from the ACTUAL P.BLOCK.)
+        monkeypatch.setenv("STELLARD_PALLAS_BLOCK", "128")
+        from stellard_tpu.ops import ed25519_pallas as P
 
-            # at least the mesh floor, or the small-batch bypass routes
-            # the chunk to the single-chip kernel (by design)
-            n = len(jax.devices()) * P.BLOCK
-            corrupt = {0, n // 2, n - 1}
-            reqs, want = make_reqs(n, corrupt)
-            v = TpuVerifier(min_batch=64, max_batch=n)
-            got = v.verify_batch(reqs)
-            assert v.n_devices == len(jax.devices())
-            assert np.array_equal(got, want)
-            assert not got[list(corrupt)].any()
+        # at least the mesh floor, or the small-batch bypass routes
+        # the chunk to the single-chip kernel (by design)
+        n = len(jax.devices()) * P.BLOCK
+        corrupt = {0, n // 2, n - 1}
+        reqs, want = make_reqs(n, corrupt)
+        v = TpuVerifier(min_batch=64, max_batch=n)
+        got = v.verify_batch(reqs)
+        assert v.n_devices == len(jax.devices())
+        assert np.array_equal(got, want)
+        assert not got[list(corrupt)].any()
 
-            # below the floor: the bypass must still verify correctly
-            # (single-chip kernel on shard-sized padding)
-            small_reqs, small_want = make_reqs(40, {3})
-            got2 = v.verify_batch(small_reqs)
-            assert np.array_equal(got2, small_want)
-        finally:
-            del os.environ["STELLARD_VERIFY_IMPL"]
-            if prev_block is None:
-                os.environ.pop("STELLARD_PALLAS_BLOCK", None)
-            else:
-                os.environ["STELLARD_PALLAS_BLOCK"] = prev_block
+        # below the floor: the bypass must still verify correctly
+        # (single-chip kernel on shard-sized padding)
+        small_reqs, small_want = make_reqs(40, {3})
+        got2 = v.verify_batch(small_reqs)
+        assert np.array_equal(got2, small_want)
 
     def test_multi_chunk_pipeline(self):
         reqs, want = make_reqs(96, corrupt={5, 50})

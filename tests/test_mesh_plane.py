@@ -12,6 +12,12 @@ buffers — all on the virtual 8-device CPU mesh, no TPU required.
 
 from __future__ import annotations
 
+import ast
+import json
+import os
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -39,6 +45,8 @@ from stellard_tpu.ops import ed25519_ref as ref
 from stellard_tpu.protocol.keys import KeyPair
 
 EIGHT_DEVICES = len(jax.devices()) >= 8
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH_CONFIGS = os.path.join(REPO, "benchmarks", "configs")
 
 
 def make_reqs(n: int, corrupt: set = frozenset(), seed: int = 9):
@@ -106,7 +114,7 @@ class TestConfigRoundTrip:
         cfg = Config.from_ini("[signature_backend]\ntype=tpu\n")
         assert cfg.verify_mesh == "auto"
         assert cfg.hash_mesh == "auto"
-        assert cfg.verify_routing == "" and cfg.hash_routing == ""
+        assert cfg.verify_routing == "cost" and cfg.hash_routing == "cost"
 
     def test_mesh_on_host_backend_is_loud(self):
         with pytest.raises(ValueError, match="meaningless"):
@@ -129,6 +137,35 @@ class TestConfigRoundTrip:
             Config.from_ini("[signature_backend]\ntype=tpu\nuse_mesh=1\n")
         with pytest.raises(ValueError, match="unknown key"):
             Config.from_ini("[hash_backend]\ntype=cpu\nfloor=64\n")
+
+    @pytest.mark.parametrize("case", [
+        "none", "off", "empty", "path",
+        "standalone-fsync.ini", "catchup-replay.ini", "state-1m.ini",
+    ])
+    def test_retired_kernel_tuning_section(self, case, tmp_path):
+        """[kernel_tuning] is retired. The benchmark's INIs still carry
+        it with ``none``, so that (``off``, or nothing) parses and does
+        nothing; a path is refused by name — an operator who points at
+        a sweep file must not believe it applied."""
+        base = "[signature_backend]\ntype=tpu\nmesh=0\n"
+        if case.endswith(".ini"):
+            with open(os.path.join(BENCH_CONFIGS, case)) as f:
+                text = f.read()
+            assert "[kernel_tuning]\nnone\n" in text
+            text = text.replace("{workdir}", str(tmp_path)).replace(
+                "{start_up}", "fresh")
+            base = text.replace("[kernel_tuning]\nnone\n", "")
+        elif case == "path":
+            with pytest.raises(ValueError, match=r"\[kernel_tuning\].*"
+                               "no longer selectable from a file"):
+                Config.from_ini(base + "[kernel_tuning]\nKERNEL_TUNING.json\n")
+            return
+        else:
+            value = "" if case == "empty" else case
+            text = base + f"[kernel_tuning]\n{value}\n"
+        env = dict(os.environ)
+        assert Config.from_ini(text) == Config.from_ini(base)
+        assert dict(os.environ) == env
 
     def test_backend_mismatched_keys_fail_loudly(self):
         """Keys only one backend type honors must not parse clean and
@@ -509,7 +546,7 @@ class TestSyncSubmitRidesThePlane:
         from stellard_tpu.protocol.stamount import STAmount
         from stellard_tpu.protocol.sttx import SerializedTransaction
 
-        n = Node(Config(signature_backend="cpu", kernel_tuning="none")).setup()
+        n = Node(Config(signature_backend="cpu")).setup()
         try:
             master = KeyPair.from_passphrase("masterpassphrase")
             dest = KeyPair.from_passphrase("plane-sync").account_id
@@ -561,7 +598,7 @@ class FakeDevHasher(BatchHasher):
 
 
 class TestWatchdogThreeWay:
-    def _mk(self, routing=None):
+    def _mk(self, routing="cost"):
         wide, one, host = FakeDevHasher(8), FakeDevHasher(1), CpuHasher()
         w = WatchdogHasher(wide, host, inner_one=one,
                            min_device_nodes=0, routing=routing)
@@ -614,3 +651,99 @@ class TestWatchdogThreeWay:
         assert w0.inner_one is None
         host = make_watched_hasher("cpu")
         assert isinstance(host, CpuHasher)  # host passes through
+
+
+# what the program showed of itself before the variables went; printed
+# by a child so that the kernel modules are imported under them
+_SELF_PORTRAIT = """
+import hashlib, json
+import jax, jax.numpy as jnp
+from stellard_tpu.crypto.backend import CpuHasher, TpuVerifier, WatchdogHasher
+from stellard_tpu.node.verifyplane import VerifyPlane
+from stellard_tpu.ops.ed25519_jax import pt_add_cached, pt_double
+
+p = jax.ShapeDtypeStruct((4, 20, 8), jnp.int32)
+program = str(jax.make_jaxpr(lambda a, b: pt_add_cached(pt_double(a), b))(p, p))
+plane = VerifyPlane(backend="cpu")
+try:
+    print(json.dumps({
+        "describe": TpuVerifier().describe(),
+        "verify_routing": plane.routing,
+        "hash_routing": WatchdogHasher(CpuHasher(), CpuHasher()).routing,
+        "program": hashlib.sha256(program.encode()).hexdigest(),
+    }))
+finally:
+    plane.stop()
+"""
+
+REMOVED_VARIABLES = {
+    "STELLARD_HOIST_SELECT": "1",
+    "STELLARD_GROUP_OPS": "1",
+    "STELLARD_VERIFY_UNROLL": "4",
+    "STELLARD_FE_MUL": "legacy",
+    "STELLARD_VERIFY_ROUTING": "device",
+    "STELLARD_HASH_ROUTING": "device",
+    "STELLARD_HASH_MIN_DEVICE_NODES": "7",
+    "STELLARD_PATHQ_ROUTING": "host",
+    "STELLARD_PATHQ_MIN_DEVICE_BATCH": "7",
+    "STELLARD_SWEEP_ALLOW_CPU": "1",
+    "STELLARD_PROFILE_TRACE": "1",
+}
+
+
+class TestRetiredEnvironment:
+    def _portrait(self, extra: dict) -> dict:
+        env = {k: v for k, v in os.environ.items()
+               if k not in REMOVED_VARIABLES}
+        env.update(extra, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+        out = subprocess.run(
+            [sys.executable, "-c", _SELF_PORTRAIT], env=env, cwd=REPO,
+            capture_output=True, text=True, timeout=300,
+        )
+        assert out.returncode == 0, out.stderr[-2000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    def test_removed_variables_are_not_read(self):
+        """Each of the 11 removed variables at a value that used to
+        switch something: the verifier, both routers and the point
+        arithmetic the kernel traces are what they are without them."""
+        plain = self._portrait({})
+        assert plain["verify_routing"] == plain["hash_routing"] == "cost"
+        assert self._portrait(REMOVED_VARIABLES) == plain
+
+    def test_no_module_writes_the_environment(self):
+        """No module of the program writes a STELLARD_* variable (the
+        retired tuning loader did, with keys from a dict, so any write
+        whose key is not a literal of another prefix counts)."""
+        writers = ("setdefault", "update", "pop", "popitem", "clear",
+                   "putenv", "unsetenv")
+        found = []
+        for root, _dirs, files in os.walk(os.path.join(REPO, "stellard_tpu")):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                path = os.path.join(root, name)
+                with open(path) as f:
+                    tree = ast.parse(f.read(), path)
+                for node in ast.walk(tree):
+                    keys = []
+                    if isinstance(node, (ast.Assign, ast.AugAssign,
+                                         ast.Delete)):
+                        targets = (node.targets if hasattr(node, "targets")
+                                   else [node.target])
+                        keys = [t.slice for t in targets
+                                if isinstance(t, ast.Subscript)
+                                and "environ" in ast.unparse(t.value)]
+                    elif (isinstance(node, ast.Call)
+                          and isinstance(node.func, ast.Attribute)
+                          and node.func.attr in writers
+                          and ("environ" in ast.unparse(node.func.value)
+                               or node.func.attr in ("putenv", "unsetenv"))):
+                        keys = node.args[:1] or [None]
+                    for key in keys:
+                        literal = (isinstance(key, ast.Constant)
+                                   and isinstance(key.value, str))
+                        if not literal or key.value.startswith("STELLARD_"):
+                            found.append(
+                                f"{os.path.relpath(path, REPO)}:{node.lineno}")
+        assert found == []

@@ -1,6 +1,6 @@
 """Differential test of the Pallas whole-verify-in-VMEM Ed25519 kernel
 (ops/ed25519_pallas.py) against the host library, in interpreter mode on
-the CPU backend (the real-chip A/B runs via tools/kernel_sweep.py).
+the CPU backend.
 
 Covers: multi-block grids, tail padding, and cryptographically planted
 corruption (R byte, S low byte, public key byte, message swap) — the
@@ -15,10 +15,8 @@ import os
 import numpy as np
 import pytest
 
-# small grid block keeps interpreter cost CI-sized; must be FORCED (not
-# setdefault) before the module under test is imported (read once at
-# import, jit-static) — an earlier node test's [kernel_tuning]
-# application may already have set the 512 production default
+# small grid block keeps interpreter cost CI-sized; set before the
+# module under test is imported (read once at import, jit-static)
 os.environ["STELLARD_PALLAS_BLOCK"] = "128"
 
 from stellard_tpu.ops.ed25519_jax import prepare_batch  # noqa: E402

@@ -14,7 +14,7 @@ import pytest
 
 jax.config.update("jax_platforms", "cpu")
 
-from stellard_tpu.crypto.backend import make_path_evaluator  # noqa: E402
+from stellard_tpu.crypto.backend import PathQualityEvaluator  # noqa: E402
 from stellard_tpu.engine import TransactionEngine  # noqa: E402
 from stellard_tpu.node.config import Config  # noqa: E402
 from stellard_tpu.node.node import Node  # noqa: E402
@@ -426,7 +426,7 @@ class TestPathQualityEvaluator:
     def test_host_device_byte_identity(self, width):
         """THE device-plane pin: the mesh arm is byte-identical to the
         host arm at every width (virtual 8-device CPU mesh)."""
-        ev = make_path_evaluator(mesh=str(width), routing="device")
+        ev = PathQualityEvaluator(mesh=str(width), routing="device")
         for n in (1, 3, 37, 128):
             rates = self._rates(n, seed=n)
             host = ev.evaluate_host(rates)
@@ -438,7 +438,7 @@ class TestPathQualityEvaluator:
         assert width in j["arm_widths"].values()  # honest width provenance
 
     def test_identity_and_saturation_rows(self):
-        ev = make_path_evaluator(routing="host")
+        ev = PathQualityEvaluator(routing="host")
         rates = np.full((3, MAX_HOPS), Q16_ONE, dtype=np.uint32)
         rates[1, 0] = 2 * Q16_ONE
         rates[2, :] = Q16_MAX
@@ -448,7 +448,7 @@ class TestPathQualityEvaluator:
         assert out[2] == Q16_MAX  # saturated stays saturated
 
     def test_cost_routing_floors_small_batches(self):
-        ev = make_path_evaluator(mesh="2", routing="cost",
+        ev = PathQualityEvaluator(mesh="2", routing="cost",
                                  min_device_batch=64)
         ev.evaluate(self._rates(8))
         assert ev.host_batches == 1 and ev.device_batches == 0
@@ -459,7 +459,7 @@ class TestPathQualityEvaluator:
 
     def test_bad_routing_is_loud(self):
         with pytest.raises(ValueError):
-            make_path_evaluator(routing="gpu")
+            PathQualityEvaluator(routing="gpu")
 
 
 # --------------------------------------------------------------------------
@@ -470,7 +470,7 @@ class TestPathPlane:
     def test_pre_rank_noop_below_floor(self):
         net = liquid_net()
         led = close(net)
-        ev = make_path_evaluator(routing="host")
+        ev = PathQualityEvaluator(routing="host")
         plane = PathPlane(evaluator=ev, prune_floor=8, prune_keep=2)
         pre = plane.make_pre_rank(led)
         cands = [([PathElement(account=BOB.account_id)],
@@ -481,7 +481,7 @@ class TestPathPlane:
     def test_pre_rank_prunes_but_keeps_empty_paths(self):
         net = liquid_net()
         led = close(net)
-        ev = make_path_evaluator(routing="host")
+        ev = PathQualityEvaluator(routing="host")
         plane = PathPlane(evaluator=ev, prune_floor=4, prune_keep=2)
         pre = plane.make_pre_rank(led)
         cands = [([PathElement(account=BOB.account_id)],
@@ -498,7 +498,7 @@ class TestPathPlane:
 
     def test_no_evaluator_means_no_hook(self):
         assert PathPlane().make_pre_rank(None) is None
-        ev = make_path_evaluator(routing="host")
+        ev = PathQualityEvaluator(routing="host")
         assert PathPlane(evaluator=ev,
                          device_prune=False).make_pre_rank(None) is None
 

@@ -121,12 +121,10 @@ def run_smoke() -> int:
         hash_mesh="auto",
         hash_routing="device",
         hash_min_device_nodes=0,
-        kernel_tuning="none",
     ))
     vp = planes["verify"]
     cpu_closes, _planes_cpu, cpu_rejected = drive(Config(
         signature_backend="cpu",
-        kernel_tuning="none",
     ))
 
     bad = 0
