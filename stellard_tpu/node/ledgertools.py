@@ -23,7 +23,13 @@ from ..protocol.ter import TER
 from ..state.ledger import Ledger
 from .heapaging import HEAP_AGING
 from .ledgermaster import CanonicalTXSet, LedgerMaster
-from .tracer import GC_PROBE, get_tracer
+from .tracer import GC_PROBE, Tracer, get_tracer
+
+# A replayed transaction has no tree to join (no submit, no open
+# window): the ledger master that re-applies it marks no `close.tx`.
+# One in eight would be 2,048 orphans a span of 16,384 in the ring that
+# the `replay.*` spans are read from.
+_NO_TX_MARKS = Tracer(enabled=False)
 
 __all__ = [
     "dump_ledger",
@@ -163,17 +169,18 @@ def replay_ledger(
     ledger_hash: bytes,
     hash_batch: Optional[Callable] = None,
     verify_many: Optional[Callable] = None,
-    _txs: Optional[list] = None,
-    _target: Optional[Ledger] = None,
     tracer=None,
 ) -> dict:
     """Re-close a stored ledger from its parent and verify the result
     hashes identically (reference: --ledger N --replay, Main.cpp:325-332).
 
-    Loads ledger L and parent P from the NodeStore, re-applies L's tx
-    set to P in canonical order through the full engine, re-hashes both
-    trees through the (device) BatchHasher, and compares against L's
-    recorded hashes. Returns timing/throughput stats.
+    Loads ledger L and parent P from the NodeStore, both whole and
+    eagerly (every node of both trees fetched and content-checked: this
+    is the plain path, the one other replays are compared with),
+    re-applies L's tx set to P in canonical order through the full
+    engine, re-hashes both trees through the (device) BatchHasher, and
+    compares against L's recorded hashes. Returns timing/throughput
+    stats.
 
     With `verify_many` (a VerifyPlane-style batched verifier), every tx
     signature in the ledger is re-verified in ONE batch up front and the
@@ -182,38 +189,42 @@ def replay_ledger(
     catch-up trust model: replayed history is re-verified, batched.
 
     One ``replay.ledger`` span a call (``tracer`` defaults to the
-    process tracer), with the loads it makes (``ledger.load``),
+    process tracer; ``parent_from`` is always ``"store"`` here), with
+    the two loads (``ledger.load``), ``replay.parse``,
     ``replay.verify``, ``replay.apply`` and ``replay.close`` (the close
     and both tree hashes) under it."""
     tr = tracer if tracer is not None else get_tracer()
+    kw = {"hash_batch": hash_batch} if hash_batch else {}
     probed = GC_PROBE.install(tr)
     HEAP_AGING.acquire()
     try:
         with tr.span("replay.ledger", "replay") as span:
-            return _replay_ledger(db, ledger_hash, hash_batch, verify_many,
-                                  _txs, _target, tr, span)
+            target = Ledger.load(db, ledger_hash, tracer=tr, **kw)
+            parent = Ledger.load(db, target.parent_hash, tracer=tr, **kw)
+            with tr.span("replay.parse", "replay"):
+                txs = _parse_txs(target)
+            stats, _closed = _reclose(target, parent, txs, verify_many, kw,
+                                      tr, span, "store")
+            return stats
     finally:
         HEAP_AGING.release()
         if probed:
             GC_PROBE.remove(tr)
 
 
-def _replay_ledger(db, ledger_hash, hash_batch, verify_many, _txs, _target,
-                   tr, span) -> dict:
-    kw = {"hash_batch": hash_batch} if hash_batch else {}
-    target = _target if _target is not None else Ledger.load(
-        db, ledger_hash, tracer=tr, **kw
-    )
-    parent = Ledger.load(db, target.parent_hash, tracer=tr, **kw)
+def _parse_txs(target: Ledger) -> list[SerializedTransaction]:
+    return [
+        SerializedTransaction.from_bytes(blob)
+        for _txid, blob, _meta in target.tx_entries()
+    ]
 
-    if _txs is not None:
-        txs = _txs
-    else:
-        with tr.span("replay.parse", "replay"):
-            txs = [
-                SerializedTransaction.from_bytes(blob)
-                for _txid, blob, _meta in target.tx_entries()
-            ]
+
+def _reclose(target: Ledger, parent: Ledger, txs: list, verify_many, kw,
+             tr, span, parent_from: str) -> tuple[dict, Ledger]:
+    """Re-apply `txs` to `parent` and close under `target`'s header ->
+    (the stats of `replay_ledger`, the ledger it re-closed). Of `target`
+    it reads the header and the two root hashes, never a node."""
+    ledger_hash = target.hash()
     t0 = time.perf_counter()
     if verify_many is not None:
         with tr.span("replay.verify", "replay", sigs=len(txs)):
@@ -224,7 +235,7 @@ def _replay_ledger(db, ledger_hash, hash_batch, verify_many, _txs, _target,
         txset = CanonicalTXSet(parent.hash())
         for tx in txs:
             txset.insert(tx)
-        lm = LedgerMaster(tracer=tr, **kw)
+        lm = LedgerMaster(tracer=_NO_TX_MARKS, **kw)
         results = lm._apply_transactions(replay, txset)
     with tr.span("replay.close", "replay"):
         replay.close(
@@ -236,7 +247,8 @@ def _replay_ledger(db, ledger_hash, hash_batch, verify_many, _txs, _target,
         replay_hash = replay.hash()
     elapsed = time.perf_counter() - t0
     if span is not None:
-        span.attrs = {"seq": target.seq, "txs": len(txs)}
+        span.attrs = {"seq": target.seq, "txs": len(txs),
+                      "parent_from": parent_from}
 
     ok = replay_hash == ledger_hash
     return {
@@ -254,7 +266,7 @@ def _replay_ledger(db, ledger_hash, hash_batch, verify_many, _txs, _target,
         == target.state_map.get_hash(),
         "tx_hash_ok": replay.tx_map.get_hash() == target.tx_map.get_hash(),
         "results": {k.hex(): int(v) for k, v in results.items()},
-    }
+    }, replay
 
 
 def replay_range(
@@ -273,15 +285,35 @@ def replay_range(
     kernel invocation up front, then re-applies ledger by ledger with the
     verdicts memoized (the SF_SIGGOOD seam) — the bigger the catch-up
     span, the further the batch rides up the device's throughput curve.
-    Verdict semantics are identical to per-ledger replay: a bad historic
-    signature still fails its own ledger's hash check, no other's.
+
+    What it reads from the store: of every target the header and the
+    transaction tree (a lazy open: the header hash is checked against
+    the hash asked for, every transaction node is content-checked as it
+    faults, and no node of a target's state tree below its root is
+    fetched: the root hashes under the header are what ``state_hash_ok``
+    and ``tx_hash_ok`` compare with); of the state, ONE ledger whole and
+    eagerly, the first target's parent. Each later ledger's parent is
+    the ledger re-closed before it, taken from the chain when and only
+    when that ledger replayed to its stored hash (``ok``) and this
+    target's ``parent_hash`` is that hash: the re-closed ledger then IS
+    the stored parent. After a ledger that failed, and across a gap in
+    the list, the parent is loaded from the store as `replay_ledger`
+    loads it. Verdict semantics are therefore identical to per-ledger
+    replay: a bad historic signature still fails its own ledger's hash
+    check, no other's, because a ledger that failed is never anybody's
+    parent.
 
     One ``replay.span`` span a call (``tracer`` defaults to the process
-    tracer): ``ledger.load`` for every target, ``replay.parse``,
-    ``replay.verify`` (the plane's ``verify.batch`` nests under it), then
-    a ``replay.ledger`` per ledger. It ends with the ledgers and
-    transactions it covered and what the collector (``gc_pause_s``) and
-    the hot cache's victim scans (``evict_scan_s``) took of it."""
+    tracer): a lazy ``ledger.load`` for every target, ``replay.parse``
+    (the transaction trees fault here), ``replay.verify`` (the plane's
+    ``verify.batch`` nests under it), then a ``replay.ledger`` per
+    ledger (``parent_from`` ``"chain"`` or ``"store"``; an eager
+    ``ledger.load`` under it where it is the store). It ends with the
+    ledgers and transactions it covered, how many took their parent
+    from the chain (``chained``; the result carries it too), the eager
+    loads it made (``state_loads``), and what the collector
+    (``gc_pause_s``) and the hot cache's victim scans
+    (``evict_scan_s``) took of it."""
     tr = tracer if tracer is not None else get_tracer()
     probed = GC_PROBE.install(tr)
     HEAP_AGING.acquire()
@@ -294,6 +326,8 @@ def replay_range(
                 gc_s, scan_s = _runtime_marks()
                 span.attrs = {
                     "ledgers": out["ledger_count"], "txs": out["tx_count"],
+                    "chained": out["chained"],
+                    "state_loads": out["ledger_count"] - out["chained"],
                     "gc_pause_s": round(gc_s - marks[0], 6),
                     "evict_scan_s": round(scan_s - marks[1], 6),
                 }
@@ -307,15 +341,12 @@ def replay_range(
 def _replay_range(db, ledger_hashes, hash_batch, verify_many, tr) -> dict:
     kw = {"hash_batch": hash_batch} if hash_batch else {}
     t0 = time.perf_counter()
-    targets = [Ledger.load(db, h, tracer=tr, **kw) for h in ledger_hashes]
+    # opened, not loaded: the header and two roots now, the transaction
+    # tree as `replay.parse` walks it
+    targets = [Ledger.load(db, h, lazy=True, tracer=tr, **kw)
+               for h in ledger_hashes]
     with tr.span("replay.parse", "replay"):
-        per_ledger: list[list[SerializedTransaction]] = [
-            [
-                SerializedTransaction.from_bytes(blob)
-                for _txid, blob, _meta in target.tx_entries()
-            ]
-            for target in targets
-        ]
+        per_ledger = [_parse_txs(target) for target in targets]
     if verify_many is not None:
         with tr.span("replay.verify", "replay",
                      sigs=sum(len(txs) for txs in per_ledger)):
@@ -323,11 +354,26 @@ def _replay_range(db, ledger_hashes, hash_batch, verify_many, tr) -> dict:
                 [tx for txs in per_ledger for tx in txs], verify_many
             )
     stats = []
-    for h, txs, target in zip(ledger_hashes, per_ledger, targets):
-        stats.append(replay_ledger(db, h, hash_batch=hash_batch, _txs=txs,
-                                   _target=target, tracer=tr))
-        # behind the ledger's span: what it left alive (the first time,
-        # every target of the range) lives to the end of the range
+    chained = 0
+    # the ledger re-closed last, while it replayed to its stored hash:
+    # the one name that keeps a state alive between two ledgers
+    parent = None
+    for txs, target in zip(per_ledger, targets):
+        with tr.span("replay.ledger", "replay") as span:
+            if parent is not None and target.parent_hash == parent.hash():
+                parent_from = "chain"
+                chained += 1
+            else:
+                parent_from = "store"
+                parent = Ledger.load(db, target.parent_hash, tracer=tr, **kw)
+            s, parent = _reclose(target, parent, txs, None, kw, tr, span,
+                                 parent_from)
+        if not s["ok"]:
+            parent = None  # a ledger that failed is nobody's parent
+        stats.append(s)
+        # behind the ledger's span: what it left alive (its re-closed
+        # ledger, the next one's parent; the first time, every opened
+        # target of the range) is aged once
         HEAP_AGING.age()
     elapsed = time.perf_counter() - t0
     total = sum(s["tx_count"] for s in stats)
@@ -335,6 +381,7 @@ def _replay_range(db, ledger_hashes, hash_batch, verify_many, tr) -> dict:
         "ok": all(s["ok"] for s in stats),
         "ledger_count": len(stats),
         "tx_count": total,
+        "chained": chained,
         "elapsed_s": elapsed,
         "tx_per_s": total / elapsed if elapsed > 0 else 0.0,
         "ledgers": stats,

@@ -708,7 +708,7 @@ class _GcProbe:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         # id(tracer) -> [weak reference, installations]: a tracer that
-        # installed twice (replay_range and the replay_ledger inside it)
+        # installed twice (a node and a replay_ledger run in its process)
         # still gets each collection once. Weak: a node dropped without
         # stop() must not pin its ring
         self._tracers: dict[int, list] = {}

@@ -515,16 +515,28 @@ class TestReplaySpans:
         assert root["args"]["ledgers"] == n
         assert root["args"]["txs"] == n * PER_LEDGER
         assert root["args"]["gc_pause_s"] >= 0
-        # replay_range and each replay_ledger inside it install the same
-        # tracer: a collection is still ONE span in its ring
+        # a collection is ONE span in the ring
         gcs = by_name.get("gc.collect", [])
         assert len({ev["ts"] for ev in gcs}) == len(gcs)
         assert root["args"]["evict_scan_s"] >= 0
-        assert len(by_name["ledger.load"]) == 2 * n  # targets + parents
-        assert {ev["args"]["seq"] for ev in by_name["ledger.load"]} \
-            >= {l.seq for l in ledgers}
-        assert all("cache_hits" in ev["args"] and "nodes_fetched" in ev["args"]
-                   for ev in by_name["ledger.load"])
+        # every target opened lazily, ONE state loaded eagerly: the
+        # first target's parent; the chain carries the rest
+        loads = by_name["ledger.load"]
+        assert len(loads) == n + 1
+        assert [ev["args"]["seq"] for ev in loads if ev["args"]["lazy"]] \
+            == [l.seq for l in ledgers]
+        eager, = [ev for ev in loads if not ev["args"]["lazy"]]
+        assert eager["args"]["seq"] == ledgers[0].seq - 1
+        assert "nodes_fetched" in eager["args"]
+        assert all("cache_hits" in ev["args"] for ev in loads)
+        assert root["args"]["chained"] == out["chained"] == n - 1
+        assert root["args"]["state_loads"] == 1
+        assert [ev["args"]["parent_from"] for ev in sorted(
+            by_name["replay.ledger"], key=lambda ev: ev["ts"])] \
+            == ["store"] + ["chain"] * (n - 1)
+        # the eager load is the first ledger's own child
+        assert eager["args"]["parent"] == min(
+            by_name["replay.ledger"], key=lambda ev: ev["ts"])["args"]["span"]
         for name, count in (("replay.parse", 1), ("replay.verify", 1),
                             ("replay.ledger", n), ("replay.apply", n),
                             ("replay.close", n)):
@@ -624,8 +636,11 @@ class TestHotCacheScans:
 
     def test_replay_range_past_the_eager_cap(self, chain, monkeypatch):
         # the real path: every eager load of a tree with more inner
-        # nodes than the cap evicts, and still replays to its hashes
+        # nodes than the cap evicts, and still replays to its hashes.
+        # Newest first, so that no ledger's parent is the one re-closed
+        # before it and every parent is loaded from the store
         db, ledgers = chain
+        ledgers = ledgers[::-1]
         monkeypatch.setattr(hotcache, "EAGER_ENTRY_CAP", 2)
         cache = inner_node_cache()
         cache.clear()
@@ -642,6 +657,8 @@ class TestHotCacheScans:
         assert scanned == evicted and scans <= evicted
         root, = spans(tr, "replay.span")
         assert root["args"]["evict_scan_s"] > 0
+        assert (root["args"]["chained"], root["args"]["state_loads"]) \
+            == (0, len(ledgers))
         loads = spans(tr, "ledger.load")
         assert sum(ev["args"]["evict_scans"] for ev in loads) == scans
         assert sum(ev["args"]["evict_scan_s"] for ev in loads) \
