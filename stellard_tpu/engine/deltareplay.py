@@ -53,9 +53,10 @@ from ..state.entryset import Action
 from ..state.ledger import Ledger
 from ..state.shamap import SHAMapItem, TNType
 from ..state.specview import PARENT, SpecView
-from .engine import TransactionEngine, TxParams, _is_tec
+from .engine import TransactionEngine, TxParams, _is_tec, merge_tally
 
-__all__ = ["SpecState", "CloseReplay", "HEADER_TYPES", "execute_record"]
+__all__ = ["SpecState", "CloseReplay", "HEADER_TYPES", "FALLBACK_REASONS",
+           "execute_record"]
 
 log = logging.getLogger("stellard.deltareplay")
 
@@ -66,12 +67,21 @@ HEADER_TYPES = frozenset(
     {TxType.ttFEE, TxType.ttAMENDMENT, TxType.ttINFLATION}
 )
 
+# why a transaction ran the full serial apply at the close instead of
+# splicing its record (`CloseReplay.try_splice` names one on every miss;
+# `not_attempted`: the caller never asked). Per close they sum to
+# `fallback`.
+FALLBACK_REASONS = (
+    "no_record", "read_invalidated", "succ_invalidated", "disabled",
+    "parent_mismatch", "header_dirty", "not_attempted",
+)
+
 
 class SpecRecord:
     __slots__ = (
         "raw_ter", "ter", "did_apply", "reads", "succs", "write_items",
         "meta", "fee", "meta_blob", "meta_index_off", "net_deletes",
-        "origin", "index",
+        "origin", "index", "tally",
     )
 
     def __init__(self, raw_ter, ter, did_apply, reads, succs, write_items,
@@ -113,10 +123,15 @@ class SpecRecord:
         # order of the parallel executor (engine/specexec.py). None
         # until assigned by SpecState.speculate / the executor.
         self.index: Optional[int] = None
+        # what the transactor counted in this run (`offers.*`,
+        # `flow.*`): added to the node's counters when the record is
+        # spliced. A record that crossed a process boundary carries
+        # none (the worker transports ship no tally).
+        self.tally: dict = {}
 
 
 def execute_record(view, tx: SerializedTransaction,
-                   origin: str = "submit") -> SpecRecord:
+                   origin: str = "submit", tracer=None) -> SpecRecord:
     """Run the close-mode engine over ``view`` (which must be inside a
     ``begin_tx`` bracket) and build the SpecRecord: compacted write set
     serialized NOW (the splice and the pre-seal building tree share
@@ -129,7 +144,7 @@ def execute_record(view, tx: SerializedTransaction,
     Exceptions propagate — the caller decides whether a failure poisons
     the whole overlay (serial) or just retries the task (parallel)."""
     txid = tx.txid()
-    engine = TransactionEngine(view)
+    engine = TransactionEngine(view, tracer=tracer)
     ter, did_apply = engine.apply_transaction(tx, TxParams.NONE)
     reads, succs, writes = view.end_tx()
     meta = view.parsed_metas.pop(txid, None)
@@ -184,6 +199,7 @@ def execute_record(view, tx: SerializedTransaction,
                 rec.meta_index_off = diffs[0] - 3
     rec.net_deletes = frozenset(net_deletes)
     rec.origin = origin
+    rec.tally = engine.tally
     return rec
 
 
@@ -191,8 +207,9 @@ class SpecState:
     """Per-open-ledger speculation: the shared overlay view plus one
     record per open-accepted txid. Consumed by at most one close."""
 
-    def __init__(self, ledger: Ledger):
+    def __init__(self, ledger: Ledger, tracer=None):
         self.parent_hash = ledger.parent_hash
+        self.tracer = tracer  # the inline speculation's sampled spans
         self.view = SpecView(ledger)
         self.records: dict[bytes, SpecRecord] = {}
         self.disabled = False  # poisoned overlay -> all-fallback close
@@ -286,7 +303,7 @@ class SpecState:
         txid = tx.txid()
         self.view.begin_tx(txid)
         try:
-            rec = execute_record(self.view, tx, origin)
+            rec = execute_record(self.view, tx, origin, self.tracer)
             if rec.did_apply and rec.meta is None:
                 return rec  # commit tail didn't complete; keep no record
             rec.index = self.alloc_index() if index is None else index
@@ -330,6 +347,10 @@ class CloseReplay:
         # several passes — the last attempt's outcome wins, so
         # spliced+fallback always sums to the distinct tx count)
         self._class: dict[bytes, str] = {}
+        # the reason of each transaction's last fallback
+        self._why: dict[bytes, str] = {}
+        # the tallies (`offers.*`, `flow.*`) of the records spliced
+        self.tally: dict[str, int] = {}
         self.invalidated = 0  # validation failures, counted PER ATTEMPT
         # (a retried record re-validates each pass; the churn is the
         # diagnostic, so attempts are the honest unit here)
@@ -351,8 +372,11 @@ class CloseReplay:
         """-> (ter, did_apply) when the recorded outcome stands in for
         this pass, else None (caller runs the full serial apply)."""
         if not self.parent_ok or self.header_dirty:
+            spec = self.spec
             self._fallback_reason = (
-                "header_dirty" if self.header_dirty else "parent_mismatch"
+                "header_dirty" if self.header_dirty
+                else "disabled" if spec is not None and spec.disabled
+                else "parent_mismatch"
             )
             return None
         txid = tx.txid()
@@ -383,13 +407,13 @@ class CloseReplay:
             # path reports the RAW tec (the claim only runs under NONE)
             self._class[txid] = "spliced"
             ter = rec.raw_ter if not final and _is_tec(rec.raw_ter) else rec.ter
-            self._mark(txid, "spliced", int(ter))
+            self._mark(tx, "spliced", int(ter))
             return ter, False
         if not final and _is_tec(rec.raw_ter):
             # defer the recorded fee claim to final-pass semantics, like
             # the serial path; the caller's tec branch requeues it
             self._class[txid] = "spliced"
-            self._mark(txid, "spliced", int(rec.raw_ter))
+            self._mark(tx, "spliced", int(rec.raw_ter))
             return rec.raw_ter, False
 
         ledger = self.ledger
@@ -427,8 +451,9 @@ class CloseReplay:
             else:
                 pending[k] = item  # speculation-time item: no re-serialize
             writers[k] = txid
+        merge_tally(self.tally, rec.tally)
         self._class[txid] = "spliced"
-        self._mark(txid, "spliced", int(rec.ter), origin=rec.origin)
+        self._mark(tx, "spliced", int(rec.ter), origin=rec.origin)
         return rec.ter, True
 
     # -- batched tree merge ------------------------------------------------
@@ -527,16 +552,18 @@ class CloseReplay:
                           "falling back to the full seal")
             self.seal_adopt = "error"
 
-    def _mark(self, txid: bytes, mode: str, ter: Optional[int] = None,
-              reason: Optional[str] = None,
+    def _mark(self, tx: SerializedTransaction, mode: str,
+              ter: Optional[int] = None, reason: Optional[str] = None,
               origin: Optional[str] = None) -> None:
         """Per-tx splice/fallback trace mark (sampled): the close-stage
         node of the transaction's causal span tree, with the fallback
         reason when the record could not be spliced."""
         tr = self.tracer
+        txid = tx.txid()
         if not tr.enabled or not tr.sampled(txid):
             return
-        attrs = {"mode": mode, "ledger_seq": self.ledger.seq}
+        attrs = {"mode": mode, "ledger_seq": self.ledger.seq,
+                 "type": tx.tx_type.name}
         if ter is not None:
             attrs["ter"] = ter
         if reason is not None:
@@ -551,7 +578,8 @@ class CloseReplay:
         that read them can never splice against diverged values."""
         txid = tx.txid()
         self._class[txid] = "fallback"
-        self._mark(txid, "fallback", reason=self._fallback_reason)
+        self._why[txid] = self._fallback_reason
+        self._mark(tx, "fallback", reason=self._fallback_reason)
         self._fallback_reason = "not_attempted"
         if not did_apply:
             return
@@ -573,9 +601,14 @@ class CloseReplay:
 
     def counts(self) -> dict:
         cls = self._class.values()
+        by_reason = dict.fromkeys(FALLBACK_REASONS, 0)
+        for txid, c in self._class.items():
+            if c == "fallback":
+                by_reason[self._why[txid]] += 1
         return {
             "spliced": sum(1 for c in cls if c == "spliced"),
             "fallback": sum(1 for c in cls if c == "fallback"),
+            "fallback_by_reason": by_reason,
             "invalidated": self.invalidated,
             "parent_ok": self.parent_ok,
             "bulk_merges": self.bulk_merges,

@@ -18,7 +18,7 @@ from ..protocol.ter import TER
 from ..state import LedgerEntrySet, indexes
 from ..state.ledger import Ledger
 
-__all__ = ["TransactionEngine", "TxParams"]
+__all__ = ["TransactionEngine", "TxParams", "merge_tally"]
 
 
 class TxParams(IntFlag):
@@ -37,13 +37,29 @@ _OPEN_LEDGER_I = int(TxParams.OPEN_LEDGER)
 _RETRY_I = int(TxParams.RETRY)
 
 
+def merge_tally(into: dict, tally: dict) -> None:
+    """Add one transaction's counts (``TransactionEngine.tally``) into a
+    running total."""
+    for name, n in tally.items():
+        into[name] = into.get(name, 0) + n
+
+
 def _is_tec(ter: TER) -> bool:
     return 100 <= int(ter) < 300
 
 
 class TransactionEngine:
-    def __init__(self, ledger: Ledger):
+    def __init__(self, ledger: Ledger, tracer=None):
         self.ledger = ledger
+        # the node's tracer, where a node built this engine: transactors
+        # hang their sampled spans (`offer.cross`, `flow.payment`) under
+        # the span the caller holds open on this thread
+        self.tracer = tracer
+        # what the last applied transaction's transactor counted
+        # (`offers.*`, `flow.*`): the caller adds it to its counters
+        # when, and only when, that application is the one that lands
+        # in a closing ledger (a speculated run keeps it on its record)
+        self.tally: dict[str, int] = {}
         self.les: LedgerEntrySet | None = None
         self.tx_seq = 0  # metadata TransactionIndex within the closing ledger
         # raw transactor outcome of the last apply, BEFORE the tec
@@ -63,6 +79,7 @@ class TransactionEngine:
         # IntFlag stays on the C fast path
         params = int(params)
         self.les = LedgerEntrySet(self.ledger)
+        self.tally = {}
 
         # pseudo-transactions (zero account, no fee/signature) only enter
         # through a consensus set; their own pre_check enforces the
@@ -95,6 +112,7 @@ class TransactionEngine:
         elif _is_tec(ter) and not (params & _RETRY_I):
             # claim only the fee (reference: TransactionEngine.cpp:146-185)
             self.les = LedgerEntrySet(self.ledger)
+            self.tally = {}  # what the transactor did is discarded
             idx = indexes.account_root_index(tx.account)
             acct = self.les.peek(idx)
             if acct is None:
@@ -150,6 +168,11 @@ class TransactionEngine:
                 self.les.apply()
 
         return ter, did_apply
+
+    def count(self, name: str, n: int = 1) -> None:
+        """A transactor's counter for the transaction being applied."""
+        if n:
+            self.tally[name] = self.tally.get(name, 0) + n
 
     def _check_invariants(self, tx: SerializedTransaction, params: TxParams,
                           minted: int = 0) -> bool:

@@ -91,6 +91,21 @@ class Amounts:
     o: STAmount
 
 
+class CrossStats:
+    """What one transaction's book walks did, counted as they happen:
+    ``steps`` (``succ`` steps over the books' quality directories),
+    ``consumed`` (resting offers taken, whole or in part), ``removed``
+    (unfunded, expired and self-crossing offers deleted as met) and
+    ``bridged`` (crossing steps that went through the two STR books).
+    The transactor folds them into its engine's tally and onto the
+    sampled ``offer.cross`` / ``flow.payment`` span."""
+
+    __slots__ = ("steps", "consumed", "removed", "bridged")
+
+    def __init__(self):
+        self.steps = self.consumed = self.removed = self.bridged = 0
+
+
 def _scale_to_out(a: Amounts, limit_out: STAmount) -> Amounts:
     """Clamp .o to limit_out keeping the ratio
     (reference: Quality::ceil_out)."""
@@ -129,6 +144,7 @@ def cross_offers(
     parent_close_time: int,
     max_quality_levels: Optional[int] = None,
     threshold_rate: Optional[int] = None,
+    stats: Optional[CrossStats] = None,
 ) -> tuple[TER, STAmount, STAmount]:
     """Cross the book base(in_currency, out_currency) as a taker; returns
     (TER, paid_in_total, got_out_total).
@@ -163,6 +179,8 @@ def cross_offers(
 
     in_left = taker_pays_in
     out_left = taker_wants_out
+    if stats is None:
+        stats = CrossStats()
 
     cursor = book_base
     levels_used = 0
@@ -177,6 +195,7 @@ def cross_offers(
             break
 
         item = les.ledger.state_map.succ(cursor)
+        stats.steps += 1
         if item is None or item.tag >= book_end:
             break
         dir_idx = item.tag
@@ -201,18 +220,21 @@ def cross_offers(
             if owner == taker_id:
                 # self-crossing offers are removed (reference :116-128)
                 views.offer_delete(les, offer_idx)
+                stats.removed += 1
                 continue
             if (
                 sfExpiration in offer
                 and parent_close_time >= offer[sfExpiration]
             ):
                 views.offer_delete(les, offer_idx)
+                stats.removed += 1
                 continue
 
             rest = Amounts(offer[sfTakerPays], offer[sfTakerGets])
             owner_funds = views.account_funds(les, owner, rest.o)
             if owner_funds.signum() <= 0:
                 views.offer_delete(les, offer_idx)  # unfunded
+                stats.removed += 1
                 continue
 
             # limit by owner funds net of transfer fee (Taker::fill)
@@ -271,6 +293,7 @@ def cross_offers(
             in_left = in_left - flow.i
             if not sell:
                 out_left = out_left - flow.o
+            stats.consumed += 1
 
             if consumed:
                 views.offer_delete(les, offer_idx)
@@ -313,7 +336,7 @@ def _exact_price(pay: STAmount, get: STAmount) -> Fraction:
 
 def _tip_info(
     les, taker_id: bytes, want_in: STAmount, want_out: STAmount,
-    parent_close_time: int,
+    parent_close_time: int, stats: CrossStats,
 ):
     """Peek the best live, funded, non-self tip of a book WITHOUT mutating:
     -> (price Fraction in-per-out, in_capacity, out_capacity) or None.
@@ -326,6 +349,7 @@ def _tip_info(
     cursor = base
     while True:
         item = les.ledger.state_map.succ(cursor)
+        stats.steps += 1
         if item is None or item.tag >= end:
             return None
         dir_idx = item.tag
@@ -362,6 +386,7 @@ def cross_offers_auto_bridged(
     passive: bool,
     parent_close_time: int,
     max_steps: int = 64,
+    stats: Optional[CrossStats] = None,
 ) -> tuple[TER, STAmount, STAmount]:
     """Best-execution crossing for an IOU/IOU taker over three books:
     direct IN->OUT, plus the IN->STR / STR->OUT bridge."""
@@ -376,6 +401,8 @@ def cross_offers_auto_bridged(
     got = STAmount.zero_like(taker_wants_out.currency, taker_wants_out.issuer)
     in_left = taker_pays_in
     out_left = taker_wants_out
+    if stats is None:
+        stats = CrossStats()
 
     for _ in range(max_steps):
         if sell:
@@ -386,9 +413,12 @@ def cross_offers_auto_bridged(
         if views.account_funds(les, taker_id, in_left).signum() <= 0:
             break
 
-        tip_d = _tip_info(les, taker_id, in_left, out_left, parent_close_time)
-        tip_1 = _tip_info(les, taker_id, in_left, xrp_zero, parent_close_time)
-        tip_2 = _tip_info(les, taker_id, xrp_zero, out_left, parent_close_time)
+        tip_d = _tip_info(les, taker_id, in_left, out_left,
+                          parent_close_time, stats)
+        tip_1 = _tip_info(les, taker_id, in_left, xrp_zero,
+                          parent_close_time, stats)
+        tip_2 = _tip_info(les, taker_id, xrp_zero, out_left,
+                          parent_close_time, stats)
         price_d = tip_d[0] if tip_d else None
         price_b = tip_1[0] * tip_2[0] if (tip_1 and tip_2) else None
 
@@ -408,7 +438,7 @@ def cross_offers_auto_bridged(
             ter, p, g = cross_offers(
                 les, taker_id, in_left, out_left, sell, passive,
                 parent_close_time, max_quality_levels=1,
-                threshold_rate=threshold_enc,
+                threshold_rate=threshold_enc, stats=stats,
             )
             if ter != TER.tesSUCCESS:
                 return ter, paid, got
@@ -448,7 +478,7 @@ def cross_offers_auto_bridged(
         ter, p_a, g_x = cross_offers(
             les, taker_id, in_left, x_step, False, passive,
             parent_close_time, max_quality_levels=1,
-            threshold_rate=PERMISSIVE_RATE,
+            threshold_rate=PERMISSIVE_RATE, stats=stats,
         )
         if ter != TER.tesSUCCESS:
             return ter, paid, got
@@ -462,12 +492,13 @@ def cross_offers_auto_bridged(
                 taker_wants_out.currency, taker_wants_out.issuer
             ),
             True, passive, parent_close_time, max_quality_levels=1,
-            threshold_rate=PERMISSIVE_RATE,
+            threshold_rate=PERMISSIVE_RATE, stats=stats,
         )
         if ter != TER.tesSUCCESS:
             return ter, paid, got
         if g_b.signum() <= 0:
             continue  # stale leg2 level cleaned; leg1's STR stays banked
+        stats.bridged += 1
         paid = paid + p_a
         got = got + g_b
         in_left = in_left - p_a
@@ -520,6 +551,7 @@ class OfferCreateTransactor(Transactor):
             cancel_idx = indexes.offer_index(self.account_id, cancel_seq)
             if self.les.peek(cancel_idx) is not None:
                 views.offer_delete(self.les, cancel_idx)
+                self.engine.count("offers.replaced")
 
         # expired: done, nothing placed (reference: :404-411)
         if has_expiration and (
@@ -553,6 +585,10 @@ class OfferCreateTransactor(Transactor):
             if not taker_pays.is_native and not taker_gets.is_native
             else cross_offers
         )
+        stats = CrossStats()
+        tracer = self.engine.tracer
+        token = tracer.begin("offer.cross", "apply", txid=tx.txid()) \
+            if tracer is not None else None
         ter, paid, got = crosser(
             self.les,
             self.account_id,
@@ -561,7 +597,16 @@ class OfferCreateTransactor(Transactor):
             sell=sell,
             passive=passive,
             parent_close_time=self.engine.ledger.parent_close_time,
+            stats=stats,
         )
+        if token is not None:
+            tracer.end(token, steps=stats.steps, consumed=stats.consumed,
+                       bridged=stats.bridged)
+        count = self.engine.count
+        count("offers.book_steps", stats.steps)
+        count("offers.crossed", stats.consumed)
+        count("offers.removed_unfunded", stats.removed)
+        count("offers.bridged", stats.bridged)
         if ter != TER.tesSUCCESS:
             return ter
         taker_pays = taker_pays - got
@@ -635,6 +680,7 @@ class OfferCreateTransactor(Transactor):
         offer[sfOwnerNode] = owner_node
         offer[sfBookDirectory] = book_root
         offer[sfBookNode] = book_node
+        count("offers.created")
         return TER.tesSUCCESS
 
 
@@ -648,5 +694,6 @@ class OfferCancelTransactor(Transactor):
             return TER.temBAD_SEQUENCE
         offer_idx = indexes.offer_index(self.account_id, offer_seq)
         if self.les.peek(offer_idx) is not None:
+            self.engine.count("offers.cancelled")
             return views.offer_delete(self.les, offer_idx)
         return TER.tesSUCCESS  # not found: not an error

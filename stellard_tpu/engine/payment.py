@@ -207,6 +207,7 @@ class PaymentTransactor(Transactor):
         """Path-engine delivery (reference: Payment.cpp:185-248 calling
         RippleCalc::rippleCalc with the tx's paths/flags)."""
         from ..paths.flow import flow
+        from .offers import CrossStats
 
         tx_paths = (
             self.tx.obj[sfPaths].paths if sfPaths in self.tx.obj else []
@@ -227,6 +228,10 @@ class PaymentTransactor(Transactor):
             from ..paths.flow import _ratio
 
             limit_quality = _ratio(dst_amount, max_amount)
+        stats = CrossStats()
+        tracer = self.engine.tracer
+        token = tracer.begin("flow.payment", "apply", txid=self.tx.txid()) \
+            if tracer is not None else None
         ter, _spent, _delivered = flow(
             self.les,
             self.account_id,
@@ -237,5 +242,13 @@ class PaymentTransactor(Transactor):
             partial,
             self.engine.ledger.parent_close_time,
             limit_quality=limit_quality,
+            stats=stats,
         )
+        if token is not None:
+            tracer.end(token, strands=len(paths), book_steps=stats.steps)
+        count = self.engine.count
+        count("flow.payments")
+        count("flow.book_steps", stats.steps)
+        count("offers.crossed", stats.consumed)
+        count("offers.removed_unfunded", stats.removed)
         return ter

@@ -16,7 +16,8 @@ import threading
 import time
 from typing import Callable, Optional
 
-from ..engine.engine import TransactionEngine, TxParams
+from ..engine.deltareplay import FALLBACK_REASONS
+from ..engine.engine import TransactionEngine, TxParams, merge_tally
 from ..node.hashrouter import SF_SIGGOOD
 from ..protocol.sttx import SerializedTransaction
 from ..protocol.ter import TER
@@ -134,6 +135,17 @@ class LedgerMaster:
             # ledgers whose building tree a failed fold disarmed (the
             # exception fold_building swallows, as a number)
             "incremental_seals", "building_fold_failures",
+            # `fallback` by the reason `try_splice` named
+            *(f"fallback.{r}" for r in FALLBACK_REASONS),
+        )
+        # what the transactors counted in the transactions of closed
+        # ledgers (`offers.*`, `flow.*`: engine/offers.py,
+        # engine/payment.py), each transaction once: a spliced record's
+        # count is its speculation's, a fallback's its serial apply's
+        self.engine_stats = AtomicCounters(
+            "offers.created", "offers.crossed", "offers.removed_unfunded",
+            "offers.cancelled", "offers.replaced", "offers.book_steps",
+            "offers.bridged", "flow.payments", "flow.book_steps",
         )
         self.last_close: dict = {}
         # the hot-node cache's (faults, fault_s, evictions) when the
@@ -292,7 +304,8 @@ class LedgerMaster:
         open_ledger = self.current_ledger()
         engine = TransactionEngine(open_ledger)
         with self.tracer.span("open.apply", "apply", txid=tx.txid(),
-                              ledger_seq=open_ledger.seq):
+                              ledger_seq=open_ledger.seq,
+                              type=tx.tx_type.name):
             ter, applied = engine.apply_transaction(tx, params)
         if applied:
             # seed the OPEN ledger's parsed-tx memo so the close path
@@ -325,7 +338,8 @@ class LedgerMaster:
         if spec is None:
             from ..engine.deltareplay import SpecState
 
-            spec = open_ledger._spec_state = SpecState(open_ledger)
+            spec = open_ledger._spec_state = SpecState(
+                open_ledger, tracer=self.tracer)
             if self.incremental_seal:
                 # the open window never mutates the state map, so
                 # its root IS the parent state the close starts
@@ -356,7 +370,8 @@ class LedgerMaster:
                 ex.end_window(session, timeout=ex.drain_timeout_s)
                 spec._exec_session = None
         with self.tracer.span("open.speculate", "apply",
-                              txid=tx.txid(), origin=origin):
+                              txid=tx.txid(), origin=origin,
+                              type=tx.tx_type.name):
             spec.speculate(tx, origin=origin)
         rec = spec.records.get(tx.txid())
         if rec is not None and spec.building is not None:
@@ -494,6 +509,15 @@ class LedgerMaster:
         t = self._drainer
         if t is not None:
             t.join(timeout=5)
+
+    def engine_json(self) -> dict:
+        """The transactors' counters, by block: ``{"offers": {...},
+        "flow": {...}}`` for get_counts."""
+        out: dict = {}
+        for name, n in self.engine_stats.snapshot().items():
+            block, _dot, key = name.partition(".")
+            out.setdefault(block, {})[key] = n
+        return out
 
     def tree_json(self) -> dict:
         """Batched-commit-plane counters for get_counts/server_state."""
@@ -944,8 +968,12 @@ class LedgerMaster:
         transactor run); everything else runs the full serial apply and
         poisons its written keys (engine/deltareplay.py)."""
         results: dict[bytes, TER] = {}
-        engine = TransactionEngine(ledger)
         tracer = self.tracer
+        engine = TransactionEngine(ledger, tracer=tracer)
+        # what the transactors counted (`offers.*`, `flow.*`), of the
+        # applications that land in this ledger: the serial ones here,
+        # the spliced records' in the replay
+        tally: dict[str, int] = {}
         replay = None
         if spec is not None and self.delta_replay:
             from ..engine.deltareplay import CloseReplay
@@ -964,6 +992,8 @@ class LedgerMaster:
             ter, did_apply = engine.apply_transaction(
                 tx, TxParams.NONE if final else TxParams.RETRY
             )
+            if did_apply:
+                merge_tally(tally, engine.tally)
             if replay is not None:
                 replay.note_fallback(tx, engine, did_apply)
             elif tracer.enabled and tracer.sampled(tx.txid()):
@@ -971,7 +1001,7 @@ class LedgerMaster:
                 # per-tx close mark still lands in the causal tree
                 tracer.instant("close.tx", "close", txid=tx.txid(),
                                mode="serial", ledger_seq=ledger.seq,
-                               ter=int(ter))
+                               ter=int(ter), type=tx.tx_type.name)
             return ter, did_apply
 
         remaining = txset.items_sorted()
@@ -1005,6 +1035,9 @@ class LedgerMaster:
                 # residual (full seal stays the automatic fallback)
                 replay.maybe_adopt_prehashed()
             self._note_delta_stats(replay)
+            merge_tally(tally, replay.tally)
+        if tally:
+            self.engine_stats.add_many(**tally)
         return results
 
     # -- delta-replay / close-stage observability -------------------------
@@ -1022,6 +1055,8 @@ class LedgerMaster:
             invalidated=c["invalidated"],
             incremental_seals=int(c.get("seal_adopt") == "adopted"),
             building_fold_failures=c.get("fold_failures", 0),
+            **{f"fallback.{r}": n
+               for r, n in c["fallback_by_reason"].items()},
         )
         with self._drain_cv:
             self.tree_stats["bulk_merges"] += c.get("bulk_merges", 0)
@@ -1069,9 +1104,13 @@ class LedgerMaster:
         chain lock: RPC worker threads call this while the close thread
         records stages / merges last_close."""
         with self._lock:
+            stats = self.delta_stats.snapshot()
             out = {
                 "enabled": self.delta_replay,
-                **self.delta_stats.snapshot(),
+                **{k: v for k, v in stats.items()
+                   if not k.startswith("fallback.")},
+                "fallback_by_reason": {
+                    r: stats[f"fallback.{r}"] for r in FALLBACK_REASONS},
                 "last_close": dict(self.last_close),
             }
             # close-stage percentiles from the tracer's `close.*` stage

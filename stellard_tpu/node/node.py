@@ -937,6 +937,7 @@ class Node:
                 prune_keep=cfg.paths_prune_keep,
                 max_updates_per_close=cfg.paths_max_updates_per_close,
                 resources=self.rpc_resources,
+                tracer=self.tracer,
             )
             self.ops.on_ledger_closed.append(
                 lambda led, results: self.path_plane.note_close(led)
@@ -1085,7 +1086,7 @@ class Node:
                     # the first close
                     keys = self.clf.offer_keys(led)
                     if keys is not None:
-                        self.path_plane.index.seed(led, keys)
+                        self.path_plane.seed_index(led, keys)
         # from here to stop() this node decides when the old generation
         # is walked (node/heapaging.py); what set-up built stays
         if not self._heap_owned:
@@ -1288,6 +1289,12 @@ class Node:
                              "validation_aborts", "serial_fallbacks")
                 },
             )
+        # the transactors' counters (`offers.created`, `flow.payments`,
+        # ... on /metrics)
+        for block in self.ledger_master.engine_json():
+            self.collector.hook(
+                block,
+                lambda b=block: self.ledger_master.engine_json()[b])
         self.collector.hook(
             "delta_replay",
             # snapshot via delta_replay_json: it takes the chain lock, so

@@ -34,6 +34,7 @@ from ..engine.flags import lsfHighNoRipple, lsfLowNoRipple
 from ..engine.offers import (
     Amounts,
     CURRENCY_ONE as _CUR_ONE,
+    CrossStats,
     PERMISSIVE_RATE,
     _scale_to_out,
     cross_offers,
@@ -254,6 +255,7 @@ def book_quote(
     in_issuer: bytes,
     out_need: STAmount,
     in_cap: Optional[STAmount] = None,
+    stats: Optional[CrossStats] = None,
 ) -> tuple[STAmount, STAmount]:
     """Read-only estimate: walking the book best-quality-first, what
     input buys `out_need` (owner-funds-limited)? -> (in_needed,
@@ -273,6 +275,8 @@ def book_quote(
     cursor = book_base
     while out_total < out_need:
         item = les.ledger.state_map.succ(cursor)
+        if stats is not None:
+            stats.steps += 1
         if item is None or item.tag >= book_end:
             break
         cursor = item.tag
@@ -336,6 +340,7 @@ def execute_strand(
     out_target: STAmount,
     in_budget: STAmount,
     parent_close_time: int,
+    stats: Optional[CrossStats] = None,
 ) -> tuple[STAmount, STAmount]:
     """Run the strand forward on `les` (callers pass a duplicate); returns
     (spent_at_src, delivered_at_dst). Raises PathError on a dry/broken
@@ -416,7 +421,7 @@ def execute_strand(
             targets[i] = need
             # book input requirement discovered by quote
             in_needed, out_avail = book_quote(
-                les, hop.in_currency, hop.in_issuer, need
+                les, hop.in_currency, hop.in_issuer, need, stats=stats
             )
             if out_avail.signum() <= 0:
                 raise PathError(TER.tecPATH_DRY, "empty book")
@@ -532,7 +537,8 @@ def execute_strand(
                 if cap_left.signum() <= 0:
                     break
                 _, est_out = book_quote(
-                    les, hop.in_currency, hop.in_issuer, still, cap_left
+                    les, hop.in_currency, hop.in_issuer, still, cap_left,
+                    stats=stats,
                 )
                 if est_out.signum() <= 0:
                     if total_got is None:
@@ -561,6 +567,7 @@ def execute_strand(
                     # wrongly rejects the marginal offer of a multi-
                     # level fill; est_in/est_out still cap both sides.
                     threshold_rate=PERMISSIVE_RATE,
+                    stats=stats,
                 )
                 if ter != TER.tesSUCCESS:
                     if total_got is None:
@@ -612,11 +619,15 @@ def flow(
     parent_close_time: int,
     max_iterations: int = 30,
     limit_quality: Optional[Fraction] = None,
+    stats: Optional[CrossStats] = None,
 ) -> tuple[TER, STAmount, STAmount]:
     """Deliver `dst_amount` to dst using the given strands, best quality
     first, spending at most `send_max` (reference: rippleCalc multi-path
     loop). Returns (ter, actually_spent, actually_delivered); mutations
-    land in `les` only for the committed strands."""
+    land in `les` only for the committed strands. ``stats`` counts the
+    book steps of every strand tried, the discarded ones included (they
+    are the work a path payment costs), and the offers taken or removed
+    by the strands that stand."""
     src_currency = send_max.currency
     src_issuer = (
         ACCOUNT_ZERO if send_max.is_native else send_max.issuer
@@ -642,15 +653,20 @@ def flow(
     for _ in range(max_iterations):
         if remaining.signum() <= 0 or budget.signum() <= 0:
             break
-        best = None  # (ratio, sandbox, spent, delivered)
+        best = None  # (ratio, sandbox, spent, delivered, its walks)
         for hops in strands:
             sandbox = les.duplicate()
+            walked = CrossStats()
             try:
                 spent, delivered = execute_strand(
-                    sandbox, src, hops, remaining, budget, parent_close_time
+                    sandbox, src, hops, remaining, budget,
+                    parent_close_time, walked,
                 )
             except PathError:
                 continue
+            finally:
+                if stats is not None:
+                    stats.steps += walked.steps
             if delivered.signum() <= 0 or spent.signum() <= 0:
                 continue
             if spent > budget:
@@ -659,11 +675,14 @@ def flow(
             if limit_quality is not None and r < limit_quality:
                 continue  # tfLimitQuality: refuse worse-than-stated rates
             if best is None or r > best[0]:
-                best = (r, sandbox, spent, delivered)
+                best = (r, sandbox, spent, delivered, walked)
         if best is None:
             break
-        _r, sandbox, spent, delivered = best
+        _r, sandbox, spent, delivered, walked = best
         les.swap_with(sandbox)
+        if stats is not None:  # offers taken by the strand that stands
+            stats.consumed += walked.consumed
+            stats.removed += walked.removed
         total_spent = total_spent + spent
         total_delivered = total_delivered + delivered
         remaining = remaining - delivered

@@ -157,11 +157,25 @@ class LiveBookIndex:
     def seq(self) -> int | None:
         return self._seq
 
+    @property
+    def offers(self) -> int:
+        """The offers the index counts, over all its books."""
+        with self._advance_lock:
+            return sum(self._counts.values())
+
+    def book_counts(self) -> dict[Book, int]:
+        """-> {book: its offers} as the index stands (a copy): equal,
+        for the ledger it was advanced to, to a count of the ltOFFER
+        entries of that ledger's state by book."""
+        with self._advance_lock:
+            return dict(self._counts)
+
     def counters(self) -> dict:
         return {
             "incremental": bool(self.incremental),
             "seq": self._seq,
             "books": len(self._counts),
+            "offers": self.offers,
             "full_rebuilds": self.full_rebuilds,
             "incremental_advances": self.incremental_advances,
             "carries": self.carries,

@@ -22,6 +22,7 @@ Everything is observable under `paths.*` (doc/observability.md) via
 from __future__ import annotations
 
 import threading
+import time
 from typing import Optional
 
 from .orderbook import LiveBookIndex, OrderBookDB
@@ -48,8 +49,10 @@ class PathPlane:
         max_updates_per_close: int = DEFAULT_UPDATE_BUDGET,
         resources=None,
         update_charge=None,
+        tracer=None,
     ):
         self.index = LiveBookIndex(incremental=incremental)
+        self.tracer = tracer  # `paths.index.advance` / `.seed` spans
         self.evaluator = evaluator
         self.device_prune = bool(device_prune)
         self.prune_floor = max(1, int(prune_floor))
@@ -80,8 +83,31 @@ class PathPlane:
 
     def note_close(self, ledger) -> None:
         """Per-validated-close hook (ops.on_ledger_closed): advance the
-        incremental index so continuity never breaks between closes."""
-        self.index.advance(ledger)
+        incremental index so continuity never breaks between closes.
+        One `paths.index.advance` span a close: the books whose count
+        the close's metadata moved, the net change of the offers the
+        index counts, and whether it had to scan the whole state."""
+        index = self.index
+        t0 = time.perf_counter()
+        was = (index.book_rereads, index.offers, index.full_rebuilds)
+        index.advance(ledger)
+        if self.tracer is not None:
+            self.tracer.complete(
+                "paths.index.advance", "paths", t0, time.perf_counter(),
+                seq=ledger.seq, books_reread=index.book_rereads - was[0],
+                offers_delta=index.offers - was[1],
+                full_rebuild=index.full_rebuilds - was[2])
+
+    def seed_index(self, ledger, offer_keys) -> bool:
+        """Start the index from the keys of `ledger`'s offers (a resumed
+        node: `LiveBookIndex.seed`), as one `paths.index.seed` span."""
+        t0 = time.perf_counter()
+        ok = self.index.seed(ledger, offer_keys)
+        if self.tracer is not None:
+            self.tracer.complete(
+                "paths.index.seed", "paths", t0, time.perf_counter(),
+                seq=ledger.seq, offers=self.index.offers, ok=ok)
+        return ok
 
     def books_for(self, ledger) -> OrderBookDB:
         return self.index.advance(ledger)

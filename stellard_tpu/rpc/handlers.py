@@ -607,6 +607,9 @@ def do_get_counts(ctx: Context) -> dict:
         **node.ledger_master.held_stats,
     }
     out["delta_replay"] = node.ledger_master.delta_replay_json()
+    # what the offer and path-payment transactors did in the
+    # transactions of closed ledgers (`offers.*`, `flow.*`)
+    out.update(node.ledger_master.engine_json())
     # batched state-tree commit plane: bulk merges, background pre-hash
     # drains, seal adoptions (node/ledgermaster.py tree_json)
     out["tree"] = node.ledger_master.tree_json()

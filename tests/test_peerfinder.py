@@ -299,15 +299,19 @@ class TestAbuse:
         # endpoint is now above the drop threshold: reconnects are refused
         # at accept time (admission gate)
         assert not victim.resources.should_admit(("127.0.0.1", 55555))
-        # the legit mesh survived the flood
-        assert victim.peer_count() == 3
+        # the legit mesh survived the flood. The count was asserted on
+        # the instant and read 2 under a loaded run (1 run in 8 with six
+        # workers busy): a crossing-dial resolution can replace one
+        # session seconds late there (TestDialChurn settles for the same
+        # reason), so it gets the wait this file's other cases have
+        assert wait_until(lambda: victim.peer_count() == 3, 30)
         assert wait_until(
             lambda: all(
                 ov.node.lm.closed_ledger().seq
                 >= overlays[0].node.lm.closed_ledger().seq - 1
                 for ov in overlays
             ),
-            10,
+            30,
         )
 
 
