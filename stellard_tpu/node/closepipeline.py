@@ -22,7 +22,9 @@ makes that stage explicit and strictly ordered:
 The pipeline is storage-agnostic: the node passes the three stage
 callables (NodeStore save, txdb header+rows, CLF commit) and gets
 per-stage latency histograms + queue-depth gauges back via get_json()
-(surfaced in `server_state` / `get_counts`).
+(surfaced in `server_state` / `get_counts`). The two SQL stages return
+(rows, statements): what they bound and in how many statements, carried
+on their spans and summed here (a stage that returns None counts none).
 """
 
 from __future__ import annotations
@@ -64,8 +66,8 @@ class ClosePipeline:
     def __init__(
         self,
         save_stage: Callable,          # save_stage(ledger) -> NodeStore flush
-        txdb_stage: Callable,          # txdb_stage(ledger, results) -> rows
-        clf_stage: Callable,           # clf_stage(ledger) -> CLF commit
+        txdb_stage: Callable,  # txdb_stage(ledger, results) -> (rows, stmts)
+        clf_stage: Callable,   # clf_stage(ledger) -> (rows, stmts)
         recover_results: Optional[Callable] = None,  # ledger -> {txid: TER}
         depth: int = 8,
         name: str = "ledger-persist",
@@ -88,12 +90,18 @@ class ClosePipeline:
         self._by_hash: dict[bytes, _Entry] = {}
         self._by_seq: dict[int, _Entry] = {}
         self._stopping = False
+        # the entry queued with wake=False, until wake() lets the drain at it
+        self._held: Optional[_Entry] = None
         # metrics
         self.persisted = 0
         self.failed = 0
         self.depth_hwm = 0
         self.backpressure_waits = 0
         self.backpressure_ms = 0.0
+        # rows the SQL stages bound and the statements that carried them
+        # (each statement is one hand-over of the interpreter lock)
+        self.sql_written = {"txdb_rows": 0, "txdb_statements": 0,
+                            "clf_rows": 0, "clf_statements": 0}
         # the stage latencies (queue_wait, nodestore, txdb, clf, total)
         # are the tracer's `persist.*` spans: ONE histogram an interval,
         # in tracer.stage_hist, which get_json() reads back
@@ -114,11 +122,28 @@ class ClosePipeline:
 
     def submit_close(self, ledger, results: dict,
                      done: Optional[Callable] = None,
-                     on_failed: Optional[Callable] = None) -> None:
+                     on_failed: Optional[Callable] = None,
+                     wake: bool = True) -> None:
         """Queue a freshly-closed ledger for full persistence (NodeStore +
-        tx rows + ordered CLF commit). Blocks when the queue is full."""
+        tx rows + ordered CLF commit). Blocks when the queue is full.
+        `wake=False` queues it where readers find it and holds the drain
+        off it until wake() (or a flush, a reader's wait, stop): the
+        standalone close is the first of its sinks, and a drain that
+        keeps up would otherwise start this ledger's persist beside the
+        closing thread's other sinks and take half the interpreter from
+        them (`close_p50_ms`, PERF.md section 6, PR 31)."""
         self._submit(_Entry("close", ledger, results, done, on_failed),
-                     self.depth)
+                     self.depth, wake)
+
+    def wake(self) -> None:
+        """Let the drain at an entry queued with `wake=False`."""
+        with self._lock:
+            self._release()
+
+    def _release(self) -> None:
+        # caller holds self._lock
+        self._held = None
+        self._not_empty.notify()
 
     def submit_repair(self, ledger, results: Optional[dict] = None,
                       done: Optional[Callable] = None,
@@ -157,7 +182,8 @@ class ClosePipeline:
     def _kind_depth(self, kind: str) -> int:
         return sum(1 for e in self._queue if e.kind == kind)
 
-    def _submit(self, entry: _Entry, limit: int) -> None:
+    def _submit(self, entry: _Entry, limit: int,
+                wake: bool = True) -> None:
         with self._not_full:
             if self._stopping:
                 # never strand the submitter's accounting on shutdown
@@ -193,7 +219,10 @@ class ClosePipeline:
                 h = entry.ledger.hash()
                 self._by_hash[h] = entry
                 self._by_seq[entry.ledger.seq] = entry
-            self._not_empty.notify()
+            if wake:
+                self._not_empty.notify()
+            else:
+                self._held = entry
 
     # -- read-your-writes lookups -----------------------------------------
 
@@ -229,7 +258,8 @@ class ClosePipeline:
     def _drain(self) -> None:
         while True:
             with self._not_empty:
-                while not self._queue and not self._stopping:
+                while not self._stopping and (
+                        not self._queue or self._queue[0] is self._held):
                     self._not_empty.wait(timeout=1.0)
                 if not self._queue:
                     # stopping and drained
@@ -305,13 +335,14 @@ class ClosePipeline:
         self.save_stage(entry.ledger)
         t1 = time.perf_counter()
         tr.complete("persist.nodestore", "persist", t0, t1, seq=seq)
-        self.txdb_stage(entry.ledger, results)
+        wrote = self._sql_wrote(
+            "txdb", self.txdb_stage(entry.ledger, results))
         t2 = time.perf_counter()
-        tr.complete("persist.txdb", "persist", t1, t2, seq=seq)
+        tr.complete("persist.txdb", "persist", t1, t2, seq=seq, **wrote)
         if entry.kind == "close":
-            self.clf_stage(entry.ledger)
+            wrote = self._sql_wrote("clf", self.clf_stage(entry.ledger))
             t3 = time.perf_counter()
-            tr.complete("persist.clf", "persist", t2, t3, seq=seq)
+            tr.complete("persist.clf", "persist", t2, t3, seq=seq, **wrote)
         t_end = time.perf_counter()
         tr.complete("persist.total", "persist", t_start, t_end, seq=seq,
                     kind=entry.kind, txs=len(results or ()))
@@ -325,6 +356,16 @@ class ClosePipeline:
                     tr.instant("persist.tx", "persist", txid=txid,
                                ledger_seq=seq)
 
+    def _sql_wrote(self, stage: str, wrote) -> dict:
+        """-> the span attributes of what a SQL stage returned, summed
+        into the running totals (drain thread only)."""
+        if wrote is None:
+            return {}
+        rows, statements = wrote
+        self.sql_written[f"{stage}_rows"] += rows
+        self.sql_written[f"{stage}_statements"] += statements
+        return {"rows": rows, "statements": statements}
+
     # -- lifecycle ---------------------------------------------------------
 
     def flush(self, timeout: Optional[float] = None) -> bool:
@@ -332,6 +373,7 @@ class ClosePipeline:
         drained, False on timeout."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self._idle:
+            self._release()  # somebody waits: no deferred start
             while self._queue or self._active is not None:
                 remaining = None
                 if deadline is not None:
@@ -357,6 +399,7 @@ class ClosePipeline:
             return True
         deadline = time.monotonic() + timeout
         with self._idle:
+            self._release()  # somebody waits: no deferred start
             while any(
                 self._by_hash.get(h) is e or self._active is e
                 for h, e in targets
@@ -399,6 +442,7 @@ class ClosePipeline:
             "failed": self.failed,
             "backpressure_waits": self.backpressure_waits,
             "backpressure_ms": round(self.backpressure_ms, 3),
+            **self.sql_written,
             # from the tracer's `persist.*` stage histograms (absent
             # stages with `[trace] enabled=0`: nothing records them)
             "stages": {

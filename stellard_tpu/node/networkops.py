@@ -107,6 +107,8 @@ class NetworkOPs:
         # pub/sub sinks (wired by InfoSub manager; reference NetworkOPsImp
         # mSubLedger / mSubTransactions / ...)
         self.on_ledger_closed: list[Callable[[Ledger, dict], None]] = []
+        # run once every sink above has, whatever order they registered in
+        self.after_ledger_closed: list[Callable[[], None]] = []
         self.on_proposed_tx: list[Callable[[SerializedTransaction, TER], None]] = []
         # bounded status map (insertion-ordered; oldest evicted) — the
         # HashRouter equivalent of this sweeps on a hold timer
@@ -469,20 +471,24 @@ class NetworkOPs:
         for txid, _ter in results.items():
             if self.on_tx_result.get(txid) == TxStatus.INCLUDED:
                 self._record_status(txid, TxStatus.COMMITTED)
-        for sink in self.on_ledger_closed:
-            sink(closed, results)
-        if self.read_plane is not None:
-            # hand the serving side its persisted-tip floor — AFTER the
-            # sinks, so by the time the validated-seq cache opens this
-            # epoch the persistence pipeline already holds the ledger's
-            # entry and the SQL-index RPCs' read-your-writes wait
-            # (_await_history) covers it; in networked mode this whole
-            # method runs post-persist on the drain worker. The read
-            # plane publishes min(persisted, validated): a degraded
-            # solo close never masquerades as validated state, and on a
-            # quorum net the epoch opens when the validation floor
-            # catches up (LedgerMaster.on_validated -> note_validated).
-            self.read_plane.note_persisted(closed)
+        try:
+            for sink in self.on_ledger_closed:
+                sink(closed, results)
+            if self.read_plane is not None:
+                # hand the serving side its persisted-tip floor — AFTER the
+                # sinks, so by the time the validated-seq cache opens this
+                # epoch the persistence pipeline already holds the ledger's
+                # entry and the SQL-index RPCs' read-your-writes wait
+                # (_await_history) covers it; in networked mode this whole
+                # method runs post-persist on the drain worker. The read
+                # plane publishes min(persisted, validated): a degraded
+                # solo close never masquerades as validated state, and on a
+                # quorum net the epoch opens when the validation floor
+                # catches up (LedgerMaster.on_validated -> note_validated).
+                self.read_plane.note_persisted(closed)
+        finally:
+            for hook in self.after_ledger_closed:
+                hook()
 
     def _record_status(self, txid: bytes, status: TxStatus) -> None:
         m = self.on_tx_result

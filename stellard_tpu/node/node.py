@@ -957,12 +957,16 @@ class Node:
             self.txq.on_drop = self.overlay.node.local_txs.remove
         elif cfg.close_pipeline_enabled:
             # standalone: the ledger-closed sink ENQUEUES — ledger N's
-            # NodeStore/txdb/CLF writes overlap ledger N+1's verify/apply
+            # NodeStore/txdb/CLF writes overlap ledger N+1's verify/apply.
+            # First of the sinks, so whoever the later ones tell of the
+            # ledger finds it in the pipeline; the drain starts on it
+            # when the closing thread is through with all of them
             self.ops.on_ledger_closed.append(
                 lambda led, results: self.close_pipeline.submit_close(
-                    led, results
+                    led, results, wake=False
                 )
             )
+            self.ops.after_ledger_closed.append(self.close_pipeline.wake)
         else:
             # serial fallback ([close_pipeline] enabled=0): persistence
             # rides the ledger-closed sink in-line, on the close path
@@ -1220,6 +1224,7 @@ class Node:
                 "depth": self.close_pipeline.pending(),
                 "persisted": self.close_pipeline.persisted,
                 "backpressure_waits": self.close_pipeline.backpressure_waits,
+                **self.close_pipeline.sql_written,
             },
         )
         # subscription-fanout + read-cache gauges (ROADMAP item 3):
@@ -1517,22 +1522,25 @@ class Node:
         self._commit_clf(ledger)
         HEAP_AGING.age()
 
-    def _commit_clf(self, ledger: Ledger) -> None:
+    def _commit_clf(self, ledger: Ledger) -> tuple[int, int]:
         # CLF commit: one scoped SQL transaction — entry-row delta + LCL
         # pointer (reference: stellar::LedgerMaster::commitLedgerClose).
         # NOT part of persist_ledger_data: a repaired HISTORICAL ledger
         # must never move the CLF resume pointer backwards.
         prev = self.ledger_master.get_ledger_by_hash(ledger.parent_hash)
-        self.clf.commit_ledger_close(ledger, prev)
+        wrote = self.clf.commit_ledger_close(ledger, prev)
         if self.online_deleter is not None:
             # rotation hook: runs on the drain worker AFTER the ledger
             # is fully durable; cheap check, sweeps happen in background
             self.online_deleter.on_validated(ledger.seq)
+        return wrote
 
-    def _persist_tx_rows(self, ledger: Ledger, results: dict) -> None:
+    def _persist_tx_rows(self, ledger: Ledger,
+                         results: dict) -> tuple[int, int]:
         """Header + tx rows in ONE sqlite transaction (close-pipeline txdb
         stage). Rows were usually materialized at close time overlapped
-        with the seal tree-hash (LedgerMaster.persist_prep)."""
+        with the seal tree-hash (LedgerMaster.persist_prep). -> (rows
+        bound, statements executed)."""
         rows = getattr(ledger, "persist_rows", None)
         if rows is None:
             rows = build_tx_rows(ledger, results)
@@ -1540,7 +1548,7 @@ class Node:
             # one-shot: the memo must not pin row data in the ledger
             # cache for the ledger's whole cache lifetime
             ledger.persist_rows = None
-        self.txdb.save_ledger(ledger, rows)
+        return self.txdb.save_ledger(ledger, rows)
 
     def persist_ledger_data(self, ledger: Ledger, results: dict) -> None:
         """NodeStore + header + tx rows for one ledger (no CLF pointer) —

@@ -13,6 +13,8 @@ import sqlite3
 import threading
 from typing import Optional
 
+from ..utils.sqlrows import write_rows
+
 __all__ = ["TxDatabase"]
 
 
@@ -44,8 +46,8 @@ class TxDatabase:
             """CREATE INDEX IF NOT EXISTS AcctTxIndex ON
                  AccountTransactions(Account, LedgerSeq, TxnSeq)"""
         )
-        # the per-row DELETE in save_transactions keys on TransID; without
-        # this index it full-scans the table per tx — O(n^2) over a run
+        # the DELETE in _insert_tx_rows keys on TransID; without this
+        # index it full-scans the table per tx — O(n^2) over a run
         # (reference: DBInit.cpp:62-63 AcctTxIDIndex)
         cur.execute(
             """CREATE INDEX IF NOT EXISTS AcctTxIDIndex ON
@@ -101,11 +103,9 @@ class TxDatabase:
     # -- transactions -----------------------------------------------------
 
     def save_transactions(self, rows: list[tuple]) -> None:
-        """Persist a closed ledger's tx rows: three executemany calls
-        instead of 3+len(affected) executes per tx (sqlite statement
-        dispatch was ~25% of the flood apply path). Each row is
-        (txid, tx_type, account, seq, ledger_seq, status, raw, meta,
-        affected_accounts, txn_seq)."""
+        """Persist a closed ledger's tx rows without its header (the
+        archive's importer). Each row is (txid, tx_type, account, seq,
+        ledger_seq, status, raw, meta, affected_accounts, txn_seq)."""
         with self._lock:
             self._insert_tx_rows(rows)
             self._commit()
@@ -202,18 +202,20 @@ class TxDatabase:
 
     # -- whole-ledger persist (close-pipeline txdb stage) -----------------
 
-    def save_ledger(self, ledger, rows: list[tuple]) -> None:
+    def save_ledger(self, ledger, rows: list[tuple]) -> tuple[int, int]:
         """Header + all tx rows in ONE sqlite transaction (one fsync per
         closed ledger instead of two, and a crash can never leave the
         header stored without its rows). `rows` is save_transactions'
-        row shape, usually pre-materialized at close time."""
+        row shape, usually pre-materialized at close time. -> (rows
+        bound, statements executed), the header's one of each included."""
         with self._lock:
             self._conn.execute(
                 "INSERT OR REPLACE INTO Ledgers VALUES (?,?,?,?,?,?,?,?,?,?)",
                 self._header_row(ledger),
             )
-            self._insert_tx_rows(rows)
+            bound, statements = self._insert_tx_rows(rows)
             self._conn.commit()
+        return bound + 1, statements + 1
 
     @staticmethod
     def _header_row(ledger) -> tuple:
@@ -230,31 +232,37 @@ class TxDatabase:
             ledger.tx_hash.hex(),
         )
 
-    def _insert_tx_rows(self, rows: list[tuple]) -> None:
-        """Three executemany calls over pre-built rows; caller holds the
-        lock and owns the commit."""
+    def _insert_tx_rows(self, rows: list[tuple]) -> tuple[int, int]:
+        """A ledger's pre-built rows as a few multi-row statements
+        (utils.sqlrows: the drain thread hands the interpreter lock over
+        once a statement, not once a row), in the order and with the
+        effect of the per-row statements they replace: REPLACE the
+        Transactions rows, DELETE the AccountTransactions rows of those
+        ids (a repaired ledger's rows exist already), INSERT them anew.
+        Caller holds the lock and owns the commit; an error leaves the
+        statements before it pending in the open transaction, as ever.
+        -> (rows bound to statements, a DELETE's ids among them;
+        statements executed)."""
         tx_rows = []
-        del_rows = []
+        ids = []
         acct_rows = []
         for (txid, tx_type, account, seq, ledger_seq, status, raw, meta,
              affected, txn_seq) in rows:
             h = txid.hex()
             tx_rows.append((h, tx_type, account.hex(), seq, ledger_seq,
                             status, raw, meta))
-            del_rows.append((h,))
+            ids.append((h,))
             for acct in affected:
                 acct_rows.append((h, acct.hex(), ledger_seq, txn_seq))
-        cur = self._conn.cursor()
-        cur.executemany(
-            "INSERT OR REPLACE INTO Transactions VALUES (?,?,?,?,?,?,?,?)",
-            tx_rows,
-        )
-        cur.executemany(
-            "DELETE FROM AccountTransactions WHERE TransID = ?", del_rows
-        )
-        cur.executemany(
-            "INSERT INTO AccountTransactions VALUES (?,?,?,?)", acct_rows
-        )
+        conn = self._conn
+        statements = write_rows(
+            conn, "INSERT OR REPLACE INTO Transactions VALUES ", 8, tx_rows)
+        statements += write_rows(
+            conn, "DELETE FROM AccountTransactions WHERE TransID IN (VALUES ",
+            1, ids, ")")
+        statements += write_rows(
+            conn, "INSERT INTO AccountTransactions VALUES ", 4, acct_rows)
+        return len(tx_rows) + len(ids) + len(acct_rows), statements
 
     # -- ledger headers ---------------------------------------------------
 

@@ -39,6 +39,7 @@ from ..protocol.sfields import (
     sfTakerPays,
 )
 from ..protocol.stobject import STObject
+from ..utils.sqlrows import write_rows
 
 __all__ = ["LedgerSqlDatabase", "CLFMirror"]
 
@@ -84,6 +85,130 @@ K_LCL_HASH = "LastClosedLedger"
 K_LCL_CONTENT = "LastClosedLedgerContent"
 
 
+# table -> (upsert head, columns, delete-by-key head); the order the
+# tables are written in
+_TABLES = {
+    "accounts": ("INSERT OR REPLACE INTO accounts VALUES ", 6,
+                 "DELETE FROM accounts WHERE account_id IN (VALUES "),
+    "trustlines": ("INSERT OR REPLACE INTO trustlines VALUES ", 8,
+                   "DELETE FROM trustlines WHERE index_hex IN (VALUES "),
+    "offers": ("INSERT OR REPLACE INTO offers VALUES ", 6,
+               "DELETE FROM offers WHERE index_hex IN (VALUES "),
+}
+
+
+def entry_row(index: bytes, sle: STObject) -> Optional[tuple]:
+    """-> (table, the entry's mirror row), or None: directory, amendment
+    and fee entries have no row mirror (reference LedgerEntry::makeEntry
+    returns null for them too)."""
+    letype = LedgerEntryType(sle[_LE_TYPE_FIELD])
+    if letype == LedgerEntryType.ltACCOUNT_ROOT:
+        return "accounts", (
+            sle[sfAccount].hex(),
+            sle[sfBalance].drops(),
+            sle.get(sfSequence, 0),
+            sle.get(sfOwnerCount, 0),
+            sle.get(sfFlags, 0),
+            (sle.get(sfRegularKey) or b"").hex(),
+        )
+    if letype == LedgerEntryType.ltRIPPLE_STATE:
+        low = sle[sfLowLimit]
+        high = sle[sfHighLimit]
+        return "trustlines", (
+            index.hex(),
+            low.issuer.hex(),
+            high.issuer.hex(),
+            low.currency.hex(),
+            sle[sfBalance].value_text(),
+            low.value_text(),
+            high.value_text(),
+            sle.get(sfFlags, 0),
+        )
+    if letype == LedgerEntryType.ltOFFER:
+        return "offers", (
+            index.hex(),
+            sle[sfAccount].hex(),
+            sle.get(sfSequence, 0),
+            repr(sle[sfTakerPays]),
+            repr(sle[sfTakerGets]),
+            sle.get(sfFlags, 0),
+        )
+    return None
+
+
+def entry_key(index: bytes, sle: STObject) -> Optional[tuple]:
+    """-> (table, the one-column key row a deleted entry's mirror row
+    goes by), or None for an entry without a row."""
+    letype = LedgerEntryType(sle[_LE_TYPE_FIELD])
+    if letype == LedgerEntryType.ltACCOUNT_ROOT:
+        return "accounts", (sle[sfAccount].hex(),)
+    if letype == LedgerEntryType.ltRIPPLE_STATE:
+        return "trustlines", (index.hex(),)
+    if letype == LedgerEntryType.ltOFFER:
+        return "offers", (index.hex(),)
+    return None
+
+
+# stored rows a table gathers before they are written: a whole-state
+# import of a million accounts holds this many rows, not the state's
+_IMPORT_BATCH = 32_768
+
+
+class _EntryRows:
+    """The mirror rows of one commit, gathered by table and written as
+    multi-row statements, `_IMPORT_BATCH` rows at a time. A key is
+    stored or deleted, never both: a SHAMap delta has one verdict a key
+    and an import deletes nothing, so writing a table's stored rows
+    before its deleted keys gives what the entries' own order gave.
+    Within a table the stored rows keep that order (and with it their
+    rowid order, which `offer_keys` hands the book index)."""
+
+    def __init__(self, db: "LedgerSqlDatabase"):
+        self.db = db
+        self.stored: dict[str, list] = {t: [] for t in _TABLES}
+        self.deleted: dict[str, list] = {t: [] for t in _TABLES}
+        self.rows = 0
+        self.statements = 0
+
+    def store(self, item) -> None:
+        got = entry_row(item.tag, _parsed(item))
+        if got is not None:
+            table, row = got
+            pending = self.stored[table]
+            pending.append(row)
+            if len(pending) >= _IMPORT_BATCH:
+                self._write_stored(table)
+
+    def delete(self, item) -> None:
+        got = entry_key(item.tag, _parsed(item))
+        if got is not None:
+            self.deleted[got[0]].append(got[1])
+
+    def _write_stored(self, table: str) -> None:
+        head, ncols, _delete = _TABLES[table]
+        pending = self.stored[table]
+        self.statements += self.db.write_rows(head, ncols, pending)
+        self.rows += len(pending)
+        pending.clear()
+
+    def finish(self) -> None:
+        for table, (_head, _ncols, delete) in _TABLES.items():
+            self._write_stored(table)
+            keys = self.deleted[table]
+            self.statements += self.db.write_rows(delete, 1, keys, ")")
+            self.rows += len(keys)
+            keys.clear()
+
+
+def _parsed(item) -> STObject:
+    # the engine pinned a parsed mirror on every item it wrote
+    # (Ledger.write_entry); reuse it — re-parsing every changed entry was
+    # the commit's dominant Python cost, and on the close-pipeline worker
+    # it stole GIL time from the next ledger's apply
+    sle = item.parsed
+    return sle if sle is not None else STObject.from_bytes(item.data)
+
+
 class LedgerSqlDatabase:
     """SQLite CLF store with explicit scoped transactions."""
 
@@ -125,68 +250,12 @@ class LedgerSqlDatabase:
 
     # -- typed rows --------------------------------------------------------
 
-    def store_entry(self, index: bytes, sle: STObject) -> None:
-        letype = LedgerEntryType(sle[_LE_TYPE_FIELD])
+    def write_rows(self, head: str, ncols: int, rows: list,
+                   tail: str = "") -> int:
+        """utils.sqlrows.write_rows on this connection, inside the
+        caller's scoped transaction; -> statements executed."""
         with self._lock:
-            if letype == LedgerEntryType.ltACCOUNT_ROOT:
-                self._conn.execute(
-                    "INSERT OR REPLACE INTO accounts VALUES (?,?,?,?,?,?)",
-                    (
-                        sle[sfAccount].hex(),
-                        sle[sfBalance].drops(),
-                        sle.get(sfSequence, 0),
-                        sle.get(sfOwnerCount, 0),
-                        sle.get(sfFlags, 0),
-                        (sle.get(sfRegularKey) or b"").hex(),
-                    ),
-                )
-            elif letype == LedgerEntryType.ltRIPPLE_STATE:
-                low = sle[sfLowLimit]
-                high = sle[sfHighLimit]
-                self._conn.execute(
-                    "INSERT OR REPLACE INTO trustlines VALUES (?,?,?,?,?,?,?,?)",
-                    (
-                        index.hex(),
-                        low.issuer.hex(),
-                        high.issuer.hex(),
-                        low.currency.hex(),
-                        sle[sfBalance].value_text(),
-                        low.value_text(),
-                        high.value_text(),
-                        sle.get(sfFlags, 0),
-                    ),
-                )
-            elif letype == LedgerEntryType.ltOFFER:
-                self._conn.execute(
-                    "INSERT OR REPLACE INTO offers VALUES (?,?,?,?,?,?)",
-                    (
-                        index.hex(),
-                        sle[sfAccount].hex(),
-                        sle.get(sfSequence, 0),
-                        repr(sle[sfTakerPays]),
-                        repr(sle[sfTakerGets]),
-                        sle.get(sfFlags, 0),
-                    ),
-                )
-            # directory/amendment/fee singletons have no row mirror
-            # (reference LedgerEntry::makeEntry returns null for them too)
-
-    def delete_entry(self, index: bytes, sle: STObject) -> None:
-        letype = LedgerEntryType(sle[_LE_TYPE_FIELD])
-        with self._lock:
-            if letype == LedgerEntryType.ltACCOUNT_ROOT:
-                self._conn.execute(
-                    "DELETE FROM accounts WHERE account_id=?",
-                    (sle[sfAccount].hex(),),
-                )
-            elif letype == LedgerEntryType.ltRIPPLE_STATE:
-                self._conn.execute(
-                    "DELETE FROM trustlines WHERE index_hex=?", (index.hex(),)
-                )
-            elif letype == LedgerEntryType.ltOFFER:
-                self._conn.execute(
-                    "DELETE FROM offers WHERE index_hex=?", (index.hex(),)
-                )
+            return write_rows(self._conn, head, ncols, rows, tail)
 
     def drop_all_entries(self) -> None:
         with self._lock:
@@ -242,52 +311,48 @@ class CLFMirror:
 
     # -- close commit -------------------------------------------------------
 
-    def commit_ledger_close(self, new_ledger, prev_ledger=None) -> None:
+    def commit_ledger_close(self, new_ledger,
+                            prev_ledger=None) -> tuple[int, int]:
         """One atomic SQL transaction: entry-row delta + LCL state
-        (reference: commitLedgerClose → catchUp → updateDBFromLedger)."""
+        (reference: commitLedgerClose → catchUp → updateDBFromLedger).
+        -> (rows bound to statements, a deleted entry's key among them;
+        statements executed)."""
         stored = self.last_closed_hash
         if prev_ledger is None or stored != prev_ledger.hash():
             # mirror out of lockstep (fresh db, or we skipped ledgers):
             # rebuild from the full state walk
-            self.import_ledger_state(new_ledger)
-            return
+            return self.import_ledger_state(new_ledger)
         delta = new_ledger.state_map.compare(prev_ledger.state_map)
         with self.db.transaction():
-            for tag, (new_item, old_item) in delta.items():
-                # the engine pinned a parsed mirror on every item it
-                # wrote (Ledger.write_entry); reuse it — re-parsing every
-                # changed entry was the commit's dominant Python cost,
-                # and on the close-pipeline worker it stole GIL time
-                # from the next ledger's apply
+            entries = _EntryRows(self.db)
+            for new_item, old_item in delta.values():
                 if new_item is not None:
-                    sle = new_item.parsed
-                    if sle is None:
-                        sle = STObject.from_bytes(new_item.data)
-                    self.db.store_entry(tag, sle)
+                    entries.store(new_item)
                 elif old_item is not None:
-                    sle = old_item.parsed
-                    if sle is None:
-                        sle = STObject.from_bytes(old_item.data)
-                    self.db.delete_entry(tag, sle)
-            self._write_lcl_state(new_ledger)
+                    entries.delete(old_item)
+            entries.finish()
+            statements = entries.statements + self._write_lcl_state(new_ledger)
         self.commits += 1
+        return entries.rows + 2, statements
 
-    def import_ledger_state(self, ledger) -> None:
+    def import_ledger_state(self, ledger) -> tuple[int, int]:
         """Full rebuild (reference importLedgerState): drop rows, walk the
         whole state tree, then swap the LCL pointer — atomically."""
         with self.db.transaction():
             self.db.drop_all_entries()
+            entries = _EntryRows(self.db)
             for item in ledger.state_map.items():
-                sle = item.parsed
-                if sle is None:
-                    sle = STObject.from_bytes(item.data)
-                self.db.store_entry(item.tag, sle)
-            self._write_lcl_state(ledger)
+                entries.store(item)
+            entries.finish()
+            statements = entries.statements + self._write_lcl_state(ledger)
         self.full_imports += 1
+        return entries.rows + 2, statements
 
-    def _write_lcl_state(self, ledger) -> None:
-        self.db.set_state(K_LCL_HASH, ledger.hash())
-        self.db.set_state(K_LCL_CONTENT, ledger.header_bytes())
+    def _write_lcl_state(self, ledger) -> int:
+        return self.db.write_rows(
+            "INSERT OR REPLACE INTO StoreState (StateName, State) VALUES ", 2,
+            [(K_LCL_HASH, ledger.hash()),
+             (K_LCL_CONTENT, ledger.header_bytes())])
 
     # -- resume -------------------------------------------------------------
 

@@ -14,7 +14,12 @@ Covers the pipeline contracts the node relies on:
   server_state.
 """
 
+import os
+import sys
 import threading
+import time
+
+import pytest
 
 from stellard_tpu.node.closepipeline import ClosePipeline, LatencyHist
 from stellard_tpu.node.config import Config
@@ -26,6 +31,7 @@ from stellard_tpu.protocol.stamount import STAmount
 from stellard_tpu.protocol.sttx import SerializedTransaction
 from stellard_tpu.rpc.handlers import Context, dispatch
 
+BENCH = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
 MASTER = KeyPair.from_passphrase("masterpassphrase")
 DESTS = [KeyPair.from_passphrase(f"cp-dest-{i}").account_id for i in range(4)]
 
@@ -314,6 +320,99 @@ class TestBackpressureAndOrder:
         assert pipe.stop(timeout=10)
 
 
+class _SeqLedger:
+    def __init__(self, seq):
+        self.seq = seq
+
+    def hash(self):
+        return self.seq.to_bytes(32, "big")
+
+
+class TestDeferredStart:
+    """The standalone close queues its ledger first of the sinks and
+    wakes the drain when the closing thread is through with the rest:
+    the persist does not run beside `accept_ledger`'s tail."""
+
+    @staticmethod
+    def _pipe(started):
+        return ClosePipeline(
+            save_stage=lambda led: started.set(),
+            txdb_stage=lambda led, results: None,
+            clf_stage=lambda led: None,
+        )
+
+    @pytest.mark.parametrize("waker", ["wake", "flush", "wait_for_closes"])
+    def test_unwoken_entry_is_readable_and_waits_for_its_waker(self, waker):
+        started = threading.Event()
+        pipe = self._pipe(started)
+        pipe.submit_close(_SeqLedger(1), {})  # the worker exists and idles
+        assert pipe.flush(timeout=10)
+        started.clear()
+        led = _SeqLedger(2)
+        pipe.submit_close(led, {}, wake=False)
+        # read-your-writes sees it at once; the drain has not begun
+        assert pipe.get_by_seq(2) is led and pipe.pending() == 1
+        assert not started.wait(timeout=0.2)
+        # the close's end, or anybody who waits for the ledger
+        assert getattr(pipe, waker)() in (None, True)
+        assert started.wait(timeout=0.5), "the waker did not start the drain"
+        assert pipe.flush(timeout=10) and pipe.persisted == 2
+        assert pipe.stop(timeout=10)
+
+    def test_held_entry_outlasts_the_drains_polls_and_stop_drains_it(self):
+        started = threading.Event()
+        pipe = self._pipe(started)
+        # a worker that has just started looks before it sleeps: held too
+        pipe.submit_close(_SeqLedger(1), {}, wake=False)
+        pipe.submit_repair(_SeqLedger(7))  # behind it, in order
+        assert not started.wait(timeout=1.5)
+        assert pipe.pending() == 2
+        assert pipe.stop(timeout=10)
+        assert started.is_set() and pipe.persisted == 2
+
+    def test_a_sink_that_raises_still_lets_the_drain_start(self):
+        node = Node(Config()).setup()
+
+        def bad_sink(led, results):
+            raise RuntimeError("a stream's sink fell over")
+
+        node.ops.on_ledger_closed.append(bad_sink)
+        for tx in _payments(2):
+            node.submit(SerializedTransaction.from_bytes(tx.serialize()))
+        with pytest.raises(RuntimeError):
+            node.ops.accept_ledger()
+        node.ops.on_ledger_closed.remove(bad_sink)
+        deadline = time.monotonic() + 10
+        while node.close_pipeline.persisted < 1:  # no flush: it would wake
+            assert time.monotonic() < deadline, "the ledger stayed held"
+            time.sleep(0.01)
+        assert node.txdb.get_ledger_header(seq=2) is not None
+        node.stop()
+
+    def test_standalone_persist_starts_after_every_other_sink(self):
+        node = Node(Config()).setup()
+        order = []
+        save = node.close_pipeline.save_stage
+        node.close_pipeline.save_stage = lambda led: (
+            order.append(("persist", led.seq)), save(led))
+
+        def late_sink(led, results):
+            # registered behind the pipeline's sink, as the streams are
+            assert node.close_pipeline.get_by_seq(led.seq) is led
+            time.sleep(0.3)
+            order.append(("sink", led.seq))
+
+        node.ops.on_ledger_closed.append(late_sink)
+        _drive(node, _payments(6), per_ledger=2)
+        assert node.close_pipeline.flush(timeout=60)
+        # a ledger's persist begins once its sinks are through: the
+        # first close's too, whose worker had yet to start
+        assert [o for o in order if o[1] == 2] == [("sink", 2), ("persist", 2)]
+        for seq in (3, 4):
+            assert order.index(("sink", seq)) < order.index(("persist", seq))
+        node.stop()
+
+
 class TestMetrics:
     def test_counts_and_server_state_surface_pipeline(self):
         node = Node(Config()).setup()
@@ -331,6 +430,55 @@ class TestMetrics:
         state = dispatch(Context(node, {}), "server_state")
         assert state["state"]["close_pipeline"]["depth"] == 0
         node.stop()
+
+    def test_sql_stages_say_what_they_bound_and_in_how_many_statements(self):
+        """`persist.txdb` / `persist.clf` spans carry `rows` and
+        `statements`; `get_counts` and the `/metrics` hook carry their
+        sums; and they add up to what the tables hold. A row is whatever
+        a statement bound: a header, a Transactions row, the id its
+        AccountTransactions rows are deleted by, an AccountTransactions
+        row; a changed entry's mirror row (or a deleted entry's key) and
+        the pointer's two."""
+        node = Node(Config(rpc_port=0)).setup().serve()  # serve(): the hooks
+        _drive(node, _payments(40), per_ledger=10)
+        assert node.close_pipeline.flush(timeout=60)
+        spans = {"persist.txdb": [], "persist.clf": []}
+        events = node.tracer.chrome_trace()["traceEvents"]
+        for ev in events:
+            if ev.get("ph") == "X" and ev["name"] in spans:
+                spans[ev["name"]].append(ev["args"])
+        assert len(spans["persist.txdb"]) == len(spans["persist.clf"]) == 4
+        cp = dispatch(Context(node, {}), "get_counts")["close_pipeline"]
+        hook = node.collector.instruments_snapshot()["hooks"]
+        for stage, name in (("txdb", "persist.txdb"), ("clf", "persist.clf")):
+            for what in ("rows", "statements"):
+                total = sum(a[what] for a in spans[name])
+                assert cp[f"{stage}_{what}"] == total > 0
+                assert hook[f"close_pipeline.{stage}_{what}"] == total
+        tables = node.txdb.counts()
+        assert tables["transactions"] == 40
+        # the genesis header went in by itself, before the pipeline
+        assert cp["txdb_rows"] == (
+            (tables["ledgers"] - 1) + 2 * tables["transactions"]
+            + tables["account_transactions"])
+        # a header, one REPLACE, one DELETE, one INSERT a ledger
+        assert cp["txdb_statements"] == 4 * 4
+        # the first commit finds a fresh mirror and imports the state:
+        # the master and four destinations, and the pointer's two rows;
+        # every later one rewrites those five accounts
+        assert [a["rows"] for a in spans["persist.clf"]] == [7, 7, 7, 7]
+        assert node.clf.db.count("accounts") == 5
+        assert node.clf.full_imports == 1 and node.clf.commits == 3
+        # accounts and the pointer: two statements a commit
+        assert cp["clf_statements"] == 4 * 2
+        node.stop()
+        # and the benchmark's reader of these spans reads their ratio
+        sys.path.insert(0, BENCH)
+        from yardstick import manifest, readers
+
+        got = readers.read_metric(manifest.reader_file(
+            BENCH, "persist.rows_per_statement"), {"spans": events})
+        assert got == (cp["txdb_rows"] + cp["clf_rows"]) / (16 + 8)
 
     def test_latency_hist_quantiles(self):
         h = LatencyHist()
