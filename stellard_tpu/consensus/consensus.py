@@ -40,7 +40,7 @@ from .timing import (
 # (reference: PROPOSE_INTERVAL / PROPOSE_FRESHNESS, LedgerTiming.h:64-67)
 PROPOSE_INTERVAL = 12
 PROPOSE_FRESHNESS = 20
-from .txset import TxSet
+from .txset import MAX_TXSET_BLOBS, TxSet
 from .validation import STValidation
 from .validations import ValidationsStore
 
@@ -160,6 +160,18 @@ class LedgerConsensus:
         self._pre_close_open_ids: set[bytes] = set()
         self.our_close_time = 0
         self.round_ms = 0  # set on accept
+        self.position_changes = 0
+        # the round as intervals on the tracer's own clock (`clock` may
+        # be a scaled test clock): `consensus.round` from here to
+        # ACCEPTED, with `consensus.open` (to our close),
+        # `consensus.establish` (to agreement) and `consensus.accept`
+        # under it; a round abandoned for another LCL records nothing
+        self._span = self.tracer.begin(
+            "consensus.round", "consensus", seq=self.seq,
+        )
+        self._t_round = (self._span.t0 if self._span is not None
+                         else _time.perf_counter())
+        self._t_close: Optional[float] = None
 
     # -- timer ------------------------------------------------------------
 
@@ -195,7 +207,7 @@ class LedgerConsensus:
         takeInitialPosition :1761-1813)."""
         open_ledger = self.lm.current_ledger()
         self.our_set = TxSet(self.hash_batch)
-        for txid, blob, _meta in open_ledger.tx_entries():
+        for txid, blob in self._position_entries(open_ledger):
             self.our_set.add(txid, blob)
         if self.voting is not None:
             # flag-ledger voting: amendment/fee pseudo-txs join our initial
@@ -212,7 +224,7 @@ class LedgerConsensus:
                 self.our_set.add(ptx.txid(), ptx.serialize())
         # remembered for accept(): these are re-applied (when left out) by
         # close_with_txset, so the dispute-reapply loop must skip them
-        self._pre_close_open_ids = self.our_set.txids()
+        self._pre_close_open_ids |= self.our_set.txids()
         self.our_close_time = Ledger.round_close_time(
             self.network_time(), self.resolution
         )
@@ -224,7 +236,7 @@ class LedgerConsensus:
             self.adapter.propose(self.our_position)
             self.tracer.instant(
                 "consensus.propose_out", "consensus", seq=self.seq,
-                propose_seq=0, txs=len(self._pre_close_open_ids),
+                propose_seq=0, txs=len(self.our_set),
             )
         self.adapter.share_tx_set(self.our_set)
         self.acquired[self.our_set.hash()] = self.our_set
@@ -232,6 +244,12 @@ class LedgerConsensus:
         self.tracer.instant(
             "consensus.state", "consensus", seq=self.seq,
             state="ESTABLISH", open_ms=self._ms_since(self.round_start),
+        )
+        self._t_close = _time.perf_counter()
+        self.tracer.complete(
+            "consensus.open", "consensus", self._t_round, self._t_close,
+            seq=self.seq, parent=self._span,
+            txs=len(self.our_set), open_txs=len(self._pre_close_open_ids),
         )
         self.consensus_start = self.clock()
         self.last_propose = self.clock()
@@ -244,6 +262,41 @@ class LedgerConsensus:
                     self.acquired[prop.tx_set_hash] = ts
             if ts is not None:
                 self._compare_set(ts)
+
+    def _position_entries(self, open_ledger) -> list[tuple[bytes, bytes]]:
+        """The open ledger's transactions that go into our position:
+        all of them, unless they are more than a peer will take in one
+        candidate set (`MAX_TXSET_BLOBS`: past it `TxSetData` is refused
+        as hostile and its sender charged, so a validator that proposed
+        its whole open ledger after a long round could not be agreed
+        with by anyone, and the net forked for good: PERF.md section 6,
+        PR 32). Then the first of every account, the second of every
+        account, and so on up to the cap: every account's transactions
+        stay in sequence, and what is left out stays in the open ledger
+        for the next round."""
+        entries = [(txid, blob)
+                   for txid, blob, _meta in open_ledger.tx_entries()]
+        # close_with_txset re-applies every one of them that the agreed
+        # set leaves out, in our position or not
+        self._pre_close_open_ids = {txid for txid, _blob in entries}
+        if len(entries) <= MAX_TXSET_BLOBS:
+            return entries
+        from ..protocol.sttx import SerializedTransaction
+
+        parsed = getattr(open_ledger, "parsed_txs", {})
+        by_account: dict[bytes, list] = {}
+        for txid, blob in entries:
+            tx = parsed.get(txid) or SerializedTransaction.from_bytes(blob)
+            by_account.setdefault(tx.account, []).append(
+                (tx.sequence, txid, blob))
+        ranked = []
+        for chain in by_account.values():
+            chain.sort()
+            ranked.extend((rank, txid, blob)
+                          for rank, (_seq, txid, blob) in enumerate(chain))
+        ranked.sort()
+        return [(txid, blob) for _rank, txid, blob in
+                ranked[:MAX_TXSET_BLOBS]]
 
     # -- peer input -------------------------------------------------------
 
@@ -382,6 +435,12 @@ class LedgerConsensus:
                 agree=agree,
                 establish_ms=self._ms_since(self.consensus_start),
             )
+            self.tracer.complete(
+                "consensus.establish", "consensus", self._t_close,
+                _time.perf_counter(), seq=self.seq, parent=self._span,
+                proposers=len(self.peer_positions), agree=agree,
+                disputes=len(self.disputes),
+            )
             self.accept(ct, ct_agree)
 
     def _prune_stale_positions(self) -> None:
@@ -435,12 +494,15 @@ class LedgerConsensus:
             self.our_close_time = ct
             changed = True
         if changed:
+            self.position_changes += 1
             new_set = self.our_set.copy()
             for d in self.disputes.values():
-                if d.our_vote and d.txid not in new_set and d.blob:
-                    new_set.add(d.txid, d.blob)
-                elif not d.our_vote and d.txid in new_set:
+                if not d.our_vote and d.txid in new_set:
                     new_set.remove(d.txid)
+            for d in self.disputes.values():
+                if (d.our_vote and d.txid not in new_set and d.blob
+                        and len(new_set) < MAX_TXSET_BLOBS):
+                    new_set.add(d.txid, d.blob)
             self.our_set = new_set
             self.acquired[new_set.hash()] = new_set
             self.our_position = self.our_position.advanced(
@@ -468,12 +530,37 @@ class LedgerConsensus:
 
     def accept(self, close_time: int, ct_agree: bool) -> None:
         """Build the new LCL from the agreed set, sign and broadcast our
-        validation (reference: accept :931-1127)."""
+        validation (reference: accept :931-1127). `consensus.accept`
+        spans it up to ACCEPTED, over `close_with_txset`'s own
+        `close.*` spans; the hand-over to the next round
+        (`on_accepted`) lies behind both it and `consensus.round`."""
+        with self.tracer.span("consensus.accept", "consensus",
+                              seq=self.seq, parent=self._span):
+            new_lcl = self._accept(close_time, ct_agree)
+        self.tracer.end(
+            self._span, proposers=len(self.peer_positions),
+            txs=len(new_lcl.apply_results), disputes=len(self.disputes),
+            position_changes=self.position_changes,
+            round_ms=(_time.perf_counter() - self._t_round) * 1000.0,
+        )
+        self._span = None
+        self.adapter.on_accepted(new_lcl, self.round_ms)
+
+    def _accept(self, close_time: int, ct_agree: bool) -> Ledger:
         consensus_set = self.acquired.get(
             self.our_position.tx_set_hash if self.our_position else b"",
             self.our_set,
         )
         txs = consensus_set.transactions() if consensus_set else []
+        if not ct_agree:
+            # we agreed to disagree on the close time: every validator
+            # takes the SAME stand-in, one second past the parent's
+            # (reference: accept, "closeTime = prevCloseTime + 1"). Its
+            # own vote, which this used, gave four validators four
+            # ledgers over one agreed set: no ledger of the round could
+            # be validated, each went on alone, and the net did not
+            # come back (PERF.md section 6, PR 32)
+            close_time = self.prev_ledger.close_time + 1
         new_lcl, _results = self.lm.close_with_txset(
             txs, close_time, self.resolution, correct_close_time=ct_agree
         )
@@ -537,7 +624,7 @@ class LedgerConsensus:
             "consensus.state", "consensus", seq=self.seq,
             state="ACCEPTED", round_ms=self.round_ms,
         )
-        self.adapter.on_accepted(new_lcl, self.round_ms)
+        return new_lcl
 
     # -- introspection ----------------------------------------------------
 

@@ -249,6 +249,31 @@ class LedgerMaster:
                     self.ledgers_by_hash.put(h, led)
             return led
 
+    def validated_ledger_at(self, seq: int) -> Optional[Ledger]:
+        """The VALIDATED chain's ledger at `seq`, or None: ahead of the
+        last validated ledger, or not resolvable. Walked by parent hash
+        from the last validated ledger, headers only, for the last 256
+        sequences (what `set_validated` repairs); further back the
+        index's slot, which only the validated chain writes below its
+        floor. A sequence alone can name a ledger this node closed by
+        itself and left; a walk from the quorum's tip cannot."""
+        with self._lock:
+            tip = self.validated
+            if tip is None or seq > tip.seq:
+                return None
+            if seq == tip.seq:
+                return tip
+            if tip.seq - seq > 256:
+                return self.get_ledger_by_seq(seq)
+            cur_hash = tip.parent_hash
+            for _ in range(tip.seq - seq - 1):
+                info = self._resolve_header(cur_hash)
+                if info is None:
+                    return None
+                cur_hash = info[1]
+            led = self.get_ledger_by_hash(cur_hash)
+            return led if led is not None and led.seq == seq else None
+
     # -- held transactions (reference: addHeldTransaction) ----------------
 
     def add_held_transaction(self, tx: SerializedTransaction) -> None:
@@ -746,6 +771,11 @@ class LedgerMaster:
                 for txid, blob, _meta in open_ledger.tx_entries()
                 if txid not in consensus_ids
             ]
+            # the tx map hands them over in txid order: put an account's
+            # back in sequence, or the later one meets the new open
+            # ledger first, is held as terPRE_SEQ, and every submission
+            # of that account behind it queues up behind the hold
+            leftovers.sort(key=lambda tx: (tx.account, tx.sequence))
             self._open_next(new_lcl, (t_apply - t0) * 1000.0,
                             leftovers=leftovers)
             self._note_close_stages(t0, t_apply, t_seal, new_lcl.seq)

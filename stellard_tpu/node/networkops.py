@@ -101,8 +101,8 @@ class NetworkOPs:
         # ever taking the chain lock
         self.read_plane = None
         self.relay_tx: Optional[
-            Callable[[SerializedTransaction, set[int]], None]
-        ] = None
+            Callable[[SerializedTransaction, set[int], bool], None]
+        ] = None  # (tx, peers it came from, wait for room in a queue)
         self.local_push: Optional[Callable[[int, SerializedTransaction], None]] = None
         # pub/sub sinks (wired by InfoSub manager; reference NetworkOPsImp
         # mSubLedger / mSubTransactions / ...)
@@ -290,7 +290,8 @@ class NetworkOPs:
         sweeps ride the configured kernel."""
         ok = bool(self.vp.verify_many(
             [VerifyRequest(tx.signing_pub_key, tx.signing_hash(),
-                           tx.signature)]
+                           tx.signature)],
+            source="intake",
         )[0])
         tx.set_sig_verdict(ok)
         return ok
@@ -381,7 +382,10 @@ class NetworkOPs:
         # tx below the floor relays when promotion applies it
         # (publish_closed_ledger drains TxQ.drain_relay).
         if not ter.is_tem and (did_apply or ter == TER.terPRE_SEQ):
-            self.relay_applied(tx)
+            # the origin's relay, off the master lock: it may wait for
+            # room in a peer's queue (the promotion drain, on the
+            # persist worker, never does)
+            self.relay_applied(tx, wait=True)
         elif ter == TER.terQUEUED and txq is not None and (
             txq.meets_network_floor(tx, self.lm.current_ledger())
         ):
@@ -394,7 +398,7 @@ class NetworkOPs:
         return ter, did_apply
 
     def relay_applied(self, tx: SerializedTransaction,
-                      track: bool = True) -> bool:
+                      track: bool = True, wait: bool = False) -> bool:
         """Relay (+ optional local-retry tracking) for a tx this node
         accepted — shared by the submit path and the TxQ promotion
         drain. The SF_RELAYED swap_set gate makes the broadcast
@@ -406,7 +410,7 @@ class NetworkOPs:
             if self.relay_tx is not None:
                 # prev_peers = peers this tx already arrived from;
                 # they are excluded from the fan-out
-                self.relay_tx(tx, prev_peers)
+                self.relay_tx(tx, prev_peers, wait)
             if track and self.local_push is not None:
                 self.local_push(self.lm.closed_ledger().seq, tx)
         return newly

@@ -51,6 +51,7 @@ from typing import Callable, Optional
 from ..protocol.sfields import sfBalance, sfSequence
 from ..protocol.sttx import SerializedTransaction
 from ..protocol.ter import TER
+from ..consensus.timing import LEDGER_MIN_CLOSE_MS, LEDGER_MIN_CONSENSUS_MS
 from .loadmgr import NORMAL_FEE
 
 __all__ = ["TxQ", "FeeMetrics", "NORMAL_LEVEL", "fee_level"]
@@ -60,6 +61,10 @@ __all__ = ["TxQ", "FeeMetrics", "NORMAL_LEVEL", "fee_level"]
 # directly, so this MUST stay the same 1/256 scale as loadmgr's
 # NORMAL_FEE — imported, not redefined, to keep the coupling explicit.
 NORMAL_LEVEL = NORMAL_FEE
+
+# the protocol's shortest round (least open time + least time to
+# agree): the span over which a door lets the open ledger fill
+OPEN_FILL_S = (LEDGER_MIN_CLOSE_MS + LEDGER_MIN_CONSENSUS_MS) / 1000.0
 
 
 def fee_level(fee_drops: int, base_fee: int) -> int:
@@ -196,6 +201,11 @@ class TxQ:
         # the next close's splice/fallback classes for the
         # promote_spliced / promote_fallback counters
         self._promoted_window: set[bytes] = set()
+        # the open ledger the door's pacing counts from (open_has_room):
+        # its (seq, parent), when it opened, what it held then
+        self._paced: Optional[tuple] = None
+        self._paced_at = 0.0
+        self._paced_from = 0
         # promoted txs awaiting relay (fee floor met only at promotion);
         # drained outside the chain lock by publish_closed_ledger
         self._pending_relay: list[SerializedTransaction] = []
@@ -246,6 +256,35 @@ class TxQ:
         """Applied-tx count of an OPEN ledger (parsed_txs is seeded by
         LedgerMaster._open_apply exactly once per applied tx)."""
         return len(ledger.parsed_txs)
+
+    def _note_open(self, ledger, now: float) -> None:
+        """A new open ledger: the door's pacing starts from what it
+        holds now (the leftovers of the round before it)."""
+        self._paced = (ledger.seq, ledger.parent_hash)
+        self._paced_at = now
+        self._paced_from = self.open_size(ledger)
+
+    def open_has_room(self, lm) -> bool:
+        """What the door of a networked node asks before it takes a
+        client's `submit` in (rpc/http_server.py holds the answer while
+        this says no): the open ledger may GROW by the soft cap, and no
+        faster than evenly over the protocol's shortest round
+        (OPEN_FILL_S). Growth counts what every door of the net let in
+        since this ledger opened (ours, and what the peers relayed), not
+        the leftovers it opened with. The cap alone let a ledger's worth
+        in at the doors' speed the moment a ledger opened: a burst no
+        validator's intake had digested when the round closed, so it
+        landed in the NEXT round and every other round ran empty. A bare
+        read of the chain's state, no lock: only the door's loop asks."""
+        ledger = lm.current
+        if ledger is None:
+            return True
+        now = time.monotonic()
+        if (ledger.seq, ledger.parent_hash) != self._paced:
+            self._note_open(ledger, now)  # an adopted chain's open ledger
+        share = min(1.0, (now - self._paced_at) / OPEN_FILL_S)
+        grown = self.open_size(ledger) - self._paced_from
+        return grown < self.metrics.txns_expected * share
 
     def open_ledger_fee(self, ledger) -> int:
         """Drops required to enter the open ledger RIGHT NOW."""
@@ -514,6 +553,7 @@ class TxQ:
         )
         self._sweep_expired(closed_ledger.seq)
         self._lm = lm
+        self._note_open(lm.current_ledger(), time.monotonic())
         if self.spec_dispatch is not None:
             # the job promotes into THIS open window only: if the job
             # queue backs up past the next close (the overload case),

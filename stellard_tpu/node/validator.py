@@ -201,6 +201,22 @@ class ValidatorNode:
         self.degrade_transitions = 0
         # last VALIDATED seq the LocalTxs inclusion-sweep ran against
         self._local_sweep_seq = 0
+        # what the net hands the verify plane (`relay.*`, `netverify.*`
+        # in get_counts): relayed transactions by the way their
+        # signature was checked, proposals and validations by kind.
+        # `txs_in` and `duplicates` are the transport's to count (it is
+        # what knows a first sighting from a later copy)
+        self.relay_stats = AtomicCounters(
+            "txs_in", "duplicates", "sigs_verified", "batches", "singles",
+        )
+        self.netverify_stats = AtomicCounters(
+            "proposal_batches", "proposal_sigs",
+            "validation_batches", "validation_sigs",
+        )
+        # ledger hash -> (perf_counter at our accept, seq): the start
+        # of its `consensus.validated` span (our accept to the quorum's
+        # validations seen), bounded
+        self._accepted_at: dict[bytes, tuple[float, int]] = {}
 
     # -- byzantine defense -------------------------------------------------
 
@@ -565,6 +581,12 @@ class ValidatorNode:
         )
         self.prev_round_ms = max(round_ms, LEDGER_MIN_CONSENSUS_MS)
         self.rounds_completed += 1
+        if len(self._accepted_at) >= 16:
+            self._accepted_at.pop(next(iter(self._accepted_at)))
+        self._accepted_at[ledger.hash()] = (_time.perf_counter(), ledger.seq)
+        if self.lm.validated is ledger:
+            # the quorum's validations were here before our accept
+            self._note_validated(ledger.hash())
         self._fire_on_ledger(ledger)
         # local submissions that missed this ledger re-apply to the new
         # open ledger; landed/expired ones sweep (reference LocalTxs).
@@ -579,6 +601,25 @@ class ValidatorNode:
                 self.lm, TxParams.OPEN_LEDGER | TxParams.RETRY
             )
         self.begin_round()
+
+    def _note_validated(self, ledger_hash: bytes) -> None:
+        """`consensus.validated`, once a ledger of ours: from our accept
+        to `check_accept` seeing the quorum for it."""
+        mark = self._accepted_at.pop(ledger_hash, None)
+        if mark is not None:
+            self.lm.tracer.complete(
+                "consensus.validated", "consensus", mark[0],
+                _time.perf_counter(), seq=mark[1],
+                trusted=self.validations.trusted_count_for(ledger_hash),
+            )
+
+    def netverify_json(self) -> dict:
+        """`netverify.*` for get_counts: proposals and validations
+        through `_verify`, by kind and summed."""
+        nv = self.netverify_stats.snapshot()
+        nv["batches"] = nv["proposal_batches"] + nv["validation_batches"]
+        nv["sigs"] = nv["proposal_sigs"] + nv["validation_sigs"]
+        return nv
 
     def _sweep_local_txs(self) -> None:
         """Inclusion/expiry sweep against the latest quorum-validated
@@ -600,7 +641,7 @@ class ValidatorNode:
             return TER.temINVALID, False
         if not (flags & SF_SIGGOOD):
             ok, _ = tx.passes_local_checks()
-            if not ok or not self._check_tx_sig(tx):
+            if not ok or not self._check_tx_sig(tx, local):
                 self.router.set_flag(txid, SF_BAD)
                 return TER.temINVALID, False
             self.router.set_flag(txid, SF_SIGGOOD)
@@ -632,7 +673,8 @@ class ValidatorNode:
             signature=tx.signature,
         )
 
-    def _check_tx_sig(self, tx: SerializedTransaction) -> bool:
+    def _check_tx_sig(self, tx: SerializedTransaction,
+                      local: bool = True) -> bool:
         """Tx signature through the verify plane when one is wired —
         relayed network txs are the bulk of a real validator's verify
         load (reference: PeerImp::checkTransaction, the #1 hot call),
@@ -640,10 +682,15 @@ class ValidatorNode:
         batched/native/device plane entirely (close-p50 profile: ~45%%
         of busy samples in keys.verify_signature)."""
         if self.verify_many is not None:
-            good = bool(self.verify_many([self._tx_verify_request(tx)])[0])
+            source = "intake" if local else "relay"
+            good = bool(self.verify_many(
+                [self._tx_verify_request(tx)], source=source)[0])
             tx.set_sig_verdict(good)
-            return good
-        return tx.check_sign()
+        else:
+            good = tx.check_sign()
+        if not local:
+            self.relay_stats.add_many(singles=1, sigs_verified=1)
+        return good
 
     def prefetch_tx_sigs(self, txs: list) -> None:
         """Batch-verify a burst of relayed txs' signatures through the
@@ -657,14 +704,17 @@ class ValidatorNode:
         if self.verify_many is None:
             return
         pending = []
+        duplicates = flagged = 0
         seen: set[bytes] = set()  # dedupe: N copies of one tx in a burst
         for tx in txs:            # must cost ONE verify, not N
             txid = tx.txid()
             if txid in seen:
+                duplicates += 1
                 continue
             seen.add(txid)
             flags = self.router.get_flags(txid)
             if flags & (SF_SIGGOOD | SF_BAD):
+                flagged += 1
                 continue
             # structural validity gates the SIGGOOD flag exactly as the
             # per-tx path does (submit() skips its checks when the flag
@@ -677,9 +727,17 @@ class ValidatorNode:
             pending.append(tx)
         if not pending:
             return
-        results = self.verify_many(
-            [self._tx_verify_request(tx) for tx in pending]
-        )
+        # one `relay.tx_batch` a call that verifies anything: what the
+        # read carried, and what of it still needed a verdict
+        with self.lm.tracer.span(
+            "relay.tx_batch", "verify", n=len(txs), verified=len(pending),
+            duplicates=duplicates, already_flagged=flagged,
+        ):
+            results = self.verify_many(
+                [self._tx_verify_request(tx) for tx in pending],
+                source="relay",
+            )
+        self.relay_stats.add_many(batches=1, sigs_verified=len(pending))
         for tx, good in zip(pending, results):
             good = bool(good)
             tx.set_sig_verdict(good)
@@ -707,7 +765,7 @@ class ValidatorNode:
         if flags & SF_BAD:
             return False
         if not (flags & SF_SIGGOOD):
-            if not self._verify([prop]):
+            if not self._verify([prop], "proposal"):
                 self.router.set_flag(pid, SF_BAD)
                 self.note_byzantine(
                     "bad_proposal_sig", peer=prop.node_public
@@ -744,7 +802,7 @@ class ValidatorNode:
         if flags & SF_BAD:
             return False
         if not (flags & SF_SIGGOOD):
-            if not self._verify([val]):
+            if not self._verify([val], "validation"):
                 self.router.set_flag(vid, SF_BAD)
                 self.note_byzantine(
                     "bad_validation_sig", peer=val.signer or None
@@ -768,10 +826,11 @@ class ValidatorNode:
                 peer=val.signer.hex()[:16] if val.signer else None,
             )
             current = self.validations.add(val)
-            self.lm.check_accept(
+            if self.lm.check_accept(
                 val.ledger_hash,
                 self.validations.trusted_count_for(val.ledger_hash),
-            )
+            ):
+                self._note_validated(val.ledger_hash)
             if current and self.follower:
                 # steady-state tailing: a fresh trusted validation IS
                 # the new-validated-ledger announcement — elect/acquire
@@ -937,10 +996,13 @@ class ValidatorNode:
         if self.round is not None:
             self.round.have_tx_set(h, txset)
 
-    def _verify(self, objs) -> bool:
-        """Verify a burst of signed consensus objects (proposals or
-        validations); batched on the VerifyPlane when available. Returns
-        True only when every signature in the burst is good."""
+    def _verify(self, objs, kind: str) -> bool:
+        """Verify a burst of signed consensus objects (``kind``:
+        ``proposal`` or ``validation``); batched on the VerifyPlane when
+        available. Returns True only when every signature in the burst
+        is good."""
+        self.netverify_stats.add_many(
+            **{kind + "_batches": 1, kind + "_sigs": len(objs)})
         if self.verify_many is not None:
             from ..crypto.backend import VerifyRequest
 
@@ -952,7 +1014,7 @@ class ValidatorNode:
                 )
                 for o in objs
             ]
-            return bool(all(self.verify_many(reqs)))
+            return bool(all(self.verify_many(reqs, source=kind)))
         ok = True
         for o in objs:
             good = o.is_valid() if hasattr(o, "is_valid") else o.check_sign()

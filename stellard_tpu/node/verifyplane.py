@@ -382,7 +382,7 @@ class VerifyPlane:
                 self._pending = self._pending[self.max_batch :]
             reqs = [r for r, _ in batch]
             try:
-                results = self.verify_many(reqs)
+                results = self.verify_many(reqs, source="intake")
             except Exception as exc:  # noqa: BLE001 — fail the futures, not the plane
                 for _, fut in batch:
                     if not fut.done():
@@ -546,7 +546,14 @@ class VerifyPlane:
         t.start()
         return t
 
-    def verify_many(self, reqs: Sequence[VerifyRequest]) -> np.ndarray:
+    def verify_many(self, reqs: Sequence[VerifyRequest],
+                    source: Optional[str] = None) -> np.ndarray:
+        """``source`` names the caller on the batch's span, so a reader
+        can tell whose batch the router priced: ``intake`` (the
+        coalescing flusher and the synchronous door), ``relay`` (a
+        network read's burst of relayed transactions), ``proposal``,
+        ``validation`` (``ValidatorNode._verify``); a caller that does
+        not say (a replay, a check) leaves the attribute off."""
         if not reqs:
             return np.zeros(0, bool)
         n = len(reqs)
@@ -571,6 +578,8 @@ class VerifyPlane:
         else:
             why = "nodevice"
         evidence = {"why": why}
+        if source is not None:
+            evidence["source"] = source
         if exp_dev is not None:
             evidence["exp_device_ms"] = round(exp_dev, 3)
         if exp_cpu is not None:

@@ -64,6 +64,7 @@ from .wire import (
     TxSetData,
     ValidationMessage,
     frame,
+    frame_kind,
 )
 
 __all__ = ["TcpOverlay"]
@@ -90,6 +91,17 @@ class _Peer:
     # sendall — a relay burst of small frames becomes one size-bounded
     # batch write instead of a syscall per frame
     WRITE_COALESCE = 256 * 1024
+    # how long the ORIGIN of a transaction (the door that accepted it)
+    # waits for room in a full queue before it sheds like a relay: the
+    # wait is what makes a door answer no faster than its peers read,
+    # so a loop of clients is paced by the net and not by one node's
+    # apply rate (a relayed copy never waits: two pumps waiting on each
+    # other's queues would be a deadlock, and a relay is a duplicate of
+    # what the origin sends everyone itself)
+    ORIGIN_WAIT_S = 1.0
+    # how long a TLS reader waits for bytes before it looks again
+    # whether its session still stands
+    TLS_POLL_S = 0.05
 
     # never-recycled session ids for HashRouter suppression sets (id()
     # can be reused by a later peer object within the router's 300s hold,
@@ -138,6 +150,10 @@ class _Peer:
         self.sendq_dropped = 0
         self._consec_drops = 0
         self.evicted = False
+        # the overlay's outbound traffic counter (frame bytes -> None),
+        # called by the writer for each frame it takes off the queue;
+        # None on a bare peer
+        self.on_send: Optional[Callable[[bytes], None]] = None
         self._writer: Optional[threading.Thread] = None
         self.alive = True
         self.established_at = 0.0
@@ -151,13 +167,19 @@ class _Peer:
         # dialable identity of this peer for discovery
         self.advertised: Optional[tuple[str, int]] = None
 
-    def send(self, data: bytes) -> None:
+    def send(self, data: bytes, wait_s: float = 0.0,
+             spare: bool = False) -> None:
         """Non-blocking enqueue; the per-peer writer thread drains. A
         full queue sheds the OLDEST queued frame (never the sender's
         thread — the master lock may be held here); EVICT_DROPS
         consecutive overflows means the reader is wedged, not slow, and
         the peer is evicted so one dead peer can never hold a sendq's
-        worth of every relay wave forever."""
+        worth of every relay wave forever. With ``wait_s`` the caller
+        (off the master lock) first waits that long for room. A
+        ``spare`` frame (a relayed copy of a transaction its origin
+        sends every peer itself) is the one that goes when the queue is
+        full: it displaces nothing, and a queue full of them says
+        nothing of the reader, so it does not count toward eviction."""
         import queue
 
         if not self.alive:
@@ -173,7 +195,16 @@ class _Peer:
         try:
             self.sendq.put_nowait(data)
         except queue.Full:
+            if wait_s > 0.0:
+                try:
+                    self.sendq.put(data, timeout=wait_s)
+                    self._consec_drops = 0
+                    return
+                except queue.Full:
+                    pass
             self.sendq_dropped += 1
+            if spare:
+                return
             self._consec_drops += 1
             if self._consec_drops >= self.EVICT_DROPS:
                 self.evicted = True
@@ -193,10 +224,15 @@ class _Peer:
     def _write_loop(self) -> None:
         import queue
 
+        def count(frame_bytes: bytes) -> None:
+            if self.on_send is not None:
+                self.on_send(frame_bytes)
+
         while True:
             data = self.sendq.get()
             if data is None or not self.alive:
                 return
+            count(data)  # out of the queue: a frame it shed never counts
             # coalesce a backlog burst into one bounded write: frames
             # are self-delimiting, so concatenation is free batching
             if len(data) < self.WRITE_COALESCE:
@@ -210,6 +246,7 @@ class _Peer:
                     if nxt is None:  # close sentinel: flush then exit
                         self._flush(b"".join(chunks))
                         return
+                    count(nxt)
                     chunks.append(nxt)
                     size += len(nxt)
                 data = b"".join(chunks) if len(chunks) > 1 else data
@@ -234,13 +271,26 @@ class _Peer:
         b\"\" on EOF, data otherwise. Raises OSError on a dead socket."""
         if not self.is_tls:
             return self.sock.recv(bufsize)
+        import select
         import ssl as _ssl
 
         try:
+            # wait for bytes OUTSIDE the lock: a reader that holds it
+            # through the poll leaves the writer the instants between
+            # two polls, and on a busy interpreter it seldom got one (a
+            # proposal or a validation waited seconds in a queue, and a
+            # queue of 256 frames filled at 100 transactions a second)
+            with self.io_lock:
+                buffered = self.sock.pending()
+            if not buffered and not select.select(
+                    [self.sock], [], [], self.TLS_POLL_S)[0]:
+                return None
             with self.io_lock:
                 return self.sock.recv(bufsize)
         except (TimeoutError, socket.timeout, _ssl.SSLWantReadError):
             return None
+        except ValueError:  # select on a socket closed under us
+            raise OSError("socket closed") from None
 
     def close(self) -> None:
         self.alive = False
@@ -365,6 +415,11 @@ class TcpOverlay(ConsensusAdapter):
             "throttled_msgs", "dup_charges", "sendq_dropped",
             "sendq_evicted", "squelch_demoted",
         )
+        # messages and bytes in and out by message type
+        # (`overlay.msgs_in.transaction`, ... in get_counts): counted
+        # where a frame is queued for a peer and where a read's frames
+        # are decoded
+        self.traffic = AtomicCounters()
         self.unl_store = unl_store  # node.unl.UniqueNodeList or None
         # same-operator cluster (reference mtCLUSTER): members share their
         # load fee so the whole cluster escalates together
@@ -537,6 +592,7 @@ class TcpOverlay(ConsensusAdapter):
                      sendq_depth=self.sendq_cap,
                      evict_drops=self.sendq_evict_drops)
         peer.is_tls = tls
+        peer.on_send = self._count_out
         try:
             if inbound and not self.resources.should_admit(peer.remote):
                 # endpoint balance still above the drop line: refuse
@@ -828,6 +884,36 @@ class TcpOverlay(ConsensusAdapter):
 
     # -- message pump -----------------------------------------------------
 
+    def _count_out(self, data: bytes) -> None:
+        kind = frame_kind(data)
+        self.traffic.add_many(**{
+            "msgs_out." + kind: 1, "bytes_out." + kind: len(data)})
+
+    def _count_in(self, kinds: list) -> None:
+        deltas: dict[str, int] = {}
+        for kind, size in kinds:
+            deltas["msgs_in." + kind] = deltas.get("msgs_in." + kind, 0) + 1
+            deltas["bytes_in." + kind] = (
+                deltas.get("bytes_in." + kind, 0) + size)
+        self.traffic.add_many(**deltas)
+
+    def traffic_json(self) -> dict:
+        """`overlay.*` observability block: messages and bytes by
+        direction and message type, their totals, and what the send
+        queues shed (drops, evictions: `squelch_json`'s, repeated here
+        so one block answers what crossed the wire and what did not)."""
+        out: dict = {k: {} for k in
+                     ("msgs_in", "msgs_out", "bytes_in", "bytes_out")}
+        for name, n in self.traffic.snapshot().items():
+            group, kind = name.split(".", 1)
+            out[group][kind] = n
+        for group in list(out):
+            out[group + "_total"] = sum(out[group].values())
+        shed = self.squelch_json()
+        out["sendq_dropped"] = shed["sendq_dropped"]
+        out["sendq_evicted"] = shed["sendq_evicted"]
+        return out
+
     def _pump(self, peer: _Peer) -> None:
         while not self._stop.is_set() and peer.alive:
             try:
@@ -840,6 +926,8 @@ class TcpOverlay(ConsensusAdapter):
                 return
             peer.last_recv = time.monotonic()
             msgs = list(peer.reader.feed(data))
+            if msgs:
+                self._count_in(peer.reader.kinds)
             # WARN throttling (enforced resource pricing): while this
             # endpoint's balance sits above the warning line its
             # NON-ESSENTIAL inbound is shed before any parse/verify work
@@ -868,21 +956,27 @@ class TcpOverlay(ConsensusAdapter):
                         FEE_UNWANTED_DATA.cost * n_shed, "throttled flood"
                     ))
             # a single read often carries a burst of relayed txs: parse
-            # each ONCE and verify their signatures in one plane call
-            # before dispatching (an unparseable tx stays None here and
-            # raises inside _dispatch, where the sender is charged)
-            parsed_txs: dict[int, SerializedTransaction] = {}
+            # each ONCE and verify the signatures of the first sightings
+            # in one plane call before dispatching (an unparseable tx
+            # stays None here and raises inside _dispatch, where the
+            # sender is charged). The sighting is noted HERE, before
+            # the prefetch: its verdict makes the router know the txid,
+            # and a txid the router knows is no first sighting to
+            # _dispatch, which used to drop every transaction of a
+            # burst, verified but neither applied nor relayed
+            parsed_txs: dict[int, tuple] = {}
             if sum(1 for m in msgs if isinstance(m, TxMessage)) > 1:
                 for i, m in enumerate(msgs):
                     if isinstance(m, TxMessage):
                         try:
-                            parsed_txs[i] = (
-                                SerializedTransaction.from_bytes(m.blob)
-                            )
+                            tx = SerializedTransaction.from_bytes(m.blob)
                         except Exception:  # noqa: BLE001 — charged below
-                            pass
+                            continue
+                        parsed_txs[i] = (
+                            tx, self._first_seen(tx.txid(), peer))
                 try:
-                    self.node.prefetch_tx_sigs(list(parsed_txs.values()))
+                    self.node.prefetch_tx_sigs(
+                        [tx for tx, fresh in parsed_txs.values() if fresh])
                 except Exception:  # noqa: BLE001 — prefetch is an
                     pass           # optimization; per-tx paths re-verify
             for i, msg in enumerate(msgs):
@@ -944,14 +1038,21 @@ class TcpOverlay(ConsensusAdapter):
 
     def _dispatch(self, peer: _Peer, msg, parsed_tx=None) -> None:
         """reference: PeerImp message switch (PeerImp.cpp:1459-1738) —
-        verify → apply → relay-if-new, charging abusive senders."""
+        verify → apply → relay-if-new, charging abusive senders.
+        ``parsed_tx`` is a burst's (transaction, first sighting) as
+        ``_pump`` parsed and noted it."""
         node = self.node
         self._adopt_ctx(msg)
         if isinstance(msg, TxMessage):
-            tx = (parsed_tx if parsed_tx is not None
-                  else SerializedTransaction.from_bytes(msg.blob))
-            txid = tx.txid()
-            if self._first_seen(txid, peer):
+            if parsed_tx is not None:
+                tx, fresh = parsed_tx
+                txid = tx.txid()
+            else:
+                tx = SerializedTransaction.from_bytes(msg.blob)
+                txid = tx.txid()
+                fresh = self._first_seen(txid, peer)
+            node.relay_stats.add("txs_in")
+            if fresh:
                 # trace root for an overlay-relayed tx: the first sighting
                 # on this node (the local-submit root is NetworkOPs')
                 node.lm.tracer.instant(
@@ -959,9 +1060,14 @@ class TcpOverlay(ConsensusAdapter):
                     peer=peer.remote[0] if peer.remote else None,
                 )
                 if node.handle_tx(tx):
+                    # relayed once: a later dispute over it must not
+                    # send it to the same peers again
+                    node.router.set_flag(txid, SF_RELAYED)
                     self._relay(msg, except_peer=peer)
                 else:
                     self._charge_if_bad(peer, txid)
+            else:
+                node.relay_stats.add("duplicates")
         elif isinstance(msg, ProposeSet):
             prop = msg.to_proposal()
             pid = prop.suppression_id()
@@ -1122,8 +1228,12 @@ class TcpOverlay(ConsensusAdapter):
             targets = [
                 p for p in self.peers.values() if p is not except_peer
             ]
+        # a relayed transaction is the frame a full queue can spare:
+        # shedding the OLDEST frame for it threw proposals, validations
+        # and tx sets away under a flood, and evicted the peers
+        spare = except_peer is not None and isinstance(msg, TxMessage)
         for p in targets:
-            p.send(data)
+            p.send(data, spare=spare)
 
     def _broadcast(self, msg) -> None:
         self._relay(msg, None)
@@ -1288,14 +1398,23 @@ class TcpOverlay(ConsensusAdapter):
         )
 
     def relay_disputed_tx(self, blob: bytes) -> None:
+        """Flood a disputed transaction, unless this node has relayed
+        it already (reference: LedgerConsensus::addDisputedTransaction
+        relays only when setFlag(SF_RELAYED) was news). A transaction in
+        flight when a round closes is disputed on every validator that
+        has it; sending it again to peers that got it from us a moment
+        ago is the same-peer re-send the resource plane charges, and
+        under load a round's worth of them walked honest peers over the
+        drop line (PERF.md section 6, PR 32)."""
         msg = TxMessage(blob)
+        try:
+            txid = SerializedTransaction.from_bytes(blob).txid()
+        except Exception:  # noqa: BLE001 — a blob from a peer's set
+            return
+        if not self.node.router.set_flag(txid, SF_RELAYED):
+            return
         if self.node.lm.tracer.propagate:
-            try:
-                self._stamp_ctx(
-                    msg, txid=SerializedTransaction.from_bytes(blob).txid()
-                )
-            except Exception:  # noqa: BLE001 — tracing never blocks a relay
-                pass
+            self._stamp_ctx(msg, txid=txid)
         self._broadcast(msg)
 
     def request_ledger_data(self, msg: GetLedger) -> None:
@@ -1376,11 +1495,14 @@ class TcpOverlay(ConsensusAdapter):
         self._stamp_ctx(msg, txid=tx.txid())
         self._broadcast(msg)
 
-    def broadcast_tx(self, tx: SerializedTransaction, except_ids=None) -> None:
+    def broadcast_tx(self, tx: SerializedTransaction, except_ids=None,
+                     wait: bool = False) -> None:
         """Relay an already-applied client tx (the NetworkOPs relay seam).
         `except_ids` is the HashRouter suppression peer-id set — peers the
         tx already arrived FROM are excluded from the fan-out (reference:
-        the swapSet peer set drives exactly this exclusion)."""
+        the swapSet peer set drives exactly this exclusion). With `wait`
+        (the door that took the transaction in, off the master lock) a
+        full queue is given `_Peer.ORIGIN_WAIT_S` to make room."""
         msg = TxMessage(tx.serialize())
         self._stamp_ctx(msg, txid=tx.txid())
         data = frame(msg)
@@ -1390,8 +1512,9 @@ class TcpOverlay(ConsensusAdapter):
                 for p in self.peers.values()
                 if not except_ids or p.uid not in except_ids
             ]
+        wait_s = _Peer.ORIGIN_WAIT_S if wait else 0.0
         for p in targets:
-            p.send(data)
+            p.send(data, wait_s=wait_s)
 
     def peer_count(self) -> int:
         with self._peers_lock:

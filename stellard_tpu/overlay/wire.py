@@ -85,6 +85,7 @@ __all__ = [
     "encode_message",
     "decode_message",
     "frame",
+    "frame_kind",
     "FrameReader",
 ]
 
@@ -779,6 +780,16 @@ def decode_message(mt: int, payload: bytes):
     return _DECODERS[typ](payload)
 
 
+def frame_kind(data: bytes) -> str:
+    """The lower-case name of a frame's message type, read from its
+    header (``transaction``, ``propose_set``, ``validation``, ...):
+    what the overlay's traffic counters are keyed by."""
+    try:
+        return MessageType(int.from_bytes(data[4:6], "big")).name.lower()
+    except ValueError:
+        return "unknown"
+
+
 def frame(msg) -> bytes:
     """Full wire frame: 4-byte length + 2-byte type + payload
     (reference: Message.cpp 6-byte header)."""
@@ -792,11 +803,15 @@ class FrameReader:
 
     def __init__(self):
         self._buf = bytearray()
+        # (type name, frame bytes) of the messages the last feed()
+        # returned, in order: the overlay's inbound traffic counters
+        self.kinds: list[tuple[str, int]] = []
 
     def feed(self, data: bytes) -> list:
         """Append stream bytes; return completed messages."""
         self._buf.extend(data)
         out = []
+        self.kinds = []
         while len(self._buf) >= HEADER_LEN:
             length = int.from_bytes(self._buf[:4], "big")
             if length > MAX_FRAME:
@@ -809,4 +824,6 @@ class FrameReader:
             msg = decode_message(mt, payload)
             if msg is not None:  # unknown type: skipped, stream continues
                 out.append(msg)
+                self.kinds.append(
+                    (MessageType(mt).name.lower(), HEADER_LEN + length))
         return out

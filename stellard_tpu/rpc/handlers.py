@@ -670,8 +670,16 @@ def do_get_counts(ctx: Context) -> dict:
         # sendq shedding (doc/overlay.md degradation contract)
         out["squelch"] = overlay.squelch_json()
         out["peers"] = overlay.peer_count()
+        # what crossed the wire (`overlay.*`): messages and bytes by
+        # direction and message type, and what the send queues shed
+        out["overlay"] = overlay.traffic_json()
         vn = getattr(overlay, "node", None)
         if vn is not None:
+            # what the net hands the verify plane: relayed transactions
+            # by how their signature was checked (`relay.*`), proposals
+            # and validations by kind (`netverify.*`)
+            out["relay"] = vn.relay_stats.snapshot()
+            out["netverify"] = vn.netverify_json()
             if getattr(vn, "follower", False):
                 # follower ingest plane: ledgers adopted, validation-
                 # seen -> adopted latency, live acquisitions, segfetch
@@ -1029,7 +1037,18 @@ def do_tx(ctx: Context) -> dict:
     out = tx.obj.to_json()
     out["hash"] = h.upper()
     out["ledger_index"] = row["ledger_seq"]
-    out["validated"] = True
+    # a standalone node validates its own closes; on a net a stored
+    # transaction is validated when the QUORUM's chain holds it at its
+    # sequence: a row can be left from a ledger this node closed alone
+    # and abandoned, and the chain passing that sequence does not make
+    # it so
+    lm = ctx.node.ledger_master
+    if lm.min_validations == 0:
+        out["validated"] = True
+    else:
+        led = lm.validated_ledger_at(row["ledger_seq"])
+        out["validated"] = (
+            led is not None and led.get_transaction(txid) is not None)
     if row["meta"]:
         out["meta"] = STObject.from_bytes(row["meta"]).to_json()
     return out

@@ -31,6 +31,21 @@ _MAX_BODY = 10 * 1024 * 1024
 LAG_TICK_S = 0.050
 LAG_SPAN_MIN_S = 0.025
 
+# back-pressure at the door of a NETWORKED node: while the open ledger
+# has grown by its soft cap ([txq]), or faster than evenly over the
+# protocol's shortest round (`TxQ.open_has_room`), a client's `submit`
+# is held, up to SUBMIT_HOLD_S, until there is room; then it is admitted
+# as ever. A door answers in milliseconds, and what it admits costs
+# every validator time INSIDE the round, so clients that send as fast as
+# they are answered swelled the open ledger, the round behind it and the
+# next one more (PERF.md section 6, PR 32). Fee escalation prices the
+# same cap, but clients that all pay far more are not slowed by it, and
+# a held client is refused nothing, so an account's sequence stays in
+# order. A standalone node never holds: nothing closes its ledger but
+# its client.
+SUBMIT_HOLD_S = 10.0
+SUBMIT_HOLD_POLL_S = 0.02
+
 
 def process_http_request(node, body: bytes, role: Role = Role.ADMIN,
                          client_ip: str = "",
@@ -113,6 +128,8 @@ class HttpRpcServer:
         self.lag_ticks = 0
         self.lag_late_ticks = 0
         self.lag_s = 0.0
+        self.submit_holds = 0
+        self.submit_hold_s = 0.0
 
     # -- protocol ---------------------------------------------------------
 
@@ -173,6 +190,9 @@ class HttpRpcServer:
                     else:
                         payload = b'{"status": "ok"}'
                 else:
+                    if b'"submit"' in body:
+                        await self._hold_submit()
+                        t_read = time.perf_counter()  # busy_s: work only
                     peer = writer.get_extra_info("peername")
                     reply = process_http_request(
                         self.node, body,
@@ -198,6 +218,31 @@ class HttpRpcServer:
             pass
         finally:
             writer.close()
+
+    async def _hold_submit(self) -> None:
+        """Hold a `submit` while the open ledger has no room for it
+        (see SUBMIT_HOLD_S). The body is parsed once, behind this, so
+        the method is read off its bytes: a request that only mentions
+        "submit" is delayed like one, and no worse."""
+        node = self.node
+        txq = getattr(node, "txq", None)
+        if (txq is None or not txq.enabled
+                or getattr(node, "overlay", None) is None):
+            return
+        lm = node.ledger_master
+        if txq.open_has_room(lm):
+            return
+        t0 = time.perf_counter()
+        deadline = t0 + SUBMIT_HOLD_S
+        while time.perf_counter() < deadline:
+            await asyncio.sleep(SUBMIT_HOLD_POLL_S)
+            if txq.open_has_room(lm):
+                break
+        t1 = time.perf_counter()
+        self.submit_holds += 1
+        self.submit_hold_s += t1 - t0
+        if self._span_every and self.submit_holds % self._span_every == 0:
+            self.tracer.complete("rpc.submit_hold", "rpc", t0, t1)
 
     def _note_request(self, method, failed: bool, t_read: float,
                       bytes_in: int, bytes_out: int) -> None:
@@ -244,6 +289,8 @@ class HttpRpcServer:
             "lag_s": round(self.lag_s, 6),
             "lag_ticks": self.lag_ticks,
             "lag_late_ticks": self.lag_late_ticks,
+            "submit_holds": self.submit_holds,
+            "submit_hold_s": round(self.submit_hold_s, 6),
             "by_method": dict(self.by_method),
         }
 
