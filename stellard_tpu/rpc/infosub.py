@@ -15,6 +15,11 @@ The publishing thread (in networked mode: the ordered persist worker)
 only ENQUEUES — one wedged websocket can never stall publish for the
 other 10k subscribers. shards=0 is the legacy inline path (tests that
 want synchronous delivery construct the manager that way).
+
+Publication is driven by what the registered subscribers listen to
+(`_Interest`, rebuilt from the registry when it has changed): nothing
+about a transaction is parsed, rendered or looked up until that summary
+says somebody receives it, and then its message is built once.
 """
 
 from __future__ import annotations
@@ -191,6 +196,71 @@ class _FanoutShard:
         self.thread.join(timeout=5)
 
 
+_STREAMS = ("ledger", "server", "transactions", "transactions_proposed",
+            "rt_transactions")
+
+
+class _Interest:
+    """What the subscribers registered at one version of the registry
+    listen to, as far as transaction messages go: who takes every
+    validated transaction, who takes every proposed one, and who
+    listens to which account (the reference keeps its account
+    subscriptions by account too: NetworkOPsImp's mSubAccount /
+    mSubRTAccount). Built under the registry's lock and never changed;
+    a publisher holds one until `SubscriptionManager._version` moves."""
+
+    __slots__ = ("version", "listeners", "validated", "proposed",
+                 "accounts", "accounts_proposed", "validated_maps",
+                 "wants_validated", "wants_proposed", "_rank")
+
+    def __init__(self, version: int, subs) -> None:
+        self.version = version
+        self.listeners = len(subs)
+        self.validated: list[InfoSub] = []
+        self.proposed: list[InfoSub] = []
+        self.accounts: dict[bytes, list[InfoSub]] = {}
+        self.accounts_proposed: dict[bytes, list[InfoSub]] = {}
+        for sub in subs:
+            streams = sub.streams
+            if "transactions" in streams:
+                self.validated.append(sub)
+            if ("transactions_proposed" in streams
+                    or "rt_transactions" in streams):
+                self.proposed.append(sub)
+            for account in sub.accounts:
+                self.accounts.setdefault(account, []).append(sub)
+            for account in sub.accounts_proposed:
+                self.accounts_proposed.setdefault(account, []).append(sub)
+        # an `accounts_proposed` listener receives validated
+        # transactions too (reference: pubAccountTransaction walks
+        # both maps for an accepted transaction)
+        self.validated_maps = tuple(
+            m for m in (self.accounts, self.accounts_proposed) if m)
+        self.wants_validated = bool(self.validated or self.validated_maps)
+        self.wants_proposed = bool(self.proposed or self.accounts_proposed)
+        # registry order, so that a message reaches several subscribers
+        # in the order the per-subscriber loop it replaces gave (asked
+        # only where an account listener is hit)
+        self._rank: dict[int, int] = (
+            {sub.id: rank for rank, sub in enumerate(subs)}
+            if self.validated_maps else {})
+
+    def recipients(self, every: list, maps, touched) -> list:
+        """`every` plus the listeners of the `touched` accounts in
+        `maps`, each subscriber once, in registry order."""
+        hits: dict[int, InfoSub] = {}
+        for by_account in maps:
+            for account in touched:
+                for sub in by_account.get(account, ()):
+                    hits[sub.id] = sub
+        if not hits:
+            return every
+        for sub in every:
+            hits[sub.id] = sub
+        rank = self._rank
+        return sorted(hits.values(), key=lambda sub: rank[sub.id])
+
+
 class SubscriptionManager:
     """Fan-out hub wired into NetworkOPs' close/tx hooks."""
 
@@ -210,6 +280,13 @@ class SubscriptionManager:
         self.push_retries = int(push_retries)
         self._lock = threading.Lock()
         self._subs: dict[int, InfoSub] = {}
+        # every change to `_subs` or to a subscriber's `streams`,
+        # `accounts`, `accounts_proposed` is made under `_lock` and
+        # moves `_version`; the publishers rebuild their summary of the
+        # registry (`_interest()`) when it has moved, so it cannot be
+        # stale by more than the change that is being made
+        self._version = 0
+        self._interest_at = _Interest(0, ())
         # url -> RpcSub (reference: NetworkOPs mRpcSubMap): HTTP-callback
         # subscriptions outlive any one request; found/created by
         # `subscribe` with a url (admin-only)
@@ -222,6 +299,11 @@ class SubscriptionManager:
             "slow_evicted": 0, "dead_evicted": 0,
             "resumed": 0, "resume_replayed": 0, "resume_cold": 0,
             "dup_suppressed": 0,
+            # how often the interest summary engages: transactions of
+            # closed ledgers / submitted transactions the publishers
+            # were handed, and the messages they built for them
+            "tx_considered": 0, "tx_built": 0,
+            "proposed_considered": 0, "proposed_built": 0,
         }
         # resume-from-seq replay ring (reconnect-storm hardening): the
         # last `resume_horizon` ledgerClosed events, so a dropped client
@@ -280,6 +362,7 @@ class SubscriptionManager:
                 return
             self.rpc_subs.pop(getattr(sub, "url", None), None)
             self._subs.pop(sub.id, None)
+            self._version += 1
         close = getattr(sub, "close", None)
         if close is not None:
             close()
@@ -289,34 +372,36 @@ class SubscriptionManager:
     def add(self, sub: InfoSub) -> None:
         with self._lock:
             self._subs[sub.id] = sub
+            self._version += 1
 
     def remove(self, sub_id: int) -> None:
         with self._lock:
             self._subs.pop(sub_id, None)
+            self._version += 1
 
     def subscribe_streams(self, sub: InfoSub, streams: list[str]) -> dict:
         """Returns the initial result payload (ledger stream returns the
         current state snapshot, reference Subscribe.cpp:86-112)."""
-        result: dict = {}
-        for stream in streams:
-            if stream not in ("ledger", "server", "transactions",
-                              "transactions_proposed", "rt_transactions"):
-                continue
-            sub.streams.add(stream)
-            if stream == "ledger":
-                result.update(self._ledger_snapshot())
-        self.add(sub)
+        wanted = [stream for stream in streams if stream in _STREAMS]
+        result = self._ledger_snapshot() if "ledger" in wanted else {}
+        with self._lock:
+            sub.streams.update(wanted)
+            self._subs[sub.id] = sub
+            self._version += 1
         return result
 
     def unsubscribe_streams(self, sub: InfoSub, streams: list[str]) -> None:
-        for stream in streams:
-            sub.streams.discard(stream)
+        with self._lock:
+            sub.streams.difference_update(streams)
+            self._version += 1
 
     def subscribe_accounts(self, sub: InfoSub, accounts: list[bytes],
                            proposed: bool = False) -> None:
-        target = sub.accounts_proposed if proposed else sub.accounts
-        target.update(accounts)
-        self.add(sub)
+        with self._lock:
+            target = sub.accounts_proposed if proposed else sub.accounts
+            target.update(accounts)
+            self._subs[sub.id] = sub
+            self._version += 1
 
     # -- path-find subscriptions (reference: PathRequests) ----------------
 
@@ -412,8 +497,10 @@ class SubscriptionManager:
 
     def unsubscribe_accounts(self, sub: InfoSub, accounts: list[bytes],
                              proposed: bool = False) -> None:
-        target = sub.accounts_proposed if proposed else sub.accounts
-        target.difference_update(accounts)
+        with self._lock:
+            target = sub.accounts_proposed if proposed else sub.accounts
+            target.difference_update(accounts)
+            self._version += 1
 
     def _ledger_snapshot(self) -> dict:
         lcl = self.ops.lm.closed_ledger()
@@ -433,9 +520,37 @@ class SubscriptionManager:
         with self._lock:
             return list(self._subs.values())
 
+    def _interest(self) -> _Interest:
+        """The summary of what the registry's subscribers listen to,
+        rebuilt if the registry has changed since it was built."""
+        interest = self._interest_at
+        if interest.version != self._version:
+            with self._lock:
+                interest = _Interest(self._version, self._subs.values())
+                self._interest_at = interest
+        return interest
+
     def _pub_ledger(self, ledger: Ledger, results: dict) -> None:
         """reference: NetworkOPs::pubLedger — ledgerClosed stream msg,
-        then per-tx accepted messages."""
+        then per-tx accepted messages.
+
+        The transaction pass asks the interest summary first: with no
+        `transactions` subscriber and nobody listening to an account the
+        ledger's transactions are not even walked; with account
+        listeners only, a transaction is parsed for the accounts it
+        touches and rendered only if one of them is listened to; a
+        message is built once and handed to everyone who takes it.
+
+        The summary is read when the pass begins, behind the
+        `ledgerClosed` delivery, and again before any transaction by
+        which the registry has changed (`txs` on the span is counted by
+        the walk where there is one, and is the close's own count, the
+        `txn_count` of `ledgerClosed`, where there is none). So a subscriber that registers
+        while a ledger's transactions are being published receives the
+        remaining ones, as it always has, and one that leaves receives
+        no more; where nobody was listening when the pass began there
+        is no pass to join, and a newcomer starts with the next ledger."""
+        t0 = time.perf_counter()
         msg = {
             "type": "ledgerClosed",
             "ledger_index": ledger.seq,
@@ -454,10 +569,20 @@ class SubscriptionManager:
             if "ledger" in sub.streams:
                 self._deliver_ledger(sub, msg)
         # accepted transactions (reference: pubAcceptedTransaction)
-        for txid, blob, meta in ledger.tx_entries():
-            tx = ledger.parse_tx(txid, blob)
-            ter = results.get(txid, TER.tesSUCCESS)
-            self._pub_tx(tx, ter, ledger=ledger, validated=True, meta=meta)
+        interest = self._interest()
+        txs, built, delivered = len(results), 0, 0
+        if interest.wants_validated:
+            txs, built, delivered = self._pub_accepted(
+                ledger, results, msg["ledger_hash"], interest)
+        with self._stats_lock:
+            self.stats["tx_considered"] += txs
+            self.stats["tx_built"] += built
+        if self.tracer is not None:
+            self.tracer.complete(
+                "subs.publish", "publish", t0, time.perf_counter(),
+                seq=ledger.seq, txs=txs, built=built, delivered=delivered,
+                listeners=interest.listeners,
+            )
         # live path-find subscriptions re-search against the new state on
         # a jtUPDATE_PF job (reference: PathRequests::updateAll) — NOT on
         # this thread, which in networked mode is the ordered persist
@@ -487,68 +612,74 @@ class SubscriptionManager:
             if "server" in sub.streams:
                 self._deliver(sub, msg)
 
+    def _pub_accepted(self, ledger: Ledger, results: dict, ledger_hash: str,
+                      interest: _Interest) -> tuple[int, int, int]:
+        """A closed ledger's transaction messages, to whoever takes
+        them -> (transactions walked, messages built, messages handed
+        to `_deliver`)."""
+        from ..protocol.meta import affected_accounts
+        from ..protocol.stobject import STObject
+
+        tracer = self.tracer
+        txs = built = delivered = 0
+        for txid, blob, meta in ledger.tx_entries():
+            txs += 1
+            if interest.version != self._version:
+                interest = self._interest()
+            if not interest.wants_validated:
+                continue  # the last listener left during the pass
+            tx = ledger.parse_tx(txid, blob)
+            meta_obj = STObject.from_bytes(meta) if meta else None
+            subs = interest.validated
+            if interest.validated_maps:
+                # accounts touched: from the metadata (covers crossed
+                # offers, trust-line counterparties, issuers — reference
+                # getAffectedAccounts) beside Account/Destination
+                touched = _tx_accounts(tx)
+                if meta_obj is not None:
+                    touched.update(affected_accounts(meta_obj))
+                subs = interest.recipients(
+                    subs, interest.validated_maps, touched)
+            if not subs:
+                continue
+            tx_msg = _tx_message(
+                tx, results.get(txid, TER.tesSUCCESS), True,
+                ledger.seq, ledger_hash, meta_obj)
+            built += 1
+            if tracer is not None and tracer.enabled:
+                # per-sampled-tx fanout leaf: the publish stage of the
+                # tx's cross-node causal tree, recorded where the
+                # transaction is published to somebody (subs.fanout
+                # spans stay the sampled per-subscriber delivery
+                # evidence)
+                tracer.instant("subs.fanout.tx", "publish", txid=txid,
+                               ledger_seq=ledger.seq)
+            for sub in subs:
+                self._deliver(sub, tx_msg)
+            delivered += len(subs)
+        return txs, built, delivered
+
     def _pub_proposed(self, tx: SerializedTransaction, ter: TER) -> None:
-        self._pub_tx(tx, ter, ledger=None, validated=False)
-
-    def _pub_tx(self, tx: SerializedTransaction, ter: TER,
-                ledger: Optional[Ledger], validated: bool,
-                meta: bytes = b"") -> None:
-        msg = {
-            "type": "transaction",
-            "transaction": _tx_json_with_hash(tx),
-            "status": "closed" if validated else "proposed",
-            "engine_result": ter.token,
-            "engine_result_code": int(ter),
-            "engine_result_message": ter.human,
-            "validated": validated,
-        }
-        if ledger is not None:
-            msg["ledger_index"] = ledger.seq
-            msg["ledger_hash"] = ledger.hash().hex().upper()
-        if meta:
-            from ..protocol.stobject import STObject
-
-            msg["meta"] = STObject.from_bytes(meta).to_json()
-
-        # accounts touched: from the metadata when we have it (covers
-        # crossed offers, trust-line counterparties, issuers — reference
-        # getAffectedAccounts); fall back to Account/Destination for
-        # proposed txns that carry no meta yet
-        touched = {tx.account}
-        from ..protocol.sfields import sfDestination
-
-        dest = tx.obj.get(sfDestination)
-        if dest:
-            touched.add(dest)
-        if meta:
-            from ..protocol.meta import affected_accounts
-
-            touched.update(affected_accounts(meta))
-
-        if self.tracer is not None and validated and self.tracer.enabled:
-            # per-sampled-tx fanout leaf: the publish stage of the tx's
-            # cross-node causal tree (subs.fanout spans stay the sampled
-            # per-subscriber delivery evidence)
-            self.tracer.instant(
-                "subs.fanout.tx", "publish", txid=tx.txid(),
-                ledger_seq=msg.get("ledger_index"),
-            )
-
-        for sub in self._each():
-            wants = False
-            if validated and "transactions" in sub.streams:
-                wants = True
-            if not validated and (
-                "transactions_proposed" in sub.streams
-                or "rt_transactions" in sub.streams
-            ):
-                wants = True
-            if sub.accounts & touched and validated:
-                wants = True
-            if sub.accounts_proposed & touched:
-                wants = True
-            if wants:
-                self._deliver(sub, msg)
+        """A submitted transaction, on the intake's thread, to the
+        `transactions_proposed` / `rt_transactions` subscribers and to
+        whoever listens with `accounts_proposed` to an account it names
+        (Account/Destination: it carries no metadata yet); nothing is
+        rendered where nobody does."""
+        interest = self._interest()
+        built = 0
+        if interest.wants_proposed:
+            subs = interest.proposed
+            if interest.accounts_proposed:
+                subs = interest.recipients(
+                    subs, (interest.accounts_proposed,), _tx_accounts(tx))
+            if subs:
+                msg = _tx_message(tx, ter, False)
+                built = 1
+                for sub in subs:
+                    self._deliver(sub, msg)
+        with self._stats_lock:
+            self.stats["proposed_considered"] += 1
+            self.stats["proposed_built"] += built
 
     def _bump(self, key: str, n: int = 1) -> None:
         with self._stats_lock:
@@ -620,8 +751,10 @@ class SubscriptionManager:
                 sub.last_seq = seq
                 self._deliver(sub, msg)
                 replayed += 1
-            sub.streams.add("ledger")
-            self.add(sub)
+            with self._lock:
+                sub.streams.add("ledger")
+                self._subs[sub.id] = sub
+                self._version += 1
         self._bump("resumed")
         self._bump("resume_replayed", replayed)
         return {
@@ -650,6 +783,7 @@ class SubscriptionManager:
             sub._evict_done = True
             sub.evicted = True
             self._subs.pop(sub.id, None)
+            self._version += 1
             url = getattr(sub, "url", None)
             if url is not None and self.rpc_subs.get(url) is sub:
                 del self.rpc_subs[url]
@@ -717,3 +851,37 @@ def _tx_json_with_hash(tx: SerializedTransaction) -> dict:
     j = tx.obj.to_json()
     j["hash"] = tx.txid().hex().upper()
     return j
+
+
+def _tx_accounts(tx: SerializedTransaction) -> set:
+    """The accounts a transaction names itself: Account, and
+    Destination where it has one."""
+    from ..protocol.sfields import sfDestination
+
+    touched = {tx.account}
+    dest = tx.obj.get(sfDestination)
+    if dest:
+        touched.add(dest)
+    return touched
+
+
+def _tx_message(tx: SerializedTransaction, ter: TER, validated: bool,
+                ledger_seq: Optional[int] = None, ledger_hash: str = "",
+                meta=None) -> dict:
+    """The `transaction` stream message (reference:
+    NetworkOPs::transJson); `meta` is the parsed metadata."""
+    msg = {
+        "type": "transaction",
+        "transaction": _tx_json_with_hash(tx),
+        "status": "closed" if validated else "proposed",
+        "engine_result": ter.token,
+        "engine_result_code": int(ter),
+        "engine_result_message": ter.human,
+        "validated": validated,
+    }
+    if ledger_seq is not None:
+        msg["ledger_index"] = ledger_seq
+        msg["ledger_hash"] = ledger_hash
+    if meta is not None:
+        msg["meta"] = meta.to_json()
+    return msg
