@@ -232,8 +232,13 @@ class CpuVerifier(BatchVerifier):
     def __init__(self, threads: int = 4):
         if threads > 1:
             if CpuVerifier._shared_pool is None:
+                # the pool lives as long as the process: its threads
+                # enter the intake's role and never leave
+                from ..node.tracer import THREAD_ROLES
+
                 CpuVerifier._shared_pool = ThreadPoolExecutor(
-                    max_workers=threads, thread_name_prefix="cpu-verify"
+                    max_workers=threads, thread_name_prefix="cpu-verify",
+                    initializer=THREAD_ROLES.enter, initargs=("intake",),
                 )
             self._pool = CpuVerifier._shared_pool
         else:

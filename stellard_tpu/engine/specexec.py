@@ -75,7 +75,7 @@ from collections import deque
 from typing import Optional
 
 from ..node.metrics import AtomicCounters
-from ..node.tracer import get_tracer
+from ..node.tracer import THREAD_ROLES, get_tracer
 from ..protocol.sfields import sfTransactionIndex
 from ..protocol.stobject import STObject
 from ..protocol.sttx import SerializedTransaction
@@ -629,15 +629,16 @@ class SpecExecutor:
                 os.set_blocking(self._wake_w, False)
                 self._start_procs()
                 self._committer = threading.Thread(
-                    target=self._committer_loop, name="spec-committer",
-                    daemon=True,
+                    target=THREAD_ROLES.wrap("intake", self._committer_loop),
+                    name="spec-committer", daemon=True,
                 )
                 self._committer.start()
             elif self.mode == "thread":
                 for i in range(self.workers):
                     t = threading.Thread(
-                        target=self._thread_worker_loop, args=(i,),
-                        name=f"spec-worker-{i}", daemon=True,
+                        target=THREAD_ROLES.wrap(
+                            "intake", self._thread_worker_loop),
+                        args=(i,), name=f"spec-worker-{i}", daemon=True,
                     )
                     t.start()
                     self._threads.append(t)

@@ -37,6 +37,7 @@ from typing import Callable, Optional
 
 from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
+from .tracer import THREAD_ROLES
 
 log = logging.getLogger("stellard.closepipeline")
 
@@ -114,7 +115,8 @@ class ClosePipeline:
         """Start the drain worker on first use; caller holds self._lock."""
         if self._thread is None:
             self._thread = threading.Thread(
-                target=self._drain, name=self._name, daemon=True
+                target=THREAD_ROLES.wrap("drain", self._drain),
+                name=self._name, daemon=True
             )
             self._thread.start()
 
@@ -321,6 +323,11 @@ class ClosePipeline:
         t_start = time.perf_counter()
         seq = entry.ledger.seq
         tr = self.tracer
+        # the drain thread's CPU clock beside every reading of the wall
+        # clock: a stage's cpu_us against its dur is what it ran of what
+        # it took (the rest: I/O, or waiting for the interpreter)
+        cpu = tr.thread_cpu
+        c_start = cpu()
         tr.complete("persist.queue_wait", "persist", entry.enqueued_at,
                     t_start, seq=seq)
         results = entry.results
@@ -332,19 +339,26 @@ class ClosePipeline:
             entry.results = results
 
         t0 = time.perf_counter()
+        c0 = cpu()
         self.save_stage(entry.ledger)
         t1 = time.perf_counter()
-        tr.complete("persist.nodestore", "persist", t0, t1, seq=seq)
+        c1 = cpu()
+        tr.complete("persist.nodestore", "persist", t0, t1, seq=seq,
+                    cpu_s=tr.cpu_since(c0, c1))
         wrote = self._sql_wrote(
             "txdb", self.txdb_stage(entry.ledger, results))
         t2 = time.perf_counter()
-        tr.complete("persist.txdb", "persist", t1, t2, seq=seq, **wrote)
+        c2 = cpu()
+        tr.complete("persist.txdb", "persist", t1, t2, seq=seq,
+                    cpu_s=tr.cpu_since(c1, c2), **wrote)
         if entry.kind == "close":
             wrote = self._sql_wrote("clf", self.clf_stage(entry.ledger))
             t3 = time.perf_counter()
-            tr.complete("persist.clf", "persist", t2, t3, seq=seq, **wrote)
+            tr.complete("persist.clf", "persist", t2, t3, seq=seq,
+                        cpu_s=tr.cpu_since(c2), **wrote)
         t_end = time.perf_counter()
         tr.complete("persist.total", "persist", t_start, t_end, seq=seq,
+                    cpu_s=tr.cpu_since(c_start),
                     kind=entry.kind, txs=len(results or ()))
         # per-tx persist marks close out each SAMPLED transaction's
         # causal tree (submit → verify → apply → close → persist); runs

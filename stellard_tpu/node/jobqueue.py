@@ -22,6 +22,8 @@ from dataclasses import dataclass, field
 from enum import IntEnum
 from typing import Callable, Optional
 
+from .tracer import THREAD_ROLES
+
 __all__ = ["JobType", "JobQueue", "Job", "JOB_LIMITS"]
 
 
@@ -256,8 +258,8 @@ class JobQueue:
     def set_thread_count(self, n: int) -> None:
         while len(self._threads) < n:
             t = threading.Thread(
-                target=self._worker, name=f"{self._name}-{len(self._threads)}",
-                daemon=True,
+                target=THREAD_ROLES.wrap("intake", self._worker),
+                name=f"{self._name}-{len(self._threads)}", daemon=True,
             )
             t.start()
             self._threads.append(t)

@@ -32,6 +32,8 @@ import threading
 import time
 from typing import Optional
 
+from .tracer import THREAD_ROLES
+
 __all__ = ["LedgerCleaner", "OnlineDeleter"]
 
 
@@ -68,7 +70,8 @@ class LedgerCleaner:
             self.repairs_failed = 0
             self._stop.clear()
             self._thread = threading.Thread(
-                target=self._run, name="ledger-cleaner", daemon=True
+                target=THREAD_ROLES.wrap("upkeep", self._run),
+                name="ledger-cleaner", daemon=True
             )
             self._thread.start()
         return {"status": "started", "min_ledger": lo, "max_ledger": hi}
@@ -280,8 +283,8 @@ class OnlineDeleter:
             self._last_sweep_seq = seq
             self.sweeps_started += 1
             self._thread = threading.Thread(
-                target=self._run, args=(seq,), daemon=True,
-                name="online-delete",
+                target=THREAD_ROLES.wrap("upkeep", self._run), args=(seq,),
+                daemon=True, name="online-delete",
             )
             self._thread.start()
 

@@ -24,7 +24,7 @@ from ..protocol.ter import TER
 from ..state.ledger import Ledger
 from ..state.shamap import inner_node_cache
 from .metrics import AtomicCounters
-from .tracer import get_tracer
+from .tracer import ROLES, THREAD_ROLES, get_tracer
 
 __all__ = ["LedgerMaster", "CanonicalTXSet", "LEDGER_TOTAL_PASSES"]
 
@@ -152,6 +152,9 @@ class LedgerMaster:
         # last close ended: `close.total` carries the differences
         cache = inner_node_cache()
         self._cache_marks = (cache.faults, cache.fault_s, cache.evictions)
+        # THREAD_ROLES.marks() at the end of the last traced close: the
+        # close cycle's wall and CPU seconds are differences against it
+        self._cycle_marks: Optional[tuple] = None
         # parallel speculative executor ([spec] workers=N, engine/
         # specexec.py): when active, _speculate_open dispatches to the
         # worker pool instead of executing inline, and the close drains
@@ -410,8 +413,8 @@ class LedgerMaster:
         """Lazily start the seal-drain thread; caller holds _drain_cv."""
         if self._drainer is None and not self._drain_stop:
             self._drainer = threading.Thread(
-                target=self._drain_loop, name="seal-drain",
-                daemon=True,
+                target=THREAD_ROLES.wrap("seal", self._drain_loop),
+                name="seal-drain", daemon=True,
             )
             self._drainer.start()
 
@@ -605,13 +608,18 @@ class LedgerMaster:
         pending = [2]
         plock = threading.Lock()
 
+        traced = self.tracer.enabled
+
         def _arm(get_hash):
             def run():
+                if traced:  # two threads a close: no clock read untraced
+                    THREAD_ROLES.enter("seal")
                 try:
                     get_hash()
                 except Exception:  # noqa: BLE001 — recomputed on push
                     pass
                 finally:
+                    THREAD_ROLES.leave()
                     with plock:
                         pending[0] -= 1
                         if pending[0] == 0:
@@ -675,6 +683,7 @@ class LedgerMaster:
         """
         with self._lock:
             t0 = time.perf_counter()
+            c0 = self.tracer.thread_cpu()
             prev = self.closed_ledger()
             open_ledger = self.current_ledger()
 
@@ -698,6 +707,7 @@ class LedgerMaster:
             self._drain_spec(spec)
             results = self._apply_transactions(new_lcl, txset, spec=spec)
             t_apply = time.perf_counter()
+            c_apply = self.tracer.thread_cpu()
 
             # 3. seal + advance
             new_lcl.close(close_time, close_resolution, correct_close_time)
@@ -710,6 +720,7 @@ class LedgerMaster:
             # helper thread while the persist rows materialize here
             self._seal(new_lcl, results)
             t_seal = time.perf_counter()
+            c_seal = self.tracer.thread_cpu()
             self._push_closed(new_lcl)
             self._open_next(new_lcl, (t_apply - t0) * 1000.0)
 
@@ -720,7 +731,8 @@ class LedgerMaster:
                 if self.on_validated:
                     self.on_validated(new_lcl)
 
-            self._note_close_stages(t0, t_apply, t_seal, new_lcl.seq)
+            self._note_close_stages(t0, t_apply, t_seal, new_lcl.seq,
+                                    (c0, c_apply, c_seal))
             return new_lcl, results
 
     def close_with_txset(
@@ -737,6 +749,7 @@ class LedgerMaster:
         (reference: reapply of local/disputed txns :1050-1127)."""
         with self._lock:
             t0 = time.perf_counter()
+            c0 = self.tracer.thread_cpu()
             prev = self.closed_ledger()
             open_ledger = self.current_ledger()
 
@@ -752,6 +765,7 @@ class LedgerMaster:
             self._drain_spec(spec)
             results = self._apply_transactions(new_lcl, txset, spec=spec)
             t_apply = time.perf_counter()
+            c_apply = self.tracer.thread_cpu()
 
             new_lcl.close(close_time, close_resolution, correct_close_time)
             new_lcl.accepted = True
@@ -759,6 +773,7 @@ class LedgerMaster:
                 new_lcl.parsed_txs[tx.txid()] = tx
             self._seal(new_lcl, results)
             t_seal = time.perf_counter()
+            c_seal = self.tracer.thread_cpu()
             self._push_closed(new_lcl)
 
             # re-apply: our open-ledger txns that missed consensus first
@@ -778,7 +793,8 @@ class LedgerMaster:
             leftovers.sort(key=lambda tx: (tx.account, tx.sequence))
             self._open_next(new_lcl, (t_apply - t0) * 1000.0,
                             leftovers=leftovers)
-            self._note_close_stages(t0, t_apply, t_seal, new_lcl.seq)
+            self._note_close_stages(t0, t_apply, t_seal, new_lcl.seq,
+                                    (c0, c_apply, c_seal))
             return new_lcl, results
 
     def _open_next(self, new_lcl: Ledger, apply_ms: float,
@@ -1104,8 +1120,13 @@ class LedgerMaster:
         self.last_close.update(c)
 
     def _note_close_stages(self, t0: float, t_apply: float,
-                           t_seal: float, seq: int) -> None:
+                           t_seal: float, seq: int,
+                           cpu: tuple = (None, None, None)) -> None:
         now = time.perf_counter()
+        c0, c_apply, c_seal = cpu
+        # None: no thread clock was read (the tracer is disabled), and
+        # the spans carry no cpu_us
+        cpu_total = self.tracer.cpu_since(c0)
         stages = {
             "apply_ms": round((t_apply - t0) * 1000.0, 3),
             "seal_ms": round((t_seal - t_apply) * 1000.0, 3),
@@ -1113,8 +1134,11 @@ class LedgerMaster:
         }
         self.last_close.update(stages)
         tr = self.tracer
-        tr.complete("close.apply", "close", t0, t_apply, seq=seq)
-        tr.complete("close.seal", "close", t_apply, t_seal, seq=seq)
+        tr.complete("close.apply", "close", t0, t_apply, seq=seq,
+                    cpu_s=tr.cpu_since(c0, c_apply))
+        tr.complete("close.seal", "close", t_apply, t_seal, seq=seq,
+                    cpu_s=tr.cpu_since(c_apply, c_seal))
+        cycle = {} if cpu_total is None else self._note_cycle(cpu_total)
         # what the hot-node cache did over this close CYCLE (since the
         # last close ended: the open window's faults are the cycle's),
         # as `replay.span` carries its `evict_scan_s`; over a run the
@@ -1123,10 +1147,39 @@ class LedgerMaster:
         marks = (cache.faults, cache.fault_s, cache.evictions)
         was, self._cache_marks = self._cache_marks, marks
         tr.complete("close.total", "close", t0, now, seq=seq,
+                    cpu_s=cpu_total,
                     faults=marks[0] - was[0],
                     fault_s=round(marks[1] - was[1], 6),
                     evictions=marks[2] - was[2],
-                    resident_bytes=cache.resident_bytes)
+                    resident_bytes=cache.resident_bytes, **cycle)
+
+    def _note_cycle(self, cpu_close_s: float) -> dict:
+        """Who had the interpreter over this close CYCLE (since the last
+        close ended): ``cycle_s`` of wall, ``process_cpu_s``, and the
+        CPU seconds of the node's threads by role, ``cpu_<role>_s``, as
+        differences of ``THREAD_ROLES`` (a dozen clock reads, one a
+        thread). ``cpu_other_s`` is the process's clock less every role
+        and less this close, unless the closing thread is itself in a
+        role (``closer``: a networked node closes on a `net` thread),
+        where the close is a part of that role's seconds already. The
+        first traced close only sets the marks."""
+        marks = THREAD_ROLES.marks()
+        was, self._cycle_marks = self._cycle_marks, marks
+        if was is None:
+            return {}
+        out = {"cycle_s": round(marks[0] - was[0], 6),
+               "process_cpu_s": round(marks[2] - was[2], 6)}
+        other = marks[2] - was[2]
+        for role, now_s, was_s in zip(ROLES, marks[1], was[1]):
+            out[f"cpu_{role}_s"] = round(now_s - was_s, 6)
+            other -= now_s - was_s
+        closer = THREAD_ROLES.role_of(threading.get_ident())
+        if closer is None:
+            other -= cpu_close_s
+        else:
+            out["closer"] = closer
+        out["cpu_other_s"] = round(other, 6)
+        return out
 
     def delta_replay_json(self) -> dict:
         """spliced/fallback/invalidation counters + close-stage latency

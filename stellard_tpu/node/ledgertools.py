@@ -158,10 +158,14 @@ def _reverify_memoized(txs: list, verify_many: Callable) -> None:
 def _runtime_marks() -> tuple:
     """What the interpreter's collector and the hot-node cache's victim
     scans have cost so far: a replay span carries the difference over
-    its own length (``gc_pause_s``, ``evict_scan_s``)."""
+    its own length (``gc_pause_s``, ``evict_scan_s``), and what the
+    replaying thread and the whole process ran of it (``cpu_s``,
+    ``process_cpu_s``: the rest of the span the thread waited, on the
+    chip, the disk or the interpreter's lock)."""
     from ..state.shamap import inner_node_cache
 
-    return GC_PROBE.pause_total_s(), inner_node_cache().evict_scan_s
+    return (GC_PROBE.pause_total_s(), inner_node_cache().evict_scan_s,
+            time.thread_time(), time.process_time())
 
 
 def replay_ledger(
@@ -311,9 +315,10 @@ def replay_range(
     ``ledger.load`` under it where it is the store). It ends with the
     ledgers and transactions it covered, how many took their parent
     from the chain (``chained``; the result carries it too), the eager
-    loads it made (``state_loads``), and what the collector
+    loads it made (``state_loads``), what the collector
     (``gc_pause_s``) and the hot cache's victim scans
-    (``evict_scan_s``) took of it."""
+    (``evict_scan_s``) took of it, and what the replaying thread
+    (``cpu_s``) and the process (``process_cpu_s``) ran of it."""
     tr = tracer if tracer is not None else get_tracer()
     probed = GC_PROBE.install(tr)
     HEAP_AGING.acquire()
@@ -323,13 +328,15 @@ def replay_range(
             out = _replay_range(db, ledger_hashes, hash_batch, verify_many,
                                 tr)
             if span is not None:
-                gc_s, scan_s = _runtime_marks()
+                gc_s, scan_s, cpu_s, proc_s = _runtime_marks()
                 span.attrs = {
                     "ledgers": out["ledger_count"], "txs": out["tx_count"],
                     "chained": out["chained"],
                     "state_loads": out["ledger_count"] - out["chained"],
                     "gc_pause_s": round(gc_s - marks[0], 6),
                     "evict_scan_s": round(scan_s - marks[1], 6),
+                    "cpu_s": round(cpu_s - marks[2], 6),
+                    "process_cpu_s": round(proc_s - marks[3], 6),
                 }
             return out
     finally:

@@ -21,6 +21,8 @@ import threading
 import time
 from typing import Callable, Optional
 
+from .tracer import THREAD_ROLES
+
 __all__ = ["LoadFeeTrack", "LoadManager", "TX_BACKLOG_SHED"]
 
 NORMAL_FEE = 256  # lftNormalFee: multiplier denominator ("no escalation")
@@ -283,7 +285,8 @@ class LoadManager:
 
     def start(self) -> "LoadManager":
         self._thread = threading.Thread(
-            target=self._run, name="load-manager", daemon=True
+            target=THREAD_ROLES.wrap("upkeep", self._run),
+            name="load-manager", daemon=True
         )
         self._thread.start()
         return self

@@ -502,8 +502,11 @@ class CollectorManager:
         return lines
 
     def start(self) -> "CollectorManager":
+        from .tracer import THREAD_ROLES  # tracer imports this module
+
         self._thread = threading.Thread(
-            target=self._run, name="insight", daemon=True
+            target=THREAD_ROLES.wrap("upkeep", self._run), name="insight",
+            daemon=True
         )
         self._thread.start()
         return self

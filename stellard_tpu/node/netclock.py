@@ -19,6 +19,8 @@ import threading
 import time
 from typing import Optional
 
+from .tracer import THREAD_ROLES
+
 __all__ = ["SntpClient", "NTP_EPOCH_DELTA"]
 
 NTP_EPOCH_DELTA = 2208988800  # 1900-01-01 -> 1970-01-01
@@ -109,7 +111,8 @@ class SntpClient:
 
     def start(self) -> "SntpClient":
         self._thread = threading.Thread(
-            target=self._run, name="sntp", daemon=True
+            target=THREAD_ROLES.wrap("upkeep", self._run), name="sntp",
+            daemon=True
         )
         self._thread.start()
         return self

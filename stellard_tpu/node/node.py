@@ -415,8 +415,11 @@ class Node:
                     except Exception:  # noqa: BLE001 — log-and-skip source
                         pass
 
+                from .tracer import THREAD_ROLES
+
                 threading.Thread(
-                    target=fetch_site, name="validators-site", daemon=True
+                    target=THREAD_ROLES.wrap("upkeep", fetch_site),
+                    name="validators-site", daemon=True,
                 ).start()
         self.pow_factory = PowFactory()
         self.ledger_cleaner = LedgerCleaner(self)
@@ -1271,7 +1274,7 @@ class Node:
         # (`gc.gen2_pause_s`, `rpc.busy_s`, `rpc.lag_s`,
         # `state_cache.evict_scan_s`, ... on /metrics)
         from ..state.shamap import inner_node_cache
-        from .tracer import GC_PROBE
+        from .tracer import GC_PROBE, THREAD_ROLES
 
         def _flat(get_json):
             return lambda: {
@@ -1280,6 +1283,7 @@ class Node:
             }
 
         self.collector.hook("gc", _flat(GC_PROBE.get_json))
+        self.collector.hook("threads", THREAD_ROLES.flat_json)
         self.collector.hook("state_cache", _flat(inner_node_cache().get_json))
         if self.http_server is not None:
             self.collector.hook("rpc", _flat(self.http_server.get_json))
@@ -1327,6 +1331,9 @@ class Node:
         self.load_manager.arm()
         last_beat = 0.0
         last_sweep = 0.0
+        from .tracer import THREAD_ROLES
+
+        THREAD_ROLES.enter("net")
         try:
             self._run_loop(last_beat, last_sweep)
         except BaseException:
@@ -1337,6 +1344,8 @@ class Node:
             except Exception:  # noqa: BLE001 — dump must not mask the crash
                 pass
             raise
+        finally:
+            THREAD_ROLES.leave()
 
     def _run_loop(self, last_beat: float, last_sweep: float) -> None:
         import time as _time

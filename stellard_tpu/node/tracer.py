@@ -64,6 +64,22 @@ The interpreter's collector is part of the runtime every span runs on:
 nodes and replay tools whose tracer is enabled) counts collections and
 their pauses per generation and records the long ones as ``gc.collect``
 spans in the rings of the tracers that installed it.
+
+Who ran: the host is one interpreter with a dozen threads and one lock,
+so a span's wall clock measures its neighbours too. A span opened with
+``begin()``/``span()`` may also read the opening thread's CPU clock
+(``time.thread_time()``); ``end()`` on the SAME thread exports the
+difference as ``cpu_us``. A span ended on another thread carries none,
+and neither does one that was not clocked: absence means "not known",
+never 0. The clock is a system call (microseconds where the kernel is
+sandboxed), so of a transaction's spans that a thread opens outside any
+other, one in ``Tracer.CPU_EVERY`` is clocked, with everything opened
+beneath it on that thread: a reader sums the clocked spans of a name and
+scales by their share of all. Ledger- and subsystem-scoped spans (a
+handful a close) are always clocked, and ``complete()`` takes ``cpu_s``
+from callers that clock their own stages. ``THREAD_ROLES`` (one registry a
+process) sums the CPU seconds of the node's own threads by role; the
+ledger master puts a close CYCLE's differences on ``close.total``.
 """
 
 from __future__ import annotations
@@ -82,8 +98,8 @@ from typing import Optional
 from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
 
-__all__ = ["Tracer", "SpanToken", "get_tracer", "GC_PROBE",
-           "parse_anchor", "place_on_trace_clock"]
+__all__ = ["Tracer", "SpanToken", "get_tracer", "GC_PROBE", "THREAD_ROLES",
+           "ROLES", "parse_anchor", "place_on_trace_clock"]
 
 # the clock anchor's name in a profiler trace: node tag and the
 # perf_counter reading (ns) taken as the annotation was written
@@ -115,9 +131,10 @@ class SpanToken:
     back to ``end()`` (or as ``parent=`` of a child span)."""
 
     __slots__ = ("name", "cat", "trace", "span_id", "parent", "t0",
-                 "tid", "attrs")
+                 "tid", "attrs", "c0", "cpu_us")
 
-    def __init__(self, name, cat, trace, span_id, parent, t0, tid, attrs):
+    def __init__(self, name, cat, trace, span_id, parent, t0, tid, attrs,
+                 c0=None, cpu_us=None):
         self.name = name
         self.cat = cat
         self.trace = trace
@@ -126,6 +143,10 @@ class SpanToken:
         self.t0 = t0
         self.tid = tid
         self.attrs = attrs
+        # the opening thread's CPU clock at begin(); cpu_us is what that
+        # thread ran until end() (None: ended elsewhere, or never clocked)
+        self.c0 = c0
+        self.cpu_us = cpu_us
 
 
 class _NullSpan:
@@ -179,6 +200,10 @@ def _trace_id(txid, seq) -> Optional[str]:
 
 class Tracer:
     """Lock-light bounded ring-buffer span recorder."""
+
+    # one of a transaction's spans in this many reads the thread's CPU
+    # clock (``_clocked``)
+    CPU_EVERY = 8
 
     def __init__(self, capacity: int = 16384, enabled: bool = True,
                  sample: float = 0.125, propagate: bool = False,
@@ -294,6 +319,51 @@ class Tracer:
     def _now_us(self) -> int:
         return int((time.perf_counter() - self._epoch) * 1e6)
 
+    def _clocked(self, name: str, per_tx: bool = True) -> bool:
+        """Whether the span the calling thread opens now reads its CPU
+        clock. Beneath a ``span()`` it follows that span (a clocked
+        span's same-thread children are all clocked, so that its self
+        CPU can be told). Outside any: a ledger- or subsystem-scoped
+        span always (a handful a close); a transaction's, or a reading
+        ``thread_cpu(one_in_few=...)`` asks for, the thread's first of
+        that name and then every ``CPU_EVERY``-th of it (counted by
+        name: a thread that opens two names in turn clocks both)."""
+        stack = self._stack()
+        if stack:
+            return stack[-1].c0 is not None
+        if not per_tx:
+            return True
+        roots = getattr(self._tls, "roots", None)
+        if roots is None:
+            roots = self._tls.roots = {}
+        n = roots.get(name, 0)
+        roots[name] = n + 1
+        return n % self.CPU_EVERY == 0
+
+    def thread_cpu(self, one_in_few: Optional[str] = None
+                   ) -> Optional[float]:
+        """The calling thread's CPU clock, for a caller of ``complete()``
+        to read beside its ``perf_counter()`` readings; None, and no
+        clock read, with the tracer disabled. ``one_in_few``: the span's
+        name, from a site that runs thousands of times a close
+        (``cache.fault``): a reading only where a span of that name
+        opened here would be clocked."""
+        if not self.enabled or (
+                one_in_few is not None and not self._clocked(one_in_few)):
+            return None
+        return time.thread_time()
+
+    @staticmethod
+    def cpu_since(c0: Optional[float],
+                  c1: Optional[float] = None) -> Optional[float]:
+        """The ``cpu_s`` a caller hands ``complete()``: from one
+        ``thread_cpu()`` reading to a later one of the same thread (now,
+        where none is given); None, and no clock read, where the first
+        was not taken."""
+        if c0 is None:
+            return None
+        return (time.thread_time() if c1 is None else c1) - c0
+
     def _push(self, rec: tuple) -> None:
         with self._lock:
             if self._parked:
@@ -336,6 +406,8 @@ class Tracer:
             name, cat, trace, self._next_id(),
             parent_id, time.perf_counter(),
             threading.get_ident(), attrs or None,
+            time.thread_time() if self._clocked(name, txid is not None)
+            else None,
         )
 
     def end(self, token: Optional[SpanToken], **attrs) -> None:
@@ -344,6 +416,8 @@ class Tracer:
         if token is None:
             return
         t1 = time.perf_counter()
+        if token.c0 is not None and threading.get_ident() == token.tid:
+            token.cpu_us = int((time.thread_time() - token.c0) * 1e6)
         ms = (t1 - token.t0) * 1000.0
         if attrs:
             token.attrs = {**(token.attrs or {}), **attrs}
@@ -360,10 +434,14 @@ class Tracer:
         return _SpanCM(self, token)
 
     def complete(self, name: str, cat: str, t0: float, t1: float,
-                 txid=None, seq=None, parent=None, **attrs) -> None:
+                 txid=None, seq=None, parent=None, cpu_s=None,
+                 **attrs) -> None:
         """Record an already-measured interval (perf_counter pair) as a
         span — the zero-extra-timing path for subsystems that already
-        clock their stages (JobQueue, VerifyPlane, ClosePipeline)."""
+        clock their stages (JobQueue, VerifyPlane, ClosePipeline).
+        ``cpu_s``: what the recording thread ran inside the interval, a
+        ``time.thread_time()`` difference taken where the caller takes
+        its ``perf_counter()`` readings; exported as ``cpu_us``."""
         if not self._admit(txid):
             return
         trace = _trace_id(txid, seq)
@@ -372,6 +450,7 @@ class Tracer:
             name, cat, trace, self._next_id(),
             parent_id, t0, threading.get_ident(),
             attrs or None,
+            None, None if cpu_s is None else int(cpu_s * 1e6),
         )
         self._record_complete(token, t1, (t1 - t0) * 1000.0)
 
@@ -396,7 +475,7 @@ class Tracer:
             token.parent,
             int((token.t0 - self._epoch) * 1e6),
             max(0, int((t1 - token.t0) * 1e6)),
-            token.tid, token.attrs,
+            token.tid, token.attrs, token.cpu_us,
         )
         self._n += 1
         if self.propagate and token.trace is not None:
@@ -434,7 +513,7 @@ class Tracer:
         span_id = self._next_id()
         self._push((
             "i", name, cat, trace, span_id, parent_id,
-            self._now_us(), 0, threading.get_ident(), attrs or None,
+            self._now_us(), 0, threading.get_ident(), attrs or None, None,
         ))
         if self.propagate and trace is not None:
             with self._lock:
@@ -533,8 +612,11 @@ class Tracer:
                 self._n = 0
         events = []
         for rec in snap:
-            ph, name, cat, trace, span_id, parent, ts, dur, tid, attrs = rec
+            (ph, name, cat, trace, span_id, parent, ts, dur, tid, attrs,
+             cpu_us) = rec
             args = dict(attrs) if attrs else {}
+            if cpu_us is not None:
+                args["cpu_us"] = cpu_us
             if trace is not None:
                 args["trace"] = trace
             args["span"] = span_id
@@ -565,6 +647,15 @@ class Tracer:
                 # anchors in a profiler trace carry this node tag
                 "epoch_ns": int(self._epoch * 1e9),
                 "node_tag": f"{self.node_tag:08x}",
+                # the smallest non-zero `cpu_us` of the dump: an upper
+                # bound on the step of the thread CPU clock, taken from
+                # the data (Linux steps it in nanoseconds; a sandboxed
+                # kernel that accounts CPU time by timer tick, as the
+                # chip machines', 10 ms at a time: there one span reads
+                # 0 or a whole tick and only sums mean anything); None
+                # where no span ran
+                "cpu_tick_us": min(
+                    (rec[10] for rec in snap if rec[10]), default=None),
             },
         }
 
@@ -588,7 +679,7 @@ class Tracer:
                         break
         out = []
         for rec in reversed(picked):
-            ph, name, cat, trace, _sid, _par, ts, dur, _tid, attrs = rec
+            ph, name, cat, trace, _sid, _par, ts, dur, _tid, attrs, _cpu = rec
             ev = {"name": name, "cat": cat, "ts_ms": round(ts / 1000.0, 3)}
             if trace is not None:
                 ev["trace"] = trace
@@ -791,6 +882,147 @@ class _GcProbe:
 
 
 GC_PROBE = _GcProbe()
+
+
+# the roles a thread of the node enters (doc/observability.md lists who
+# enters which); `close` is the cpu_us of `close.total` and `other` is
+# the process's clock less every role: neither is entered
+ROLES = ("intake", "drain", "seal", "door", "fanout", "net", "upkeep")
+
+
+class _ThreadRoles:
+    """CPU seconds by role: ONE registry a process. A long-lived thread
+    of the node enters under a role at the top of its target and leaves
+    in a ``finally`` (``wrap()`` does both around a target). ``cpu_s()``
+    reads ``clock_gettime`` of the threads that are entered, under the
+    lock that leaving takes, so the clock of a thread that has ended is
+    never read (``pthread_getcpuclockid`` of a dead thread is undefined
+    behaviour): a leaving thread folds its own last reading into its
+    role's total, and one that ended without leaving (a pool's worker,
+    entered through the pool's ``initializer``) is folded in at its last
+    reading by the next snapshot, which reads only threads that
+    ``threading.enumerate()`` lists. A thread's seconds count from its
+    entry. Entering and
+    leaving are the only costs a thread pays; nothing is read between
+    two snapshots (a close with the tracer enabled, ``get_counts``, a
+    flush of ``/metrics``)."""
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        # ident -> [role, the thread's CPU clock id, its reading at
+        # entry, its seconds since entry at the last snapshot]
+        self._live: dict[int, list] = {}
+        self._left = dict.fromkeys(ROLES, 0.0)
+        if hasattr(os, "register_at_fork"):
+            # a forked child (the spec workers) has none of these threads
+            os.register_at_fork(after_in_child=self._reset)
+
+    def _reset(self) -> None:
+        self._lock = threading.Lock()
+        self._live = {}
+        self._left = dict.fromkeys(ROLES, 0.0)
+
+    def enter(self, role: str) -> None:
+        """The calling thread runs under ``role`` until ``leave()``."""
+        if role not in self._left:
+            raise ValueError(f"unknown thread role {role!r}")
+        ident = threading.get_ident()
+        # no per-thread clock id off Linux: such a thread's seconds
+        # reach its role when it leaves
+        clock_id = getattr(time, "pthread_getcpuclockid", None)
+        clk = clock_id(ident) if clock_id is not None else None
+        with self._lock:
+            self._live[ident] = [role, clk, time.thread_time(), 0.0]
+
+    def leave(self) -> None:
+        """The calling thread is done: its seconds since ``enter()``
+        stay in its role's total. A thread that never entered is fine."""
+        with self._lock:
+            was = self._live.pop(threading.get_ident(), None)
+            if was is not None:
+                self._left[was[0]] += time.thread_time() - was[2]
+
+    def wrap(self, role: str, target):
+        """``threading.Thread(target=THREAD_ROLES.wrap(role, loop))``:
+        enter at the top of the target, leave in a ``finally``."""
+        def run(*args, **kwargs):
+            self.enter(role)
+            try:
+                return target(*args, **kwargs)
+            finally:
+                self.leave()
+        return run
+
+    def role_of(self, ident: int) -> Optional[str]:
+        was = self._live.get(ident)
+        return was[0] if was is not None else None
+
+    def credit(self, seconds: Optional[float]) -> None:
+        """CPU seconds a short-lived helper ran on the calling thread's
+        behalf (the device call's deadline thread) count under the
+        caller's role, if it has one."""
+        if seconds:
+            with self._lock:
+                was = self._live.get(threading.get_ident())
+                if was is not None:
+                    self._left[was[0]] += seconds
+
+    def _read(self) -> tuple[dict[str, float], dict[str, int]]:
+        """(role -> CPU seconds so far, role -> threads entered now):
+        the threads that left, plus one clock read of each entered."""
+        gettime = time.clock_gettime
+        threads = dict.fromkeys(ROLES, 0)
+        with self._lock:
+            # listed under the lock: a thread that enters now waits for it
+            alive = {t.ident for t in threading.enumerate()}
+            for ident in [i for i in self._live if i not in alive]:
+                role, _clk, _c0, seen = self._live.pop(ident)
+                self._left[role] += seen
+            cpu = dict(self._left)
+            for was in self._live.values():
+                role, clk, c0, _seen = was
+                if clk is not None:
+                    was[3] = gettime(clk) - c0
+                    cpu[role] += was[3]
+                threads[role] += 1
+        return cpu, threads
+
+    def cpu_s(self) -> dict[str, float]:
+        return self._read()[0]
+
+    def marks(self) -> tuple:
+        """(perf_counter, the roles' seconds in ``ROLES`` order, process
+        CPU seconds): what a close cycle's differences are taken of. The
+        process's clock is read LAST, so that over a cycle the roles
+        cannot sum to more than the process ran."""
+        now = time.perf_counter()
+        cpu = self.cpu_s()
+        return now, tuple(cpu[r] for r in ROLES), time.process_time()
+
+    def get_json(self) -> dict:
+        """``get_counts.runtime.threads``: ``{role: {threads, cpu_s}}``
+        and ``process_cpu_s``."""
+        cpu, threads = self._read()
+        out: dict = {r: {"threads": threads[r],
+                         "cpu_s": round(cpu[r], 6)} for r in ROLES}
+        out["process_cpu_s"] = round(time.process_time(), 6)
+        return out
+
+    def flat_json(self) -> dict:
+        """The ``threads`` collector hook: ``/metrics``
+        ``threads.<role>_cpu_s``, ``threads.<role>_threads``,
+        ``threads.process_cpu_s``."""
+        out: dict = {}
+        for role, val in self.get_json().items():
+            if isinstance(val, dict):
+                out[f"{role}_cpu_s"] = val["cpu_s"]
+                out[f"{role}_threads"] = val["threads"]
+            else:
+                out[role] = val
+        return out
+
+
+THREAD_ROLES = _ThreadRoles()
 
 
 # module-level default: subsystems constructed outside a Node (unit

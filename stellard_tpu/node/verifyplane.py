@@ -41,6 +41,7 @@ from ..utils.devicewatch import (
 )
 from .heapaging import HEAP_AGING
 from .metrics import LatencyHist
+from .tracer import THREAD_ROLES
 
 log = logging.getLogger("stellard.device")
 
@@ -64,6 +65,18 @@ class _LatencyModel:
     # once (load characteristics drift; a one-shot loss must not be
     # forever)
     REEXPLORE_EVERY = 512
+    # the same the other way round: after this many batches in a row
+    # priced to a device arm that is NOT far ahead, one goes to the host,
+    # so that the model hears from both arms. A closed loop whose every
+    # batch rides the chip forms no host batch that could teach the
+    # host's price, and a wrong one would otherwise stand until the loop
+    # breaks (PERF.md section 7, PR 34: 24 batches of 96 in a row)
+    PROBE_HOST_EVERY = 4
+    # a host sample counts as this many times the running price at most:
+    # a batch that sat behind a close or a dump is a stall, not a price.
+    # One such sample then moves the price by a quarter; a host that
+    # really slowed down is believed a quarter more with every batch
+    STALL_CAP = 2.0
 
     def __init__(self, min_device_batch: int,
                  device_arms: Sequence[str] = ("device",)):
@@ -80,6 +93,8 @@ class _LatencyModel:
         # sample
         self._device_warm: set[tuple[str, int]] = set()
         self._since: dict[str, int] = {a: 0 for a in self.device_arms}
+        # batches priced to a device arm since the host arm last reported
+        self._device_run = 0
         self.lock = threading.Lock()
 
     @property
@@ -103,9 +118,11 @@ class _LatencyModel:
             return
         with self.lock:
             per = ms / n
+            self._device_run = 0
             if self.cpu_persig_ms is None:
                 self.cpu_persig_ms = per
             else:
+                per = min(per, self.STALL_CAP * self.cpu_persig_ms)
                 self.cpu_persig_ms += 0.25 * (per - self.cpu_persig_ms)
 
     def observe_device(self, n: int, ms: float,
@@ -159,9 +176,10 @@ class _LatencyModel:
         behind it, which rides the ``verify.batch`` span. ``why`` is one
         word: ``small`` (under min_device_batch, or no device arm),
         ``explore`` (an unmeasured arm, or the periodic retry of a
-        losing one), ``priced`` (both sides measured, the cheaper one
-        taken). Unmeasured arms are explored optimistically (in declared
-        order) once a batch reaches min_device_batch, after which real
+        losing one, or of the host behind a run of device batches),
+        ``priced`` (both sides measured, the cheaper one taken).
+        Unmeasured arms are explored optimistically (in declared order)
+        once a batch reaches min_device_batch, after which real
         measurements drive every later decision. `count=False` asks the
         same question without advancing the re-exploration counters
         (the coalescing-window decision polls this every wake-up and
@@ -183,6 +201,12 @@ class _LatencyModel:
         if cpu is None:
             return "cpu", "explore", best, None  # CPU unmeasured: measure it too
         if best < cpu:
+            if count and cpu < best * 4.0:
+                with self.lock:
+                    self._device_run += 1
+                    if self._device_run > self.PROBE_HOST_EVERY:
+                        self._device_run = 0
+                        return "cpu", "explore", best, cpu
             return best_arm, "priced", best, cpu
         if not count:
             return "cpu", "priced", best, cpu
@@ -340,7 +364,8 @@ class VerifyPlane:
             "device": LatencyHist(bounds=_HIST_BOUNDS),
         }
         self._flusher = threading.Thread(
-            target=self._flush_loop, name="verify-plane", daemon=True
+            target=THREAD_ROLES.wrap("intake", self._flush_loop),
+            name="verify-plane", daemon=True
         )
         self._flusher.start()
 
@@ -542,7 +567,8 @@ class VerifyPlane:
                 # the traced programs' objects live as long as the plane
                 HEAP_AGING.age()
 
-        t = threading.Thread(target=run, name="verify-prewarm", daemon=True)
+        t = threading.Thread(target=THREAD_ROLES.wrap("upkeep", run),
+                             name="verify-prewarm", daemon=True)
         t.start()
         return t
 
@@ -587,14 +613,36 @@ class VerifyPlane:
         wedged_now = False
         if arm != "cpu":
             ver = self._verifier_of(arm)
+            cpu = self.tracer.thread_cpu
+            ran: list = []
+
+            def device_call():
+                # on the deadline's helper thread: what it ran (packing,
+                # dispatch, unpacking) is the batch's host CPU; the rest
+                # of the batch's wall is the wait for the chip
+                c = cpu()
+                res = ver.verify_batch(reqs)
+                ran.append(self.tracer.cpu_since(c))
+                return res
+
+            # the thread clock is read OUTSIDE [t0, t1]: that interval is
+            # the router's evidence (observe_device / observe_cpu) and a
+            # read is a system call of microseconds on some hosts
+            c0 = cpu()
             t0 = time.perf_counter()
             try:
                 out = call_with_deadline(
-                    lambda: ver.verify_batch(reqs),
+                    device_call,
                     self._device_deadline(n, arm),
                     label="verify-device",
                 )
                 t1 = time.perf_counter()
+                cpu_s = self.tracer.cpu_since(c0)
+                if cpu_s is not None and ran:
+                    cpu_s += ran[0]
+                    # the helper ran on this thread's behalf: its seconds
+                    # belong to this thread's role (the flusher's intake)
+                    THREAD_ROLES.credit(ran[0])
                 ms = (t1 - t0) * 1000.0
                 self._mark_warm(n, arm)
                 self.model.observe_device(n, ms, arm=arm)
@@ -609,7 +657,7 @@ class VerifyPlane:
                 # the arm the latency model picked (device width rides
                 # the name), kernel wall time as the span duration
                 self.tracer.complete(
-                    "verify.batch", "verify", t0, t1,
+                    "verify.batch", "verify", t0, t1, cpu_s=cpu_s,
                     n=n, routed=arm, **evidence,
                 )
                 return out
@@ -639,9 +687,11 @@ class VerifyPlane:
                 )
         if n >= self.min_device_batch:
             self.cpu_eligible_batches += 1
+        c0 = self.tracer.thread_cpu()  # outside [t0, t1], as above
         t0 = time.perf_counter()
         out = self.cpu.verify_batch(reqs)
         t1 = time.perf_counter()
+        cpu_s = self.tracer.cpu_since(c0)
         ms = (t1 - t0) * 1000.0
         # tiny batches (the synchronous RPC-submit path is n=1) carry
         # un-amortized fixed overhead; folding them into the per-sig
@@ -657,8 +707,8 @@ class VerifyPlane:
         self.batches += 1
         self.verified += n
         self.tracer.complete(
-            "verify.batch", "verify", t0, t1, n=n, routed="cpu",
-            **evidence,
+            "verify.batch", "verify", t0, t1, cpu_s=cpu_s, n=n,
+            routed="cpu", **evidence,
             **({"wedged_fallback": True} if wedged_now else {}),
         )
         return out

@@ -129,8 +129,11 @@ class Database:
         self._write_error: Optional[BaseException] = None
         self._writer: Optional[threading.Thread] = None
         if async_writes:
+            from ..node.tracer import THREAD_ROLES
+
             self._writer = threading.Thread(
-                target=self._write_loop, name="nodestore-writer", daemon=True
+                target=THREAD_ROLES.wrap("drain", self._write_loop),
+                name="nodestore-writer", daemon=True,
             )
             self._writer.start()
 

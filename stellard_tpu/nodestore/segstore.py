@@ -73,6 +73,12 @@ _CKPT_MAGIC = b"SEGCKPT1"
 _CKPT_VERSION = 1
 
 
+def _thread_cpu(tracer):
+    """The calling thread's CPU clock beside a span's wall readings;
+    None without a tracer or with a disabled one (no clock read)."""
+    return None if tracer is None else tracer.thread_cpu()
+
+
 def _seg_path(root: str, sid: int) -> str:
     return os.path.join(root, _SEG_NAME % sid)
 
@@ -491,7 +497,9 @@ class SegStoreBackend(Backend):
                 self._roll_locked()
                 seg = self._segs[self._active_id]
             base = seg.size
+            tr = self._tracer
             t0 = time.perf_counter()
+            c0 = _thread_cpu(tr)
             try:
                 self._active_f.write(img)
                 self._active_f.flush()  # page cache: preads must see it
@@ -505,6 +513,7 @@ class SegStoreBackend(Backend):
                     self._mark_failed_locked("torn append not truncatable")
                 raise
             t1 = time.perf_counter()
+            c1 = _thread_cpu(tr)
             locs = []
             off = base
             for i in range(n_sel):
@@ -523,9 +532,9 @@ class SegStoreBackend(Backend):
             self.records += n_sel
             self.bytes_appended += len(img)
             self._bytes_since_ckpt += len(img)
-            tr = self._tracer
             if tr is not None:
                 tr.complete("persist.nodestore.append", "persist", t0, t1,
+                            cpu_s=tr.cpu_since(c0, c1),
                             records=n_sel, bytes=len(img),
                             seg=self._active_id)
             if self.durability == "fsync":
@@ -544,16 +553,21 @@ class SegStoreBackend(Backend):
             return n_sel
 
     def _fsync_locked(self) -> None:
+        tr = self._tracer
         t0 = time.perf_counter()
+        c0 = _thread_cpu(tr)
         self._active_f.flush()
         os.fsync(self._active_f.fileno())
         t1 = time.perf_counter()
+        c1 = _thread_cpu(tr)
         self.fsyncs += 1
         self._dirty = False
         self._last_fsync = time.monotonic()
-        tr = self._tracer
         if tr is not None:
+            # cpu_us against dur: blocked in the barrier, not waiting
+            # for the interpreter
             tr.complete("persist.nodestore.fsync", "persist", t0, t1,
+                        cpu_s=tr.cpu_since(c0, c1),
                         seg=self._active_id)
 
     def _group_fsync(self) -> None:
@@ -572,21 +586,24 @@ class SegStoreBackend(Backend):
             fd = os.dup(self._active_f.fileno())
             seg_id = self._active_id
             covered = self._segs[seg_id].size
+        tr = self._tracer
         t0 = time.perf_counter()
+        c0 = _thread_cpu(tr)
         try:
             os.fsync(fd)
         finally:
             os.close(fd)
         t1 = time.perf_counter()
+        c1 = _thread_cpu(tr)
         with self._lock:
             self.fsyncs += 1
             self._last_fsync = time.monotonic()
             if self._active_id == seg_id and \
                     self._segs[seg_id].size == covered:
                 self._dirty = False
-            tr = self._tracer
             if tr is not None:
                 tr.complete("persist.nodestore.fsync", "persist", t0, t1,
+                            cpu_s=tr.cpu_since(c0, c1),
                             seg=seg_id, group=True)
 
     def _roll_locked(self) -> None:
@@ -777,8 +794,11 @@ class SegStoreBackend(Backend):
 
     def _kick_maint_locked(self) -> None:
         if self._maint is None:
+            from ..node.tracer import THREAD_ROLES
+
             self._maint = threading.Thread(
-                target=self._maint_loop, name="segstore-maint", daemon=True
+                target=THREAD_ROLES.wrap("drain", self._maint_loop),
+                name="segstore-maint", daemon=True,
             )
             self._maint.start()
         self._maint_wake.notify_all()

@@ -30,6 +30,7 @@ from ..consensus.consensus import ConsensusAdapter
 from ..consensus.txset import TxSet
 from ..consensus.validation import STValidation
 from ..node.hashrouter import SF_RELAYED
+from ..node.tracer import THREAD_ROLES
 from ..node.validator import ValidatorNode
 from ..protocol.keys import KeyPair, verify_signature
 from ..protocol.sttx import SerializedTransaction
@@ -188,7 +189,8 @@ class _Peer:
             with self.send_lock:
                 if self._writer is None:
                     t = threading.Thread(
-                        target=self._write_loop, name="peer-writer", daemon=True
+                        target=THREAD_ROLES.wrap("net", self._write_loop),
+                        name="peer-writer", daemon=True
                     )
                     self._writer = t
                     t.start()
@@ -485,7 +487,8 @@ class TcpOverlay(ConsensusAdapter):
             t.join(timeout=2.0)
 
     def _spawn(self, fn, *args) -> None:
-        t = threading.Thread(target=fn, args=args, daemon=True)
+        t = threading.Thread(target=THREAD_ROLES.wrap("net", fn), args=args,
+                             daemon=True)
         t.start()
         with self._threads_lock:
             self._threads = [x for x in self._threads if x.is_alive()]

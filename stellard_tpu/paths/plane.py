@@ -88,12 +88,15 @@ class PathPlane:
         the close's metadata moved, the net change of the offers the
         index counts, and whether it had to scan the whole state."""
         index = self.index
+        tr = self.tracer
         t0 = time.perf_counter()
+        c0 = tr.thread_cpu() if tr is not None else None
         was = (index.book_rereads, index.offers, index.full_rebuilds)
         index.advance(ledger)
-        if self.tracer is not None:
-            self.tracer.complete(
+        if tr is not None:
+            tr.complete(
                 "paths.index.advance", "paths", t0, time.perf_counter(),
+                cpu_s=tr.cpu_since(c0),
                 seq=ledger.seq, books_reread=index.book_rereads - was[0],
                 offers_delta=index.offers - was[1],
                 full_rebuild=index.full_rebuilds - was[2])

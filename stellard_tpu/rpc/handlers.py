@@ -650,9 +650,12 @@ def do_get_counts(ctx: Context) -> dict:
     # the runtime and the door seen from inside: the collector's
     # collections and pauses per generation (node/tracer.py GC_PROBE),
     # the HTTP door's requests, busy seconds and event-loop lag
-    from ..node.tracer import GC_PROBE
+    # and who had the interpreter: CPU seconds of the node's threads by
+    # role (THREAD_ROLES)
+    from ..node.tracer import GC_PROBE, THREAD_ROLES
 
-    out["runtime"] = {"gc": GC_PROBE.get_json()}
+    out["runtime"] = {"gc": GC_PROBE.get_json(),
+                      "threads": THREAD_ROLES.get_json()}
     door = getattr(node, "http_server", None)
     if door is not None:
         out["rpc_door"] = door.get_json()

@@ -312,8 +312,11 @@ class HttpRpcServer:
     # -- lifecycle --------------------------------------------------------
 
     def start(self) -> "HttpRpcServer":
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="rpc-http")
+        from ..node.tracer import THREAD_ROLES
+
+        self._thread = threading.Thread(
+            target=THREAD_ROLES.wrap("door", self._run), daemon=True,
+            name="rpc-http")
         self._thread.start()
         self._started.wait(timeout=10)
         return self

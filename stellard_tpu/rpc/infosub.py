@@ -91,8 +91,11 @@ class _FanoutShard:
         self.evicted = 0
         self._stop = False
         self._idle = True
+        from ..node.tracer import THREAD_ROLES
+
         self.thread = threading.Thread(
-            target=self._run, name=f"subs-fanout-{idx}", daemon=True
+            target=THREAD_ROLES.wrap("fanout", self._run),
+            name=f"subs-fanout-{idx}", daemon=True
         )
         self.thread.start()
 
@@ -551,6 +554,8 @@ class SubscriptionManager:
         no more; where nobody was listening when the pass began there
         is no pass to join, and a newcomer starts with the next ledger."""
         t0 = time.perf_counter()
+        tr = self.tracer
+        c0 = tr.thread_cpu() if tr is not None else None
         msg = {
             "type": "ledgerClosed",
             "ledger_index": ledger.seq,
@@ -577,9 +582,10 @@ class SubscriptionManager:
         with self._stats_lock:
             self.stats["tx_considered"] += txs
             self.stats["tx_built"] += built
-        if self.tracer is not None:
-            self.tracer.complete(
+        if tr is not None:
+            tr.complete(
                 "subs.publish", "publish", t0, time.perf_counter(),
+                cpu_s=tr.cpu_since(c0),
                 seq=ledger.seq, txs=txs, built=built, delivered=delivered,
                 listeners=interest.listeners,
             )

@@ -109,8 +109,11 @@ class RpcSub(InfoSub):
                 return
             # ONE persistent sender per subscription (steady stream
             # traffic must not churn a thread per event)
+            from ..node.tracer import THREAD_ROLES
+
             self._worker = threading.Thread(
-                target=self._send_loop, name="rpcsub-send", daemon=True
+                target=THREAD_ROLES.wrap("door", self._send_loop),
+                name="rpcsub-send", daemon=True
             )
             self._worker.start()
 

@@ -213,8 +213,11 @@ class WsRpcServer:
     # -- lifecycle --------------------------------------------------------
 
     def start(self) -> "WsRpcServer":
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="rpc-ws")
+        from ..node.tracer import THREAD_ROLES
+
+        self._thread = threading.Thread(
+            target=THREAD_ROLES.wrap("door", self._run), daemon=True,
+            name="rpc-ws")
         self._thread.start()
         self._started.wait(timeout=10)
         return self

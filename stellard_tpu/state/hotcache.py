@@ -228,13 +228,19 @@ class HotNodeCache:
                 continue
             try:
                 self.faults += 1
+                tr = self.tracer
+                c0 = (tr.thread_cpu(one_in_few="cache.fault")
+                      if tr is not None else None)
                 t0 = time.perf_counter()
                 node, blob_len = loader(key)
                 t1 = time.perf_counter()
                 self.fault_s += t1 - t0
-                tr = self.tracer
                 if tr is not None:
+                    # cpu_us, on one fault in a few: of its wall, what the
+                    # faulting thread ran (the rest: the read, or waiting
+                    # for the lock)
                     tr.complete("cache.fault", "state", t0, t1,
+                                cpu_s=tr.cpu_since(c0),
                                 bytes=blob_len)
             except BaseException:
                 with self._lock:

@@ -150,7 +150,8 @@ class TestToyNet:
         sources = toy_run.result["sources"]
         m = manifest.load(os.path.join(REPO, "BENCHMARK.json"))
         mine = [x for x in m["per_layer"] if x.get("workloads") == [CELL]]
-        assert len(mine) == 12
+        # PR 32's twelve and PR 34's `host.net_cpu_share`
+        assert len(mine) == 13
         for x in mine:
             value = readers.read_metric(
                 manifest.reader_file(BENCH, x["name"]), sources)

@@ -59,6 +59,47 @@ class TestModel:
         assert m.use_device(1000)  # 100ms CPU > ~50ms device
         assert m.use_device(4096)  # 410ms CPU > 55ms device
 
+    def test_one_stalled_host_batch_is_a_stall_not_a_price(self):
+        """A small host batch caught behind a close reads many times
+        the running price: it moves the price by a quarter, and a
+        batch of 96 stays on the host (PERF.md section 7, PR 34)."""
+        m = _LatencyModel(min_device_batch=64)
+        for _ in range(2):
+            m.observe_device(96, 125.0)
+        m.observe_cpu(96, 7.7)  # 0.08 ms a signature
+        m.observe_cpu(8, 80.0)  # the same batch size, stalled: 10 ms
+        assert m.cpu_persig_ms == pytest.approx(7.7 / 96 * 1.25)
+        assert m.decide(96)[:2] == ("cpu", "priced")
+        # a host that really slowed down is believed a quarter more with
+        # every batch
+        for _ in range(24):
+            m.observe_cpu(96, 96 * 2.0)
+        assert m.cpu_persig_ms == pytest.approx(2.0, rel=0.05)
+        assert m.decide(96)[:2] == ("device", "priced")
+
+    def test_a_run_of_device_batches_lets_the_host_arm_report(self):
+        """A closed loop whose every batch rides the chip forms no host
+        batch: behind four in a row priced to a device arm that is not
+        far ahead, one goes to the host, which reprices it."""
+        m = _LatencyModel(min_device_batch=64)
+        for _ in range(2):
+            m.observe_device(96, 125.0)
+        m.observe_cpu(96, 96 * 2.45)  # a first sample that was a stall
+        sides = [m.decide(96)[:2] for _ in range(5)]
+        assert sides == [("device", "priced")] * 4 + [("cpu", "explore")]
+        # polling the same question does not advance the run
+        assert all(m.decide(96, count=False)[0] == "device"
+                   for _ in range(9))
+        m.observe_cpu(96, 7.7)
+        assert m.decide(96)[0] == "device"  # 1.86 ms a signature still
+        # a device arm that is far ahead is never probed
+        far = _LatencyModel(min_device_batch=64)
+        for _ in range(2):
+            far.observe_device(16384, 97.0)
+        far.observe_cpu(16384, 16384 * 0.08)
+        assert all(far.decide(16384)[:2] == ("device", "priced")
+                   for _ in range(32))
+
     def test_unmeasured_device_explored_then_driven_by_data(self):
         m = _LatencyModel(min_device_batch=64)
         m.observe_cpu(100, 1.0)  # very fast CPU: 0.01 ms/sig

@@ -11,11 +11,18 @@ three things an operator (and the tier-1 gate) needs around that RPC:
                     -o trace.json
   validate  schema-check an already-saved dump:
                 python tools/traceview.py --validate trace.json
+            and print who ran: by span name the count, wall ms, CPU ms
+            (`cpu_us`, the recording thread's own clock) and the share
+            of wall the thread did not run; by role the CPU seconds of
+            the node's threads over the dump's close cycles
   smoke     boot an in-process standalone node, flood ~200 transactions
             through the full async pipeline, close ledgers, fetch
             trace_dump over the REAL HTTP door, validate the JSON
             schema AND the causal span tree per transaction
-            (submit → verify → close → persist):
+            (submit → verify → close → persist), print the same
+            tables, and fail on a same-thread span whose `cpu_us`
+            exceeds its `dur` by more than a millisecond (or one tick of
+            the thread clock, where the dump says it is coarser):
                 python tools/traceview.py --smoke
   merge     fetch trace_dump from N nodes and emit ONE Perfetto file
             with a process lane per node — cross-node trace propagation
@@ -42,7 +49,9 @@ The schema validator is hand-rolled (no jsonschema dependency) against
 the trace-event format's documented requirements; `validate_chrome_trace`,
 `validate_span_trees`, `merge_dumps` and `validate_merged_trace` are
 importable by tests; so are the clock join (`xplane_anchors`,
-`place_spans`, `join_xplane`) and `idle_by_span`.
+`place_spans`, `join_xplane`), `idle_by_span`, and the CPU tables
+(`cpu_by_span`, `cpu_overruns`, `cpu_by_role`). The `--xplane` timeline
+carries `cpu_us` in a span's `args` as any other attribute.
 """
 
 from __future__ import annotations
@@ -460,6 +469,150 @@ def idle_by_span(spans: list, busy: list, lo: float, hi: float) -> dict:
     return {"idle": idle, "window": hi - lo, "rows": rows}
 
 
+# -- who ran: the spans' CPU clocks and the close cycles' roles --------------
+
+# a span's thread cannot run longer than the span took; the two clocks
+# are read a few hundred nanoseconds apart and tick differently: a
+# millisecond of slack, or one tick of the thread clock where the dump
+# says it is coarser (`otherData.cpu_tick_us`: 10 ms on a kernel that
+# accounts CPU time by timer tick, where a single span reads 0 or a
+# whole tick and only sums mean anything)
+CPU_SLACK_US = 1000
+
+
+def cpu_slack_us(dump) -> int:
+    other = (dump.get("otherData") or {}) if isinstance(dump, dict) else {}
+    return max(CPU_SLACK_US, int(other.get("cpu_tick_us") or 0))
+
+_ROLE_KEY = re.compile(r"^cpu_([a-z]+)_s$")
+
+
+def _complete(events) -> list:
+    return [ev for ev in events or ()
+            if isinstance(ev, dict) and ev.get("ph") == "X"]
+
+
+def cpu_by_span(events) -> dict[str, dict]:
+    """name -> count, wall_ms, and over the spans that carry `cpu_us`
+    (ended on the thread that began them, or clocked by their caller):
+    clocked, cpu_ms, waited_share = 100 x (1 - CPU / wall of those
+    spans). A span without `cpu_us` is "not known", never 0."""
+    out: dict[str, dict] = {}
+    for ev in _complete(events):
+        row = out.setdefault(ev["name"], {
+            "count": 0, "wall_ms": 0.0, "clocked": 0, "cpu_ms": 0.0,
+            "_clocked_wall_ms": 0.0})
+        row["count"] += 1
+        row["wall_ms"] += ev["dur"] / 1000.0
+        cpu = (ev.get("args") or {}).get("cpu_us")
+        if cpu is not None:
+            row["clocked"] += 1
+            row["cpu_ms"] += cpu / 1000.0
+            row["_clocked_wall_ms"] += ev["dur"] / 1000.0
+    for row in out.values():
+        wall = row.pop("_clocked_wall_ms")
+        row["waited_share"] = (
+            None if not row["clocked"] or wall <= 0
+            else 100.0 * max(0.0, 1.0 - row["cpu_ms"] / wall))
+    return out
+
+
+def cpu_overruns(events, slack_us: int = CPU_SLACK_US) -> list[str]:
+    """Spans whose thread ran longer than the span took."""
+    return [
+        f"{ev['name']}: cpu_us {ev['args']['cpu_us']} > dur {ev['dur']}"
+        for ev in _complete(events)
+        if (ev.get("args") or {}).get("cpu_us") is not None
+        and ev["args"]["cpu_us"] > ev["dur"] + slack_us]
+
+
+def cpu_by_role(events) -> dict:
+    """The dump's close cycles summed (`close.total` spans that carry
+    `cycle_s`): cycles, cycle_s, process_cpu_s, close_cpu_s (their own
+    `cpu_us`, where the closing thread is in no role: a close that says
+    its `closer` is a part of that role's seconds already and is summed
+    under `closers` instead) and role -> CPU seconds, `other` among
+    them."""
+    out = {"cycles": 0, "cycle_s": 0.0, "process_cpu_s": 0.0,
+           "close_cpu_s": 0.0, "closers": {}, "roles": {}}
+    for ev in _complete(events):
+        args = ev.get("args") or {}
+        if ev["name"] != "close.total" or "cycle_s" not in args:
+            continue
+        out["cycles"] += 1
+        out["cycle_s"] += args["cycle_s"]
+        out["process_cpu_s"] += args.get("process_cpu_s", 0.0)
+        own = (args.get("cpu_us") or 0) / 1e6
+        if args.get("closer"):
+            out["closers"][args["closer"]] = \
+                out["closers"].get(args["closer"], 0.0) + own
+        else:
+            out["close_cpu_s"] += own
+        for key, val in args.items():
+            m = _ROLE_KEY.match(key)
+            if m:
+                out["roles"][m.group(1)] = \
+                    out["roles"].get(m.group(1), 0.0) + val
+    return out
+
+
+def cpu_of_replay(events) -> dict:
+    """The dump's `replay.span` roots summed: spans, wall_s, cpu_s (the
+    replaying thread's) and process_cpu_s (every thread's)."""
+    out = {"spans": 0, "wall_s": 0.0, "cpu_s": 0.0, "process_cpu_s": 0.0}
+    for ev in _complete(events):
+        args = ev.get("args") or {}
+        if ev["name"] == "replay.span" and "process_cpu_s" in args:
+            out["spans"] += 1
+            out["wall_s"] += ev["dur"] / 1e6
+            out["cpu_s"] += args.get("cpu_s", 0.0)
+            out["process_cpu_s"] += args["process_cpu_s"]
+    return out
+
+
+def print_cpu_tables(events, file=None) -> None:
+    file = file or sys.stdout
+    rows = cpu_by_span(events)
+    if rows:
+        print(f"{'span':34} {'count':>7} {'wall ms':>12} {'cpu ms':>12} "
+              f"{'not run %':>9}", file=file)
+    for name in sorted(rows, key=lambda n: -rows[n]["wall_ms"]):
+        row = rows[name]
+        # one span in a few is clocked: the column is the clocked spans'
+        # CPU scaled to all of the name
+        cpu = (f"{row['cpu_ms'] * row['count'] / row['clocked']:12.3f}"
+               if row["clocked"] else f"{'-':>12}")
+        waited = (f"{row['waited_share']:9.1f}"
+                  if row["waited_share"] is not None else f"{'-':>9}")
+        part = ("" if row["clocked"] in (0, row["count"])
+                else f"  (from {row['clocked']} clocked)")
+        print(f"{name:34} {row['count']:7d} {row['wall_ms']:12.3f} {cpu} "
+              f"{waited}{part}", file=file)
+    by_role = cpu_by_role(events)
+    if by_role["cycles"]:
+        wall = by_role["cycle_s"]
+        print(f"close cycles: {by_role['cycles']}, {wall:.3f} s of wall, "
+              f"process {by_role['process_cpu_s']:.3f} CPU s "
+              f"({by_role['process_cpu_s'] / wall:.2f} cores busy)",
+              file=file)
+        roles = dict(by_role["roles"], close=by_role["close_cpu_s"])
+        for role in sorted(roles, key=lambda r: -roles[r]):
+            print(f"  {role:8} {roles[role]:10.3f} CPU s "
+                  f"{100.0 * roles[role] / wall:7.1f} % of a core",
+                  file=file)
+        for role, own in sorted(by_role["closers"].items()):
+            print(f"  of {role}: {own:.3f} CPU s are closes that ran on "
+                  f"a thread of that role", file=file)
+    replay = cpu_of_replay(events)
+    if replay["spans"]:
+        wall = replay["wall_s"]
+        print(f"replay spans: {replay['spans']}, {wall:.3f} s of wall, "
+              f"the replaying thread {replay['cpu_s']:.3f} CPU s, process "
+              f"{replay['process_cpu_s']:.3f} CPU s "
+              f"({replay['process_cpu_s'] / wall:.2f} cores busy)",
+              file=file)
+
+
 def fetch_dump(url: str, reset: bool = False, timeout: float = 30.0) -> dict:
     """POST trace_dump to a node's HTTP RPC door; -> the trace object."""
     body = json.dumps({
@@ -549,6 +702,14 @@ def run_smoke(n_txs: int = 200, out: str | None = None) -> int:
             print(f"  - {p}", file=sys.stderr)
         return 1
     events = dump["traceEvents"]
+    print_cpu_tables(events)
+    overruns = cpu_overruns(events, cpu_slack_us(dump))
+    if overruns:
+        print("trace smoke: A THREAD RAN LONGER THAN ITS SPAN:",
+              file=sys.stderr)
+        for p in overruns[:20]:
+            print(f"  - {p}", file=sys.stderr)
+        return 1
     traces = {
         (ev.get("args") or {}).get("trace")
         for ev in events
@@ -657,6 +818,13 @@ def main(argv=None) -> int:
         problems = validate_chrome_trace(obj)
         for p in problems:
             print(f"  - {p}", file=sys.stderr)
+        if not problems:
+            print_cpu_tables(obj["traceEvents"])
+            over = cpu_overruns(obj["traceEvents"], cpu_slack_us(obj))
+            tick = (obj.get("otherData") or {}).get("cpu_tick_us")
+            print(f"smallest non-zero cpu_us {tick} (no step of the thread "
+                  f"clock exceeds it); {len(over)} span(s) whose thread "
+                  f"ran longer than they took")
         print("valid" if not problems else f"{len(problems)} problems")
         return 0 if not problems else 1
     if args.url:
