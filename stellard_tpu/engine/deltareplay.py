@@ -55,8 +55,8 @@ from ..state.shamap import SHAMapItem, TNType
 from ..state.specview import PARENT, SpecView
 from .engine import TransactionEngine, TxParams, _is_tec, merge_tally
 
-__all__ = ["SpecState", "CloseReplay", "HEADER_TYPES", "FALLBACK_REASONS",
-           "execute_record"]
+__all__ = ["SpecState", "CloseReplay", "SpecPolicy", "HEADER_TYPES",
+           "FALLBACK_REASONS", "execute_record"]
 
 log = logging.getLogger("stellard.deltareplay")
 
@@ -75,6 +75,74 @@ FALLBACK_REASONS = (
     "no_record", "read_invalidated", "succ_invalidated", "disabled",
     "parent_mismatch", "header_dirty", "not_attempted",
 )
+
+# -- whether the open window speculates at all ------------------------------
+# On one interpreter a dry run costs what the apply it replaces costs, so
+# a record pays only where the close splices it. A close's SPLICE SHARE is
+# spliced / consulted: of the records the close asked for, how many stood
+# (`no_record` and `not_attempted` fallbacks asked for none).
+
+# a close that asked for fewer records than this says nothing about the
+# traffic: an idle ledger must not flip the policy
+MIN_CONSULTED = 64
+# a close under this share is FUTILE: one record in eight. Fixed from the
+# per-close shares of PR 35's first chip call (PERF.md section 6, every
+# close of 64 records or more in the five node cells): the exchange's
+# closes of 2,048 read 0.24, 3.91 and 6.45%; the payment deployments'
+# 37.06-42.92 (the flood, 29 closes), 43.04-51.17 (a validator in its
+# quorum, 12), 50.62-66.83 (the door, 30 closes of some 420) and
+# 62.60-66.11% (a million accounts, 20). 12.5% is a factor 1.9 over the
+# highest of the one group and a factor 3.0 under the lowest of the other
+FUTILE_SHARE = 0.125
+# after a futile close this many open windows do not speculate, and the
+# next one does, whole, as a probe. A fixed period: a sample of a window's
+# first arrivals would read high (it misses the poison chain), a growing
+# period would leave a measured window without a probe
+SKIP_WINDOWS = 3
+
+
+class SpecPolicy:
+    """Window by window, whether the open window speculates: decided by
+    the ledger master when it opens a window (`open_window`), from the
+    splice shares of the closes it has made (`note_close`). Every window
+    speculates until a close is futile; then `SKIP_WINDOWS` do not and
+    the next one probes: a futile probe starts the count again, any other
+    puts the node back to speculating every window. The caller holds the
+    chain lock around both calls."""
+
+    __slots__ = ("speculating", "skip_left", "futile_streak", "last_share")
+
+    def __init__(self):
+        self.speculating = True  # the window that is open now
+        self.skip_left = 0  # windows still to open without speculating
+        self.futile_streak = 0  # futile closes in a row
+        self.last_share: Optional[float] = None  # of the last close that said
+
+    def note_close(self, spliced: int, consulted: int) -> None:
+        if consulted < MIN_CONSULTED:
+            return
+        self.last_share = spliced / consulted
+        if self.last_share < FUTILE_SHARE:
+            self.skip_left = SKIP_WINDOWS
+            self.futile_streak += 1
+        else:
+            self.skip_left = 0
+            self.futile_streak = 0
+
+    def open_window(self) -> bool:
+        """-> whether the window now opening speculates."""
+        self.speculating = not self.skip_left
+        if self.skip_left:
+            self.skip_left -= 1
+        return self.speculating
+
+    def get_json(self) -> dict:
+        return {
+            "speculating": self.speculating,
+            "futile_streak": self.futile_streak,
+            "last_share": (None if self.last_share is None
+                           else round(self.last_share, 4)),
+        }
 
 
 class SpecRecord:
@@ -605,9 +673,14 @@ class CloseReplay:
         for txid, c in self._class.items():
             if c == "fallback":
                 by_reason[self._why[txid]] += 1
+        spliced = sum(1 for c in cls if c == "spliced")
+        fallback = sum(1 for c in cls if c == "fallback")
         return {
-            "spliced": sum(1 for c in cls if c == "spliced"),
-            "fallback": sum(1 for c in cls if c == "fallback"),
+            "spliced": spliced,
+            "fallback": fallback,
+            # the records this close asked for (`SpecPolicy`'s base)
+            "consulted": (spliced + fallback - by_reason["no_record"]
+                          - by_reason["not_attempted"]),
             "fallback_by_reason": by_reason,
             "invalidated": self.invalidated,
             "parent_ok": self.parent_ok,

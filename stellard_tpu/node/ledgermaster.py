@@ -16,7 +16,7 @@ import threading
 import time
 from typing import Callable, Optional
 
-from ..engine.deltareplay import FALLBACK_REASONS
+from ..engine.deltareplay import FALLBACK_REASONS, SpecPolicy
 from ..engine.engine import TransactionEngine, TxParams, merge_tally
 from ..node.hashrouter import SF_SIGGOOD
 from ..protocol.sttx import SerializedTransaction
@@ -125,6 +125,10 @@ class LedgerMaster:
         # a SpecView, and the close splices the recorded delta when the
         # read set still validates (engine/deltareplay.py)
         self.delta_replay = True
+        # whether the open window speculates at all: decided once a
+        # window, where it opens, from the splice shares of the closes
+        # made so far (an observation of the traffic, not an option)
+        self.spec_policy = SpecPolicy()
         # close-info counters live in one AtomicCounters bundle: the
         # close path, the TxQ's deferred promotion job, and the parallel
         # executor's commit thread all feed close-adjacent counters from
@@ -137,6 +141,10 @@ class LedgerMaster:
             "incremental_seals", "building_fold_failures",
             # `fallback` by the reason `try_splice` named
             *(f"fallback.{r}" for r in FALLBACK_REASONS),
+            # open windows the policy opened without speculation, and
+            # the dry runs not made in them. Everything above counts the
+            # closes that consulted records, and those alone
+            "windows_skipped", "txs_unspeculated",
         )
         # what the transactors counted in the transactions of closed
         # ledgers (`offers.*`, `flow.*`: engine/offers.py,
@@ -198,7 +206,7 @@ class LedgerMaster:
             genesis.accepted = True
             self._push_closed(genesis)
             self.validated = genesis
-            self.current = genesis.open_successor()
+            self._open_window(genesis)
 
     def load_ledger(self, ledger: Ledger) -> None:
         """Resume from a stored closed ledger (reference: loadOldLedger)."""
@@ -206,7 +214,17 @@ class LedgerMaster:
             ledger.accepted = True
             self._push_closed(ledger)
             self.validated = ledger
-            self.current = ledger.open_successor()
+            self._open_window(ledger)
+
+    def _open_window(self, parent: Ledger) -> None:
+        """Open `parent`'s successor, and decide whether this window
+        speculates: once, here, never in the middle of a window (a
+        window has a SpecState, a building tree and records for every
+        transaction it accepts, or none of them). Caller holds the
+        lock."""
+        self.current = parent.open_successor()
+        if self.delta_replay and not self.spec_policy.open_window():
+            self.delta_stats.add("windows_skipped")
 
     def _push_closed(self, ledger: Ledger) -> None:
         self.closed = ledger
@@ -361,6 +379,11 @@ class LedgerMaster:
         queue's promotion counters can tell spliced-promoted txs apart
         from submit-time speculation."""
         if not self.delta_replay:
+            return
+        if not self.spec_policy.speculating:
+            # the closes before this window threw their records away:
+            # no SpecState, so the close runs the plain serial apply
+            self.delta_stats.add("txs_unspeculated")
             return
         spec = getattr(open_ledger, "_spec_state", None)
         if spec is None:
@@ -807,7 +830,7 @@ class LedgerMaster:
         [txq] enabled=0 kill-switch keeps the legacy held re-apply path
         byte-for-byte). Caller holds the lock."""
         self._retire_open()
-        self.current = new_lcl.open_successor()
+        self._open_window(new_lcl)
         for tx in leftovers:
             ter, _applied = self._open_apply(
                 tx, TxParams.OPEN_LEDGER | TxParams.RETRY
@@ -878,7 +901,7 @@ class LedgerMaster:
             ledger.accepted = True
             self._push_closed(ledger)
             self._retire_open()
-            self.current = ledger.open_successor()
+            self._open_window(ledger)
             self._reindex_chain(ledger)
 
     def _reindex_chain(self, ledger: Ledger) -> None:
@@ -1073,6 +1096,9 @@ class LedgerMaster:
                         ter, _ = apply_one((key, tx), True)
                         results[tx.txid()] = ter
                 break
+        # `close.apply` carries both: the transactions this close
+        # applied, and how many of them had a record to consult
+        self.last_close = {"txs": len(results), "speculated": 0}
         if replay is not None:
             replay.flush_pending()
             if self.incremental_seal:
@@ -1080,6 +1106,8 @@ class LedgerMaster:
                 # close's final write set — the seal then hashes only the
                 # residual (full seal stays the automatic fallback)
                 replay.maybe_adopt_prehashed()
+            self.last_close["speculated"] = len(
+                results.keys() & spec.records.keys())
             self._note_delta_stats(replay)
             merge_tally(tally, replay.tally)
         if tally:
@@ -1090,6 +1118,7 @@ class LedgerMaster:
 
     def _note_delta_stats(self, replay) -> None:
         c = replay.counts()
+        self.spec_policy.note_close(c["spliced"], c["consulted"])
         if self.txq is not None and self.txq.enabled:
             # queue-aware speculation honesty: which of the txs the
             # queue promoted into this window spliced vs fell back
@@ -1135,7 +1164,9 @@ class LedgerMaster:
         self.last_close.update(stages)
         tr = self.tracer
         tr.complete("close.apply", "close", t0, t_apply, seq=seq,
-                    cpu_s=tr.cpu_since(c0, c_apply))
+                    cpu_s=tr.cpu_since(c0, c_apply),
+                    txs=self.last_close["txs"],
+                    speculated=self.last_close["speculated"])
         tr.complete("close.seal", "close", t_apply, t_seal, seq=seq,
                     cpu_s=tr.cpu_since(c_apply, c_seal))
         cycle = {} if cpu_total is None else self._note_cycle(cpu_total)
@@ -1194,6 +1225,7 @@ class LedgerMaster:
                    if not k.startswith("fallback.")},
                 "fallback_by_reason": {
                     r: stats[f"fallback.{r}"] for r in FALLBACK_REASONS},
+                "policy": self.spec_policy.get_json(),
                 "last_close": dict(self.last_close),
             }
             # close-stage percentiles from the tracer's `close.*` stage

@@ -27,6 +27,7 @@ sys.path.insert(0, BENCH)
 from yardstick import exchange, exchangecheck, manifest, nodedrive  # noqa: E402
 from yardstick import prepared, prepared_exchange, readers  # noqa: E402
 
+from stellard_tpu.engine.deltareplay import SpecPolicy  # noqa: E402
 from stellard_tpu.engine.engine import TxParams  # noqa: E402
 from stellard_tpu.engine.flags import tfImmediateOrCancel  # noqa: E402
 from stellard_tpu.engine.offers import get_rate  # noqa: E402
@@ -63,6 +64,16 @@ CONFIG, TRAFFIC = FILES["config"], FILES["traffic"]
 INI = nodedrive.plain_reference_ini(FILES["ini"])
 # the plain reference: serial apply, no speculation, full seal
 PLAIN_INI = INI + "\n[tree]\nincremental=0\n\n[close]\ndelta_replay=0\n"
+
+
+class EveryWindow(SpecPolicy):
+    """The tests of delta replay ON an exchange want every close to
+    consult records; the node itself stops speculating there (PR 35:
+    `tests/test_deltareplay.py` and the driver's rehearsal below hold
+    that to the plain path). Steered here, not by an option."""
+
+    def note_close(self, spliced, consulted):
+        pass
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +126,7 @@ def runs(store, tmp_path_factory):
     ini = nodedrive.ini_text(INI, workdir=os.path.join(workdir, "db"),
                              start_up="load")
     node = Node(Config.from_ini(ini)).setup()
+    node.ledger_master.spec_policy = EveryWindow()
     by_close = []
     try:
         resumed_index = dict(node.path_plane.index.counters())
@@ -620,7 +632,10 @@ class TestDriverRehearsal:
 
         said = []
         ctx = types.SimpleNamespace(
-            seed=SEED + 7, seconds=2.0, trace=False, rehearsal=True,
+            # four closes in the window: behind the warm-up's futile
+            # close three windows do not speculate, the fourth probes,
+            # and the three `apply.*_share` metrics have a close to read
+            seed=SEED + 7, seconds=3.5, trace=False, rehearsal=True,
             config=CONFIG, ini_template=INI, traffic=TRAFFIC,
             cache_dir=os.path.dirname(os.path.dirname(directory)),
             work_root=str(tmp_path / "work"), say=said.append)
