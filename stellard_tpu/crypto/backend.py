@@ -932,6 +932,16 @@ class _RoutedFlat:
     def hash_packed(self, buf, offsets):
         return self._wd.hash_packed(buf, offsets)
 
+    # the watchdog's routing counters, for a caller that reports which
+    # arm took its batch (the shard contract, nodestore/shards.py)
+    @property
+    def device_nodes(self) -> int:
+        return self._wd.device_nodes
+
+    @property
+    def host_nodes(self) -> int:
+        return self._wd.host_nodes
+
 
 # flat batches below this never route to a device backend: a handful of
 # residual nodes can never amortize a device round-trip (the incremental

@@ -23,7 +23,7 @@ from ..state.ledger import Ledger
 from .config import Config
 from .hashrouter import HashRouter
 from .heapaging import HEAP_AGING
-from .jobqueue import JobQueue
+from .jobqueue import JobQueue, JobType
 from .ledgermaster import LedgerMaster
 from .networkops import NetworkOPs, TxStatus
 from .txdb import TxDatabase
@@ -336,6 +336,16 @@ class Node:
         self.hasher, self.verify_plane = make_crypto_planes(
             cfg, tracer=self.tracer
         )
+        if self.shardstore is not None:
+            # the offline contract's content hashes (an archive's
+            # import gate, `verify`) ride the hash plane: its routed
+            # flat facade, so the cost router decides chip or host a
+            # batch as it does for a tree's levels
+            flat = getattr(self.hasher, "flat_hasher", None)
+            self.shardstore.hasher = (
+                flat() if flat is not None else self.hasher
+            )
+            self.shardstore.tracer = self.tracer
         self._gc_probed = False
         self._heap_owned = False
         self.verify_prewarm: Optional[threading.Thread] = None
@@ -696,15 +706,18 @@ class Node:
                     # (the distribution network's re-serve half)
                     vn.segment_source = self.shardstore
 
-                def _on_shard_imported(res: dict) -> None:
-                    feed_shard(
+                def _on_shard_imported(res: dict) -> dict:
+                    fed = feed_shard(
                         self.shardstore, res["id"],
-                        store=lambda tb, key, blob: self.nodestore.store(
-                            _NOT(tb), key, blob
-                        ),
+                        store_packed=lambda tb, keys, buf, offsets:
+                            self.nodestore.store_packed(
+                                _NOT(tb), keys, buf, offsets
+                            ),
                         txdb=self.txdb,
+                        tracer=self.tracer,
                     )
                     self._update_archive_floor()
+                    return fed
 
                 vn.shard_backfill = ShardBackfill(
                     send=self.overlay.send_segments_request,
@@ -720,6 +733,14 @@ class Node:
                     on_condemn=lambda pub: self.overlay.charge_peer(
                         pub, _FEE_GS
                     ),
+                    # the import leaves the link's reader thread: a
+                    # shard is seconds of verification and feed, and
+                    # the reader has the link's pings and the next
+                    # file's chunks to answer meanwhile
+                    dispatch=lambda work: self.job_queue.add_job(
+                        JobType.jtLEDGER_DATA, "shard_import", work
+                    ),
+                    tracer=self.tracer,
                 )
 
             # persistence rides the close pipeline's dedicated ORDERED

@@ -32,6 +32,7 @@ has trimmed a range syncs it from shards over the SAME wire path.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import struct
@@ -75,6 +76,15 @@ SHARD_FILE_BASE = 1 << 31
 _T_LEDGER = 1
 _T_ACCOUNT_NODE = 3
 _T_TRANSACTION_NODE = 4
+
+
+def tx_leaf_parts(blob: bytes) -> tuple[bytes, bytes]:
+    """(raw_tx, meta) of a TX_MD leaf record: 4B prefix + VL(tx) ||
+    VL(meta) + the 32B tag, which is the txid."""
+    from ..protocol.serializer import BinaryParser
+
+    p = BinaryParser(blob[4:-32])
+    return p.read_vl(), p.read_vl()
 
 
 def _pack_records(records: list) -> bytes:
@@ -172,17 +182,134 @@ def mark_live(fetch, headers: list[dict], live: set) -> None:
                         stack.append(blob[4 + 32 * i: 36 + 32 * i])
 
 
-def verify_shard_blob(blob: bytes) -> dict:
+# records a flat batch of the content-hash check may hold. A shard's
+# records go to the hasher in equal slabs of at most this many: the
+# hash plane's router prices a batch by its power-of-two bucket, so
+# slabs of one size (never under half the bound) land in ONE bucket
+# and a shard teaches the router both arms within its first three (a
+# one-off remainder would open a bucket of its own, and a device
+# program of its own, in every shard).
+# 65,536 records of this format are some 25 MB packed and, on the
+# device arm, a padded upload of at most 67 MB a block-count bucket:
+# past that size the program's time is linear in the rows, so a larger
+# slab prices the same and only holds more memory (a whole shard of
+# 200 thousand records would be one 170 MB upload).
+HASH_SLAB_RECORDS = 65536
+
+
+class VerifyStats:
+    """What the offline contract hashed, by arm (``shard_verify`` in
+    ``get_counts.history_shards``). ``device_records`` and
+    ``host_records`` are read off the hasher's own routing counters
+    around each flat batch; with no hasher handed in every record is a
+    host record."""
+
+    FIELDS = ("records", "device_records", "host_records", "batches",
+              "bad_records")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        for f in self.FIELDS:
+            setattr(self, f, 0)
+
+    def add(self, **deltas) -> None:
+        with self._lock:
+            for k, v in deltas.items():
+                setattr(self, k, getattr(self, k) + v)
+
+    def get_json(self) -> dict:
+        with self._lock:
+            return {f: getattr(self, f) for f in self.FIELDS}
+
+
+def trace_span(tracer, name: str, **attrs):
+    """``tracer.span(name, "archive", ...)``, or nothing without one."""
+    if tracer is None:
+        return contextlib.nullcontext()
+    return tracer.span(name, "archive", **attrs)
+
+
+def span_note(tok, **attrs) -> None:
+    """Attributes learned inside a span, onto its token (None where the
+    span is not recorded)."""
+    if tok is not None:
+        tok.attrs = {**(tok.attrs or {}), **attrs}
+
+
+def _slabs(n: int) -> list[tuple[int, int]]:
+    """[lo, hi) bounds of ceil(n / HASH_SLAB_RECORDS) near-equal slabs."""
+    k = max(1, -(-n // HASH_SLAB_RECORDS))
+    return [(n * i // k, n * (i + 1) // k) for i in range(k)]
+
+
+def _count_bad(rec_img: bytes, recs: list, hasher, tracer,
+               stats: Optional[VerifyStats]) -> int:
+    """Every record's content hash against its key -> how many differ.
+    ``recs`` rows are ``_iter_records_py``'s. With a ``hasher`` the
+    records go through ``hash_packed`` in flat slabs (a routed hasher
+    decides chip or host a slab, from what it has observed) and a
+    verdict that is not the 32 bytes of the key is a bad record; with
+    none, the plain ``hashlib`` loop: the router's host arm and the
+    reference."""
+    bad = 0
+    mv = memoryview(rec_img)
+    for lo, hi in _slabs(len(recs)):
+        part = recs[lo:hi]
+        if not part:
+            continue
+        with trace_span(tracer, "shard.verify.hash", records=len(part)) as tok:
+            if hasher is None:
+                dev = 0
+                slab_bad = sum(
+                    1 for key, _t, off, ln in part
+                    if sha512_half(mv[off: off + ln]) != key
+                )
+            else:
+                offsets = [0]
+                total = 0
+                for _key, _t, _off, ln in part:
+                    total += ln
+                    offsets.append(total)
+                buf = b"".join([mv[off: off + ln]
+                                for _k, _t, off, ln in part])
+                dev0 = getattr(hasher, "device_nodes", 0)
+                digests = hasher.hash_packed(buf, offsets)
+                dev = min(len(part), max(
+                    0, getattr(hasher, "device_nodes", 0) - dev0))
+                slab_bad = sum(
+                    1 for (key, _t, _o, _l), d in zip(part, digests)
+                    if d != key
+                ) + max(0, len(part) - len(digests))
+            span_note(tok, arm=("device" if dev == len(part)
+                                else "host" if dev == 0 else "mixed"))
+        bad += slab_bad
+        if stats is not None:
+            stats.add(records=len(part), device_records=dev,
+                      host_records=len(part) - dev, batches=1,
+                      bad_records=slab_bad)
+    return bad
+
+
+def verify_shard_blob(blob: bytes, hasher=None, tracer=None,
+                      stats: Optional[VerifyStats] = None) -> dict:
     """The offline verification contract run against RAW SHARD BYTES
-    alone — the archive-import gate (doc/archive.md). Checks magic +
-    header geometry, the whole-file CRC, every record's content hash,
-    and the lo..hi ledger-header chain anchored at the header's
-    first/last ledger hashes; the records count is DERIVED during the
-    pass (it lives in the store index, not the file), so a fetched
-    image is installable without trusting anything but its bytes. On
-    success the report carries the parsed geometry (`lo`/`hi`/
-    `rec_off`/`rec_len`/`acct_off`/`acct_len`/`records`/`first_hash`/
-    `last_hash`) an importer needs to index the file."""
+    alone — the archive-import gate (doc/archive.md) and the ONE copy
+    of the contract (``HistoryShardStore.verify`` and ``import_shard``
+    run it too). Checks magic + header geometry, the whole-file CRC,
+    every record's content hash (``_count_bad``: through ``hasher``
+    where one is handed in, the plain ``hashlib`` loop otherwise), and
+    the lo..hi ledger-header chain anchored at the header's first/last
+    ledger hashes; the records count is DERIVED during the pass (it
+    lives in the store index, not the file), so a fetched image is
+    installable without trusting anything but its bytes. On success the
+    report carries the parsed geometry (`lo`/`hi`/`rec_off`/`rec_len`/
+    `acct_off`/`acct_len`/`records`/`first_hash`/`last_hash`) an
+    importer needs to index the file."""
+    with trace_span(tracer, "shard.verify", bytes=len(blob)):
+        return _verify_image(blob, hasher, tracer, stats)
+
+
+def _verify_image(blob: bytes, hasher, tracer, stats) -> dict:
     report: dict = {"ok": False}
     if len(blob) < _HDR_SIZE + 4 or blob[:8] != _MAGIC:
         report["error"] = "bad magic/size"
@@ -202,8 +329,10 @@ def verify_shard_blob(blob: bytes) -> dict:
             or acct_len < 4 or acct_off + acct_len + 4 != len(blob)):
         report["error"] = "bad geometry"
         return report
-    body, crc = blob[:-4], struct.unpack("<I", blob[-4:])[0]
-    if zlib.crc32(body) & 0xFFFFFFFF != crc:
+    with trace_span(tracer, "shard.verify.crc"):
+        crc_ok = (zlib.crc32(memoryview(blob)[:-4]) & 0xFFFFFFFF
+                  == struct.unpack("<I", blob[-4:])[0])
+    if not crc_ok:
         report["error"] = "crc mismatch"
         return report
     (n_acct,) = struct.unpack_from("<I", blob, acct_off)
@@ -211,23 +340,20 @@ def verify_shard_blob(blob: bytes) -> dict:
         report["error"] = "bad acct index"
         return report
     rec_img = blob[rec_off: rec_off + rec_len]
-    n_checked = bad = consumed = 0
+    recs = list(_iter_records_py(rec_img))
+    consumed = recs[-1][2] + recs[-1][3] if recs else 0
+    bad = _count_bad(rec_img, recs, hasher, tracer, stats)
     headers: dict[int, dict] = {}
     ledger_prefix = HP_LEDGER_MASTER.to_bytes(4, "big")
-    for key, type_byte, off, ln in _iter_records_py(rec_img):
-        node = rec_img[off: off + ln]
-        if sha512_half(node) != key:
-            bad += 1
-        n_checked += 1
-        consumed = off + ln
-        if type_byte == _T_LEDGER and node[:4] == ledger_prefix:
-            from ..state.ledger import parse_header
+    from ..state.ledger import parse_header
 
-            h = parse_header(node[4:])
+    for key, type_byte, off, ln in recs:
+        if type_byte == _T_LEDGER and rec_img[off: off + 4] == ledger_prefix:
+            h = parse_header(rec_img[off + 4: off + ln])
             headers[h["seq"]] = {
                 "hash": key, "parent_hash": h["parent_hash"],
             }
-    report["records"] = n_checked
+    report["records"] = len(recs)
     report["bad_records"] = bad
     chain_ok = True
     for seq in range(lo, hi + 1):
@@ -286,8 +412,16 @@ class HistoryShardStore:
 
     INDEX_NAME = "shards.json"
 
-    def __init__(self, path: str):
+    def __init__(self, path: str, hasher=None, tracer=None):
         self.root = path
+        # the offline contract's content-hash check goes through
+        # ``hasher`` (a BatchHasher: a node hands over its routed flat
+        # hasher, so the hash plane's cost router decides chip or host a
+        # batch); None is the plain ``hashlib`` loop. ``tracer`` records
+        # the ``shard.verify`` / ``shard.install`` spans.
+        self.hasher = hasher
+        self.tracer = tracer
+        self.verify_stats = VerifyStats()
         os.makedirs(path, exist_ok=True)
         self._lock = threading.RLock()
         self._shards: dict[int, _Shard] = {}
@@ -466,6 +600,7 @@ class HistoryShardStore:
                 "imported_bytes": self.imported_bytes,
                 "import_rejects": self.import_rejects,
                 "contiguous_floor": self.contiguous_floor(),
+                "shard_verify": self.verify_stats.get_json(),
             }
 
     def contiguous_floor(self) -> int:
@@ -590,7 +725,8 @@ class HistoryShardStore:
         verification retains zero hostile bytes. A range the store
         already holds is an idempotent duplicate; a partial overlap is
         rejected (two honest seals never straddle a rotation point)."""
-        report = verify_shard_blob(data)
+        report = verify_shard_blob(data, self.hasher, self.tracer,
+                                   self.verify_stats)
         if not report["ok"]:
             with self._lock:
                 self.import_rejects += 1
@@ -611,21 +747,22 @@ class HistoryShardStore:
             sid = max(self._shards, default=0) + 1
         path = os.path.join(self.root, f"shard-{sid:06d}.shard")
         tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(data)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)
-        with self._lock:
-            sh = _Shard(sid, path, lo, hi,
-                        report["rec_off"], report["rec_len"],
-                        report["acct_off"], report["acct_len"],
-                        report["records"], len(data),
-                        report["first_hash"], report["last_hash"])
-            self._shards[sid] = sh
-            self._write_index_locked()
-            self.imported += 1
-            self.imported_bytes += len(data)
+        with trace_span(self.tracer, "shard.install", id=sid, bytes=len(data)):
+            with open(tmp, "wb") as f:
+                f.write(data)
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, path)
+            with self._lock:
+                sh = _Shard(sid, path, lo, hi,
+                            report["rec_off"], report["rec_len"],
+                            report["acct_off"], report["acct_len"],
+                            report["records"], len(data),
+                            report["first_hash"], report["last_hash"])
+                self._shards[sid] = sh
+                self._write_index_locked()
+                self.imported += 1
+                self.imported_bytes += len(data)
         return {"ok": True, "id": sid, "lo": lo, "hi": hi,
                 "records": report["records"]}
 
@@ -728,11 +865,7 @@ class HistoryShardStore:
         with self._lock:
             blob = os.pread(self._fd(sh), ln, off)
         self.tx_faults += 1
-        # TX_MD leaf: 4B prefix + VL(tx) || VL(meta) + 32B tag
-        from ..protocol.serializer import BinaryParser
-
-        p = BinaryParser(blob[4:-32])
-        return p.read_vl(), p.read_vl()
+        return tx_leaf_parts(blob)
 
     def account_tx(self, account: bytes, min_ledger: int, max_ledger: int,
                    limit: int = 200, forward: bool = True,
@@ -790,9 +923,10 @@ class HistoryShardStore:
     # -- offline verification ----------------------------------------------
 
     def verify(self, sid: int) -> dict:
-        """The offline verification contract (doc/storage.md): CRC over
-        the whole file, every record's content hash, and the header
-        chain — run against the file alone."""
+        """The offline verification contract (doc/storage.md) run
+        against one held shard's file alone (``verify_shard_blob``: CRC
+        over the whole file, every record's content hash, the header
+        chain), and the file held to this store's index row for it."""
         with self._lock:
             sh = self._shards.get(sid)
         if sh is None:
@@ -800,52 +934,18 @@ class HistoryShardStore:
         self.verifies += 1
         with open(sh.path, "rb") as f:
             blob = f.read()
-        report: dict = {"ok": False, "id": sid, "lo": sh.lo, "hi": sh.hi}
-        if len(blob) < _HDR_SIZE + 4 or blob[:8] != _MAGIC:
-            report["error"] = "bad magic/size"
-            return report
-        body, crc = blob[:-4], struct.unpack("<I", blob[-4:])[0]
-        if zlib.crc32(body) & 0xFFFFFFFF != crc:
-            report["error"] = "crc mismatch"
-            return report
-        rec_img = blob[sh.rec_off: sh.rec_off + sh.rec_len]
-        n_checked = bad = 0
-        headers: dict[int, dict] = {}
-        ledger_prefix = HP_LEDGER_MASTER.to_bytes(4, "big")
-        for key, type_byte, off, ln in _iter_records_py(rec_img):
-            node = rec_img[off: off + ln]
-            if sha512_half(node) != key:
-                bad += 1
-            n_checked += 1
-            if type_byte == _T_LEDGER and node[:4] == ledger_prefix:
-                from ..state.ledger import parse_header
-
-                h = parse_header(node[4:])
-                headers[h["seq"]] = {
-                    "hash": key, "parent_hash": h["parent_hash"],
-                }
-        report["records"] = n_checked
-        report["bad_records"] = bad
-        chain_ok = True
-        for seq in range(sh.lo, sh.hi + 1):
-            if seq not in headers:
-                chain_ok = False
-                break
-            if seq > sh.lo and \
-                    headers[seq]["parent_hash"] != headers[seq - 1]["hash"]:
-                chain_ok = False
-                break
-        report["header_chain_ok"] = chain_ok
-        report["first_hash_ok"] = (
-            headers.get(sh.lo, {}).get("hash") == sh.first_hash
-        )
-        report["last_hash_ok"] = (
-            headers.get(sh.hi, {}).get("hash") == sh.last_hash
-        )
-        report["ok"] = (
-            bad == 0 and n_checked == sh.records and chain_ok
-            and report["first_hash_ok"] and report["last_hash_ok"]
-        )
+        report = verify_shard_blob(blob, self.hasher, self.tracer,
+                                   self.verify_stats)
+        report["id"] = sid
+        if report["ok"] and (
+            (report["lo"], report["hi"], report["rec_off"],
+             report["rec_len"], report["records"], report["first_hash"],
+             report["last_hash"])
+            != (sh.lo, sh.hi, sh.rec_off, sh.rec_len, sh.records,
+                sh.first_hash, sh.last_hash)
+        ):
+            report["ok"] = False
+            report["error"] = "file differs from the store index"
         return report
 
     def close(self) -> None:
