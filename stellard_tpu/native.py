@@ -204,7 +204,7 @@ def _bind(lib: ctypes.CDLL) -> None:
         lib.segidx_count.restype = ctypes.c_uint64
         lib.segidx_put_batch.argtypes = [
             ctypes.c_void_p, ctypes.c_uint64, ctypes.c_char_p,
-            ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_void_p,  # locs: n uint64 (a numpy array's address)
         ]
         lib.segidx_put_batch.restype = ctypes.c_int
         lib.segidx_get.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
@@ -225,7 +225,8 @@ def _bind(lib: ctypes.CDLL) -> None:
         lib.segidx_load.restype = ctypes.c_int
         lib.segstore_pack.argtypes = [
             ctypes.c_uint64, ctypes.c_char_p, ctypes.c_char_p,
-            ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_char_p,
+            ctypes.c_void_p,  # offsets: n+1 uint64 (a numpy array's address)
             u8p, ctypes.c_uint64,
         ]
         lib.segstore_pack.restype = ctypes.c_int64
@@ -417,6 +418,18 @@ class Ed25519NativeVerify:
         return out
 
 
+def _u64_array(values):
+    """`values` as ONE contiguous uint64 numpy array: a packed buffer
+    (``array('Q')``, a uint64 array) is viewed in place, a list of ints
+    converted in one C pass. The segstore seam's offsets and locations
+    cross into C this way, as the array's address (``.ctypes.data``, an
+    int: ``data_as`` would leave a reference cycle behind every call),
+    never as one ctypes argument per value."""
+    import numpy as np
+
+    return np.ascontiguousarray(values, dtype=np.uint64)
+
+
 class SegIdxNative:
     """Native open-addressed key→loc index for the segstore backend
     (key = 32-byte content hash, loc = (seg_id << 44) | record_offset).
@@ -444,10 +457,15 @@ class SegIdxNative:
         loc = self.lib.segidx_get(self._h, key)
         return None if loc < 0 else int(loc)
 
-    def put_batch(self, packed_keys: bytes, locs: list[int]) -> None:
-        n = len(locs)
-        arr = (ctypes.c_uint64 * n)(*locs)
-        if self.lib.segidx_put_batch(self._h, n, packed_keys, arr) != 0:
+    def put_batch(self, packed_keys: bytes, locs) -> None:
+        """`locs`: one uint64 a key, as a packed buffer (``array('Q')``,
+        a numpy array) or a sequence of ints."""
+        arr = _u64_array(locs)
+        n = len(arr)
+        if len(packed_keys) < 32 * n:
+            raise ValueError("put_batch: fewer keys than locations")
+        if n and self.lib.segidx_put_batch(
+                self._h, n, packed_keys, arr.ctypes.data) != 0:
             raise ValueError("segidx_put_batch: loc out of range")
 
     def remove(self, key: bytes, expect_loc=None) -> bool:
@@ -466,7 +484,7 @@ class SegIdxNative:
         n = len(self)
         out = (ctypes.c_uint8 * (n * 40))()
         got = self.lib.segidx_dump(self._h, out, n)
-        return bytes(out[: int(got) * 40])
+        return bytes(memoryview(out)[: int(got) * 40])
 
     def load(self, blob: bytes) -> None:
         n = len(blob) // 40
@@ -477,16 +495,22 @@ class SegIdxNative:
                      offsets) -> bytes:
         """One-call append image from the flat-buffer node encoding."""
         n = len(types)
-        arr = (ctypes.c_uint64 * (n + 1))(*offsets)
-        cap = (len(buf) if not isinstance(buf, memoryview) else buf.nbytes) \
-            + n * 38
+        arr = _u64_array(offsets)
+        blobs = bytes(buf)
+        # the C loop trusts every range it copies from: n+1 offsets,
+        # never decreasing, inside the blob buffer, and 32 bytes of key
+        # for each record
+        if len(arr) != n + 1 or len(packed_keys) < 32 * n or (
+                n and (arr[-1] > len(blobs) or (arr[1:] < arr[:-1]).any())):
+            raise ValueError("pack_records: inconsistent batch")
+        cap = len(blobs) + n * 38
         out = (ctypes.c_uint8 * cap)()
         got = self.lib.segstore_pack(
-            n, packed_keys, types, bytes(buf), arr, out, cap
+            n, packed_keys, types, blobs, arr.ctypes.data, out, cap
         )
         if got < 0:
             raise ValueError("segstore_pack failed")
-        return bytes(out[: int(got)])
+        return bytes(memoryview(out)[: int(got)])
 
     def replay(self, path: str, seg_id: int, start: int) -> tuple:
         """Scan one segment file into the index; returns

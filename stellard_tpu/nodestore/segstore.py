@@ -59,7 +59,10 @@ import struct
 import threading
 import time
 import zlib
+from array import array
 from typing import Iterator, Optional
+
+import numpy as np
 
 from .core import Backend, NodeObject, NodeObjectType, register_backend
 
@@ -89,6 +92,18 @@ def _loc(sid: int, off: int) -> int:
 
 def _loc_split(loc: int) -> tuple[int, int]:
     return loc >> _SEG_SHIFT, loc & ((1 << _SEG_SHIFT) - 1)
+
+
+def _record_locs(sid: int, base: int, offsets) -> array:
+    """The loc of every record of one append image that lands at `base`
+    of segment `sid`, from its blob offsets (n+1): record i starts past
+    the i headers and type bytes and the blobs before it. Array
+    arithmetic, not a loop over records; ``array('Q')`` iterates as ints
+    for the pure-Python index and crosses the native seam as a buffer."""
+    off = np.asarray(offsets, dtype=np.uint64)
+    pos = (off[:-1] - off[0]) + np.uint64(base) + np.arange(
+        len(off) - 1, dtype=np.uint64) * np.uint64(_REC_HEADER + 1)
+    return array("Q", (pos | np.uint64(sid << _SEG_SHIFT)).tobytes())
 
 
 # --------------------------------------------------------------------------
@@ -514,14 +529,9 @@ class SegStoreBackend(Backend):
                 raise
             t1 = time.perf_counter()
             c1 = _thread_cpu(tr)
-            locs = []
-            off = base
-            for i in range(n_sel):
-                locs.append(_loc(self._active_id, off))
-                off += _REC_HEADER + 1 + (
-                    sel_offsets[i + 1] - sel_offsets[i]
-                )
-            self._idx.put_batch(sel_keys, locs)
+            self._idx.put_batch(
+                sel_keys, _record_locs(self._active_id, base, sel_offsets)
+            )
             if self._sweep_active:
                 self._recent_keys.update(
                     sel_keys[32 * i: 32 * i + 32] for i in range(n_sel)
@@ -995,15 +1005,19 @@ class SegStoreBackend(Backend):
             "<IIIQQ", _CKPT_VERSION, len(seg_items), self._active_id,
             self._segs[self._active_id].size, len(entries) // 40,
         )
-        stats = b"".join(
+        head += b"".join(
             struct.pack("<IQQ", sid, seg.size, seg.live_bytes)
             for sid, seg in seg_items
         )
-        body = head + stats + entries
-        blob = body + struct.pack("<I", zlib.crc32(body) & 0xFFFFFFFF)
+        # [head | stats | entries | crc32 of the three]: the entries are
+        # written as dump() returned them, never copied into one blob
+        crc = zlib.crc32(entries, zlib.crc32(head)) & 0xFFFFFFFF
+        size = len(head) + len(entries) + 4
         tmp = os.path.join(self.root, _CKPT_NAME + ".tmp")
         with open(tmp, "wb") as f:
-            f.write(blob)
+            f.write(head)
+            f.write(entries)
+            f.write(struct.pack("<I", crc))
             f.flush()
             os.fsync(f.fileno())
         os.replace(tmp, os.path.join(self.root, _CKPT_NAME))
@@ -1022,7 +1036,7 @@ class SegStoreBackend(Backend):
             tr.complete("store.checkpoint", "persist", t0,
                         time.perf_counter(),
                         entries=len(entries) // 40,
-                        bytes=len(blob))
+                        bytes=size)
 
     # -- misc --------------------------------------------------------------
 

@@ -9,8 +9,12 @@ from __future__ import annotations
 
 import hashlib
 import os
+import random
 import struct
+import zlib
+from array import array
 
+import numpy as np
 import pytest
 
 from stellard_tpu.nodestore import (
@@ -18,6 +22,12 @@ from stellard_tpu.nodestore import (
     NodeObjectType,
     SegStoreBackend,
     make_database,
+)
+from stellard_tpu.nodestore.segstore import (
+    _CKPT_MAGIC,
+    _PyIndex,
+    _pack_records_py,
+    _record_locs,
 )
 from stellard_tpu.utils.hashes import sha512_half
 
@@ -269,6 +279,240 @@ class TestCheckpointedOpen:
         resolvable = sum(1 for k, _ in pairs if db2.fetch(k) is not None)
         assert 0 < resolvable < 1500
         db2.close()
+
+
+def _needs_native():
+    if True not in NATIVE_MODES:
+        pytest.skip("native toolchain unavailable")
+
+
+def _seam_corpus(n, seed):
+    """n records of 40-700 byte blobs (a few MB at thousands): packed
+    keys, type bytes, one flat buffer and its n+1 offsets."""
+    rng = random.Random(seed)
+    keys = rng.randbytes(32 * n)
+    types = bytes(rng.choice((1, 3, 4)) for _ in range(n))
+    offsets = [0]
+    for _ in range(n):
+        offsets.append(offsets[-1] + rng.randrange(40, 700))
+    return keys, types, rng.randbytes(offsets[-1]), offsets
+
+
+def _slice(keys, buf, offsets, a, b):
+    """Records [a, b) of a corpus as their own flat batch."""
+    base = offsets[a]
+    return (keys[32 * a: 32 * b], buf[base: offsets[b]],
+            [o - base for o in offsets[a: b + 1]])
+
+
+def _loop_locs(sid, base, offsets):
+    """The per-record loop the append path once built its locations
+    with: the reference `_record_locs` is held to."""
+    locs, off = [], base
+    for i in range(len(offsets) - 1):
+        locs.append((sid << 44) | off)
+        off += 37 + 1 + (offsets[i + 1] - offsets[i])
+    return locs
+
+
+def _entries(blob):
+    return sorted(blob[i: i + 40] for i in range(0, len(blob), 40))
+
+
+class TestNativeSeam:
+    """Buffers cross the native seam as buffers: pack_records, dump,
+    put_batch and the append's locations give exactly what the
+    pure-Python mirrors give."""
+
+    @pytest.mark.parametrize("form", ["bytes", "memoryview", "offsets_u64"])
+    def test_pack_records_matches_python_mirror(self, form):
+        _needs_native()
+        from stellard_tpu.native import SegIdxNative
+
+        keys, types, buf, offsets = _seam_corpus(8000, seed=1)
+        want = _pack_records_py(keys, types, buf, offsets)
+        assert len(want) > 2_000_000
+        buf_in, offsets_in = buf, offsets
+        if form == "memoryview":
+            # a view that starts inside a larger buffer
+            buf_in = memoryview(b"\xee" * 100 + buf)[100:]
+        elif form == "offsets_u64":
+            offsets_in = array("Q", offsets)
+        got = SegIdxNative().pack_records(keys, types, buf_in, offsets_in)
+        assert type(got) is bytes
+        assert got == want
+
+    @pytest.mark.parametrize("fault", ["short_offsets", "past_buffer",
+                                       "decreasing", "short_keys"])
+    def test_pack_records_refuses_an_inconsistent_batch(self, fault):
+        """The C loop copies whatever ranges it is given: a batch whose
+        offsets or keys do not fit is refused before the call."""
+        _needs_native()
+        from stellard_tpu.native import SegIdxNative
+
+        keys, types, buf, offsets = _seam_corpus(50, seed=8)
+        if fault == "short_offsets":
+            offsets = offsets[:-1]
+        elif fault == "past_buffer":
+            offsets = offsets[:-1] + [len(buf) + 1]
+        elif fault == "decreasing":
+            offsets = list(offsets)
+            offsets[10], offsets[11] = offsets[11], offsets[10]
+        else:
+            keys = keys[:-32]
+        with pytest.raises(ValueError):
+            SegIdxNative().pack_records(keys, types, buf, offsets)
+
+    def test_partly_deduplicated_append_matches_python_store(self, tmp_path):
+        """The mask path: a batch of which a third is already stored
+        lands the same segment bytes and index under both paths."""
+        _needs_native()
+        keys, _, buf, offsets = _seam_corpus(6000, seed=2)
+        n = len(offsets) - 1
+        first = [i for i in range(n) if i % 3 == 0]
+        old_keys = b"".join(keys[32 * i: 32 * i + 32] for i in first)
+        old_buf = b"".join(buf[offsets[i]: offsets[i + 1]] for i in first)
+        old_offsets = [0]
+        for i in first:
+            old_offsets.append(old_offsets[-1] + offsets[i + 1] - offsets[i])
+        rest = [i for i in range(n) if i % 3]
+        t = bytes([int(NodeObjectType.ACCOUNT_NODE)])
+        want = _pack_records_py(
+            old_keys, t * len(first), old_buf, old_offsets
+        ) + _pack_records_py(
+            b"".join(keys[32 * i: 32 * i + 32] for i in rest), t * len(rest),
+            b"".join(buf[offsets[i]: offsets[i + 1]] for i in rest),
+            [0] + [sum(offsets[j + 1] - offsets[j] for j in rest[:k + 1])
+                   for k in range(len(rest))],
+        )
+        dumps = []
+        for native in (False, True):
+            be = SegStoreBackend(str(tmp_path / f"n{native}"),
+                                 durability="async", use_native=native)
+            assert be.store_packed(NodeObjectType.ACCOUNT_NODE, old_keys,
+                                   old_buf, old_offsets) == len(first)
+            assert be.store_packed(NodeObjectType.ACCOUNT_NODE, keys, buf,
+                                   offsets) == len(rest)
+            assert be.dedup_skips == len(first)
+            be._active_f.flush()
+            seg = tmp_path / f"n{native}" / "seg-00000001.seg"
+            assert seg.read_bytes() == want
+            dumps.append(_entries(be._idx.dump()))
+            for i in range(0, n, 97):
+                obj = be.fetch(keys[32 * i: 32 * i + 32])
+                assert obj.data == buf[offsets[i]: offsets[i + 1]]
+            be.close()
+        assert dumps[0] == dumps[1] and len(dumps[0]) == n
+
+    def test_dump_matches_python_index_and_loads_back(self):
+        _needs_native()
+        from stellard_tpu.native import SegIdxNative
+
+        n = 120_000
+        rng = random.Random(3)
+        keys = rng.randbytes(32 * n)
+        locs = array("Q", (((i % 9) << 44) | (i * 41) for i in range(n)))
+        nat, py = SegIdxNative(), _PyIndex()
+        nat.put_batch(keys, locs)
+        py.put_batch(keys, locs)
+        for i in range(0, n, 113):  # tombstones are not dumped
+            k = keys[32 * i: 32 * i + 32]
+            assert nat.remove(k) and py.remove(k)
+        blob = nat.dump()
+        assert type(blob) is bytes
+        assert len(blob) == 40 * len(py)
+        assert _entries(blob) == _entries(py.dump())
+        back = SegIdxNative()
+        back.load(blob)
+        assert len(back) == len(py)
+        assert _entries(back.dump()) == _entries(blob)
+        for i in range(1, n, 997):
+            k = keys[32 * i: 32 * i + 32]
+            assert back.get(k) == py.get(k) == (locs[i] if i % 113 else None)
+
+    @pytest.mark.parametrize("form", ["list", "array_q", "numpy"])
+    def test_put_batch_takes_any_u64_form(self, form):
+        _needs_native()
+        from stellard_tpu.native import SegIdxNative
+
+        keys = random.Random(5).randbytes(32 * 3000)
+        locs = [(7 << 44) | (i * 600) for i in range(3000)]
+        arg = {"list": locs, "array_q": array("Q", locs),
+               "numpy": np.array(locs, dtype=np.uint64)}[form]
+        idx = SegIdxNative()
+        idx.put_batch(keys, arg)
+        assert len(idx) == 3000
+        for i in range(0, 3000, 7):
+            assert idx.get(keys[32 * i: 32 * i + 32]) == locs[i]
+        with pytest.raises(ValueError):
+            idx.put_batch(keys[:32], [(1 << 64) - 2])
+
+    @pytest.mark.parametrize("sid,base,first", [
+        (1, 0, 0), (7, 12_345, 0), (3, 1 << 30, 999),
+        ((1 << 19) + 5, 1 << 40, 17),
+    ])
+    def test_record_locs_equal_the_per_record_loop(self, sid, base, first):
+        rng = random.Random(sid)
+        offsets = [first]
+        for _ in range(5000):
+            offsets.append(offsets[-1] + rng.randrange(0, 900))
+        want = _loop_locs(sid, base, offsets)
+        for form in (offsets, array("Q", offsets),
+                     np.array(offsets, dtype=np.uint64)):
+            got = _record_locs(sid, base, form)
+            assert type(got[0]) is int
+            assert list(got) == want
+
+    def test_append_hands_put_batch_the_loop_locations(self, tmp_path,
+                                                       use_native):
+        be = SegStoreBackend(str(tmp_path / "ns"), durability="async",
+                             use_native=use_native)
+        seen = []
+        put = be._idx.put_batch
+        be._idx.put_batch = lambda k, locs: (seen.append(list(locs)),
+                                             put(k, locs))
+        keys, _, buf, offsets = _seam_corpus(900, seed=6)
+        for a in range(0, 900, 300):
+            base = be._segs[be._active_id].size
+            k, b, o = _slice(keys, buf, offsets, a, a + 300)
+            be.store_packed(NodeObjectType.ACCOUNT_NODE, k, b, o)
+            assert seen[-1] == _loop_locs(be._active_id, base, o)
+        assert len(seen) == 3
+        be.close()
+
+    def test_checkpoint_inside_multichunk_append_reopens_without_replay(
+            self, tmp_path, use_native):
+        """Every chunk crosses the checkpoint mark, so the store
+        checkpoints inside its appends; the file is the one-blob format
+        [head | stats | entries | crc32] and a crash behind the last
+        chunk reopens with nothing to replay."""
+        root = tmp_path / "ns"
+        be = SegStoreBackend(str(root), checkpoint_bytes=1 << 16,
+                             use_native=use_native)
+        keys, _, buf, offsets = _seam_corpus(1200, seed=4)
+        for a in range(0, 1200, 200):
+            k, b, o = _slice(keys, buf, offsets, a, a + 200)
+            assert o[-1] > 1 << 16
+            be.store_packed(NodeObjectType.ACCOUNT_NODE, k, b, o)
+        assert be.checkpoints == 6
+        entries = be._idx.dump()
+        segs = sorted(be._segs.items())
+        body = _CKPT_MAGIC + struct.pack(
+            "<IIIQQ", 1, len(segs), be._active_id,
+            be._segs[be._active_id].size, len(entries) // 40,
+        ) + b"".join(struct.pack("<IQQ", sid, s.size, s.live_bytes)
+                     for sid, s in segs) + entries
+        assert (root / "index.ckpt").read_bytes() == body + struct.pack(
+            "<I", zlib.crc32(body) & 0xFFFFFFFF)
+        be._active_f.flush()  # crash: no close(), no final checkpoint
+        be2 = SegStoreBackend(str(root), use_native=use_native)
+        assert be2.opened_from_checkpoint
+        assert be2.replayed_records == 0
+        assert be2.count() == 1200
+        for i in range(1200):
+            obj = be2.fetch(keys[32 * i: 32 * i + 32])
+            assert obj.data == buf[offsets[i]: offsets[i + 1]]
+        be2.close()
 
 
 class TestTornTailRecovery:
